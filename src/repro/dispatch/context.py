@@ -34,9 +34,15 @@ class DispatchPolicy:
     shard_hints: bool = False          # apply ShardPlan hints on xla outputs
 
     def backend(self) -> str:
-        """Resolve 'auto' at trace time: compiled Pallas on TPU, XLA off."""
+        """Resolve 'auto' at trace time: compiled Pallas on TPU, XLA off
+        TPU and under a multi-device mesh — the compiler cannot partition a
+        Pallas kernel across devices, and XLA's dot it can."""
         if self.execute == "auto":
-            return "pallas" if jax.default_backend() == "tpu" else "xla"
+            from repro.parallel.hints import current_mesh
+            mesh = current_mesh()
+            sharded = mesh is not None and mesh.devices.size > 1
+            return ("pallas" if jax.default_backend() == "tpu"
+                    and not sharded else "xla")
         return self.execute
 
 

@@ -35,10 +35,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 ships the class as TPUCompilerParams; newer as CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG = -1e30
 
 
@@ -156,7 +152,7 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: float, kv_len: int,
         ),
         out_shape=[jax.ShapeDtypeStruct((B, Sq, H, hd_v), q.dtype),
                    jax.ShapeDtypeStruct((B, H, Sq), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ij, q, k, v)
@@ -273,7 +269,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, scale: float,
             scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ij_i, q, k, v, do, lse, delta)[0]
@@ -296,7 +292,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, scale: float,
         ),
         out_shape=[jax.ShapeDtypeStruct((B, Skv, H, hd), q.dtype),
                    jax.ShapeDtypeStruct((B, Skv, H, hd_v), q.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ij_j, q, k, v, do, lse, delta)

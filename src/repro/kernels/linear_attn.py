@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 ships the class as TPUCompilerParams; newer as CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG = -1e30
 
 
@@ -131,7 +127,7 @@ def linear_attn_bshk_pallas(r, k, v, logw, u, state0, *, chunk: int = 64,
         out_shape=[jax.ShapeDtypeStruct((B, S, H, V), r.dtype),
                    jax.ShapeDtypeStruct((B, H, K, V), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, u, state0)
@@ -166,7 +162,7 @@ def linear_attn_pallas(r, k, v, logw, u, *, chunk: int = 64,
         out_specs=pl.BlockSpec((1, 1, chunk, V), lambda b, c: (b, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, n, chunk, V), r.dtype),
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(rr, kk, vv, ww, u)

@@ -116,6 +116,31 @@ def _paged_impl(impl: Optional[str]) -> str:
     return default_paged_impl() if impl is None else impl
 
 
+def _paged_gqa(q, k_arena, v_arena, tables, starts, lengths, scale,
+               interpret, logit_cap, lse=False):
+    """Run the GQA paged kernel on (S, C, H, hd) chunk queries: regroup the
+    query heads under their KV head, one (C*G, hd) row block per head, and
+    back.  Returns o (S, C, H, hd_v), or with ``lse`` (o, m (S, C, H) f32,
+    l (S, C, H) f32)."""
+    from repro.kernels.paged_attn import paged_gqa_pallas
+    S, C, H, hd = q.shape
+    KVH = k_arena.shape[2]
+    G = H // KVH
+    qg = q.reshape(S, C, KVH, G, hd).transpose(0, 2, 1, 3, 4)
+    out = paged_gqa_pallas(qg.reshape(S, KVH, C * G, hd), k_arena, v_arena,
+                           tables, starts, lengths, scale, interpret,
+                           group=G, logit_cap=logit_cap, lse=lse)
+
+    def back(x):
+        x = x.reshape(S, KVH, C, G, x.shape[-1]).transpose(0, 2, 1, 3, 4)
+        return x.reshape(S, C, H, x.shape[-1])
+
+    if not lse:
+        return back(out)
+    o, m, l = out
+    return back(o), back(m)[..., 0], back(l)[..., 0]
+
+
 @functools.partial(jax.jit, static_argnames=("logit_cap", "impl",
                                              "interpret"))
 def paged_attention(q, k_arena, v_arena, tables, lengths, *,
@@ -130,19 +155,15 @@ def paged_attention(q, k_arena, v_arena, tables, lengths, *,
     in logical order (tail-pad with the last live id); lengths: (S,) int32.
     Returns (S, H, hd_v); lanes with length 0 yield zeros.
     """
-    S, H, hd = q.shape
-    KVH = k_arena.shape[2]
+    hd = q.shape[-1]
     scale = 1.0 / (hd ** 0.5)
     if _paged_impl(impl) == "xla":
         from repro.kernels.ref import paged_attention_ref
         return paged_attention_ref(q, k_arena, v_arena, tables, lengths,
                                    scale=scale, logit_cap=logit_cap)
-    from repro.kernels.paged_attn import paged_gqa_decode_pallas
-    qg = q.reshape(S, KVH, H // KVH, hd)
-    o = paged_gqa_decode_pallas(qg, k_arena, v_arena, tables, lengths,
-                                scale, _interpret(interpret),
-                                logit_cap=logit_cap)
-    return o.reshape(S, H, v_arena.shape[-1])
+    o = _paged_gqa(q[:, None], k_arena, v_arena, tables, lengths - 1,
+                   lengths, scale, _interpret(interpret), logit_cap)
+    return o[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("logit_cap", "impl",
@@ -179,19 +200,28 @@ def shared_paged_attention(q, k_arena, v_arena, unique_tables, unique_lens,
         return shared_paged_attention_ref(
             q, k_arena, v_arena, unique_tables, unique_lens, prefix_pages,
             prefix_lens, scale=scale, logit_cap=logit_cap)
-    from repro.kernels.paged_attn import (paged_gqa_decode_lse_pallas,
-                                          paged_gqa_prefix_pallas)
+    from repro.kernels.paged_attn import paged_gqa_prefix_pallas
     from repro.kernels.ref import merge_softmax_states
-    qg = q.reshape(S, KVH, H // KVH, hd)
+    G = H // KVH
     itp = _interpret(interpret)
+    # prefix phase: every lane's queries stacked per KV head
+    qp = q.reshape(S, KVH, G, hd).transpose(1, 0, 2, 3).reshape(KVH, S * G,
+                                                                 hd)
     o_p, m_p, l_p = paged_gqa_prefix_pallas(
-        qg, k_arena, v_arena, prefix_pages, prefix_lens, scale, itp,
-        logit_cap=logit_cap)
-    o_u, m_u, l_u = paged_gqa_decode_lse_pallas(
-        qg, k_arena, v_arena, unique_tables, unique_lens, scale, itp,
-        logit_cap=logit_cap)
-    o, _, _ = merge_softmax_states(o_p, m_p, l_p, o_u, m_u, l_u)
-    return o.astype(q.dtype).reshape(S, H, v_arena.shape[-1])
+        qp, k_arena, v_arena, prefix_pages, prefix_lens, scale, itp,
+        group=G, logit_cap=logit_cap)
+
+    def lanes_first(x):
+        x = x.reshape(KVH, S, G, x.shape[-1]).transpose(1, 0, 2, 3)
+        return x.reshape(S, H, x.shape[-1])
+
+    o_u, m_u, l_u = _paged_gqa(q[:, None], k_arena, v_arena, unique_tables,
+                               unique_lens - 1, unique_lens, scale, itp,
+                               logit_cap, lse=True)
+    o, _, _ = merge_softmax_states(
+        lanes_first(o_p), lanes_first(m_p)[..., 0], lanes_first(l_p)[..., 0],
+        o_u[:, 0], m_u[:, 0], l_u[:, 0])
+    return o.astype(q.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("qk_dim", "impl", "interpret"))
@@ -232,20 +262,15 @@ def paged_prefill_attention(q, k_arena, v_arena, tables, starts, lengths, *,
     a lane's chunk length are garbage the caller discards, and lanes with
     length 0 yield zeros.
     """
-    S, C, H, hd = q.shape
-    KVH = k_arena.shape[2]
+    hd = q.shape[-1]
     scale = 1.0 / (hd ** 0.5)
     if _paged_impl(impl) == "xla":
         from repro.kernels.ref import paged_prefill_attention_ref
         return paged_prefill_attention_ref(q, k_arena, v_arena, tables,
                                            starts, lengths, scale=scale,
                                            logit_cap=logit_cap)
-    from repro.kernels.paged_attn import paged_gqa_prefill_pallas
-    qg = q.reshape(S, C, KVH, H // KVH, hd)
-    o = paged_gqa_prefill_pallas(qg, k_arena, v_arena, tables, starts,
-                                 lengths, scale, _interpret(interpret),
-                                 logit_cap=logit_cap)
-    return o.reshape(S, C, H, v_arena.shape[-1])
+    return _paged_gqa(q, k_arena, v_arena, tables, starts, lengths, scale,
+                      _interpret(interpret), logit_cap)
 
 
 @functools.partial(jax.jit, static_argnames=("qk_dim", "impl", "interpret"))

@@ -12,27 +12,32 @@ serving hot path).
 
 Two kernel families share the structure:
 
-* **decode** (``paged_gqa_decode_pallas`` / ``paged_mla_decode_pallas``) —
-  one query token per lane attending over its whole table.
-* **chunked prefill** (``paged_gqa_prefill_pallas`` /
-  ``paged_mla_prefill_pallas``) — ``C`` query tokens per lane (one prompt
-  chunk, already written to the arena by the caller) attending *causally*:
-  chunk row ``r`` sits at absolute position ``starts[lane] + r`` and sees
-  keys at positions ``<= starts[lane] + r``.  Per-lane ``starts`` /
-  ``lengths`` make the batch ragged: lanes whose chunk is empty
-  (``lengths[lane] == 0``) skip every block, which is how one prefill batch
-  carries heterogeneous prompt lengths.
+* **GQA** (``paged_gqa_pallas``) — ``C`` query tokens per lane attending
+  *causally* through the lane's table: chunk row ``r`` sits at absolute
+  position ``starts[lane] + r`` and sees keys at positions ``<= starts[lane]
+  + r``.  Chunked prefill runs it at ``C = chunk``; decode is the ``C = 1``
+  case with ``starts = lengths - 1``.  Per-lane ``starts`` / ``lengths``
+  make the batch ragged: lanes whose chunk is empty (``lengths[lane] ==
+  0``) skip every block, which is how one batch carries heterogeneous
+  prompt lengths.  The kernel also emits the online-softmax state, which
+  cascade decode merges with the shared-prefix phase
+  (``paged_gqa_prefix_pallas``).
+* **absorbed MLA** (``paged_mla_decode_pallas`` /
+  ``paged_mla_prefill_pallas``) — see below.
 
-Grid layout: ``(lanes, kv_heads, table_width)`` (GQA) / ``(lanes,
-table_width)`` (MLA), table width innermost.  The block table and per-lane
-scalars ride in scalar prefetch (PrefetchScalarGridSpec) so the K/V
-BlockSpec index maps resolve ``table[lane, j]`` before the body runs —
-that indirection IS the paging.  Per (lane, head) the (m, l, acc) online
-softmax state lives in VMEM scratch, reset at ``j == 0`` and emitted on the
-last table column.  Callers pad dead table columns with the lane's last
-live block id: Pallas elides the DMA when consecutive grid steps map to
-the same block, and ``pl.when`` skips the compute, so padded columns cost
-(almost) nothing.
+Grid layout: ``(lanes, table_width)`` — for GQA ``(lanes, query row
+blocks, table_width)`` — table width innermost.  The block
+table and per-lane scalars ride in scalar prefetch (PrefetchScalarGridSpec)
+so the K/V BlockSpec index maps resolve ``table[lane, j]`` before the body
+runs — that indirection IS the paging.  One K/V block is a whole page
+``(1, block_size, KVH, hd)``: the TPU lowering needs a block's last two
+dims to be (8, 128)-aligned or whole, so the page is fetched for every KV
+head at once and the body loops over heads.  Per (lane, head) the (m, l,
+acc) online-softmax state lives in VMEM scratch, reset at ``j == 0`` and
+emitted on the last table column.  Callers pad dead table columns with the
+lane's last live block id: Pallas elides the DMA when consecutive grid
+steps map to the same block, and ``pl.when`` skips the compute, so padded
+columns cost (almost) nothing.
 
 Absorbed MLA attends in the compressed latent space: queries arrive
 pre-absorbed (q @ W_UK) plus the shared-rope query, the arena stores
@@ -50,96 +55,240 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 ships the class as TPUCompilerParams; newer as CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG = -1e30
+# query rows per grid step of the GQA kernel: longer chunks split into row
+# blocks (a (KVH, 2048, 64) block already overflows v5e's scoped VMEM)
+ROW_BLOCK = 512
 
 
 # ---------------------------------------------------------------------------
 # GQA
 # ---------------------------------------------------------------------------
 
-def _gqa_kernel(tables, lengths, q_ref, k_ref, v_ref, o_ref,
-                m_scr, l_scr, acc_scr, *, bs, n_bt, scale, logit_cap):
+def _reset_state(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _fold_page(q, k, v, live, h, m_scr, l_scr, acc_scr, *, scale,
+               logit_cap):
+    """Fold one page of keys into KV head ``h``'s running softmax state.
+
+    q: (R, hd) query rows of the head; k: (bs, hd); v: (bs, hd_v); live:
+    (R, bs) bool.  The mask on p — not just on s — keeps fully-masked rows
+    at l == 0: with m == NEG every masked exp(s - m) would be exp(0)."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if logit_cap > 0.0:
+        s = jnp.tanh(s / logit_cap) * logit_cap
+    s = jnp.where(live, s, NEG)
+    m_prev, l_prev = m_scr[h], l_scr[h]                    # (R, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[h] = m_new
+    l_scr[h] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+
+
+def _emit_state(outs, m_scr, l_scr, acc_scr):
+    """Write the normalized output and, when two more output refs follow
+    it, the raw (m, l) softmax state.  Rows that never accumulated keep
+    (0, NEG, 0): zeros out, and a softmax-state merge degenerates to the
+    other phase."""
+    o_ref = outs[0]
+    o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                  ).reshape(o_ref.shape).astype(o_ref.dtype)
+    if len(outs) == 3:
+        outs[1][...] = m_scr[...].reshape(outs[1].shape)
+        outs[2][...] = l_scr[...].reshape(outs[2].shape)
+
+
+def _gqa_kernel(tables, starts, lengths, q_ref, k_ref, v_ref, *refs, bs,
+                n_bt, group, scale, logit_cap):
+    *outs, m_scr, l_scr, acc_scr = refs
     lane = pl.program_id(0)
+    row0 = pl.program_id(1) * q_ref.shape[2]   # first flat row of the block
     j = pl.program_id(2)
-    kv_len = lengths[lane]
+    kv_len = lengths[lane]          # rows valid AFTER this chunk's write
+    q0 = starts[lane]               # absolute position of chunk row 0
 
     @pl.when(j == 0)
     def _reset():
-        m_scr[...] = jnp.full_like(m_scr, NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _reset_state(m_scr, l_scr, acc_scr)
 
     @pl.when(j * bs < kv_len)
     def _accumulate():
-        q = q_ref[0, 0]                                    # (G, hd)
-        k = k_ref[0, :, 0, :]                              # (bs, hd)
-        v = v_ref[0, :, 0, :]                              # (bs, hd_v)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if logit_cap > 0.0:
-            s = jnp.tanh(s / logit_cap) * logit_cap
-        col = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col < kv_len, s, NEG)
-        m_prev, l_prev = m_scr[0], l_scr[0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[0] = m_new
-        l_scr[0] = l_prev * corr + jnp.sum(p, axis=-1)
+        for h in range(q_ref.shape[1]):
+            q = q_ref[0, h]                                # (C*G, hd)
+            shape = (q.shape[0], bs)
+            col = j * bs + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            # flat row i is chunk row i // G at absolute position q0 + i // G;
+            # the causal mask makes each chunk query see only keys at or
+            # before its own position (block 0 always has col 0 <= q0 + row,
+            # so every live row accumulates a finite max there)
+            qpos = q0 + (row0 + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 0)) // group
+            _fold_page(q, k_ref[0, :, h, :], v_ref[0, :, h, :],
+                       (col < kv_len) & (col <= qpos), h, m_scr, l_scr,
+                       acc_scr, scale=scale, logit_cap=logit_cap)
 
     @pl.when(j == n_bt - 1)
     def _emit():
-        # empty lanes (kv_len == 0) never accumulate: l == 0 -> zeros out
-        l = jnp.maximum(l_scr[0], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        _emit_state(outs, m_scr, l_scr, acc_scr)
 
 
-def paged_gqa_decode_pallas(q, k_arena, v_arena, tables, lengths,
-                            scale: float, interpret: bool,
-                            logit_cap: float = 0.0) -> jnp.ndarray:
-    """q: (S, KVH, G, hd); k_arena: (NB, bs, KVH, hd); v_arena:
-    (NB, bs, KVH, hd_v); tables: (S, W) int32 physical block ids in logical
-    order (tail-pad with the last live id); lengths: (S,) int32 valid
-    tokens.  Returns (S, KVH, G, hd_v)."""
-    S, KVH, G, hd = q.shape
-    NB, bs = k_arena.shape[0], k_arena.shape[1]
+def paged_gqa_pallas(q, k_arena, v_arena, tables, starts, lengths,
+                     scale: float, interpret: bool, *, group: int,
+                     logit_cap: float = 0.0, lse: bool = False):
+    """q: (S, KVH, C*G, hd) one chunk of queries per lane and KV head, flat
+    row ``c * G + g`` for chunk row c and query g of the head's group
+    (``group`` = G); k_arena: (NB, bs, KVH, hd); v_arena: (NB, bs, KVH,
+    hd_v); tables: (S, W) int32 physical block ids in logical order
+    (tail-pad with the last live id); starts: (S,) int32 absolute position
+    of each lane's chunk row 0; lengths: (S,) int32 valid tokens
+    *including* the chunk (``starts + chunk_len``).  The chunk's own K/V
+    rows must already be in the arena.  Returns o (S, KVH, C*G, hd_v)
+    normalized; rows past a lane's chunk are garbage the caller discards,
+    lanes with length 0 yield zeros.  With ``lse`` it returns (o, m, l),
+    adding the softmax state cascade decode merges: m (S, KVH, C*G, 1) f32
+    running max and l (S, KVH, C*G, 1) f32 exp-sum."""
+    S, KVH, R, hd = q.shape
+    bs = k_arena.shape[1]
     hd_v = v_arena.shape[-1]
     W = tables.shape[1]
+    rb = min(R, ROW_BLOCK)
+    pad = (-R) % rb
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
 
-    grid = (S, KVH, W)
+    def row_block(width):
+        return pl.BlockSpec((1, KVH, rb, width),
+                            lambda s, r, j, t, st, ln: (s, 0, r, 0))
+
+    def page_block(width):
+        return pl.BlockSpec((1, bs, KVH, width),
+                            lambda s, r, j, t, st, ln: (t[s, j], 0, 0, 0))
+
+    rows = R + pad
+    out_specs = [row_block(hd_v)]
+    out_shape = [jax.ShapeDtypeStruct((S, KVH, rows, hd_v), q.dtype)]
+    if lse:
+        out_specs += [row_block(1)] * 2
+        out_shape += [jax.ShapeDtypeStruct((S, KVH, rows, 1),
+                                           jnp.float32)] * 2
     out = pl.pallas_call(
-        functools.partial(_gqa_kernel, bs=bs, n_bt=W, scale=scale,
+        functools.partial(_gqa_kernel, bs=bs, n_bt=W, group=group,
+                          scale=scale, logit_cap=logit_cap),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S, rows // rb, W),
+            in_specs=[row_block(hd), page_block(hd), page_block(hd_v)],
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((KVH, rb, 1), jnp.float32),
+                            pltpu.VMEM((KVH, rb, 1), jnp.float32),
+                            pltpu.VMEM((KVH, rb, hd_v), jnp.float32)],
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(tables, starts, lengths, q, k_arena, v_arena)
+    out = [x[:, :, :R] for x in out]
+    return tuple(out) if lse else out[0]
+
+
+# ---------------------------------------------------------------------------
+# shared-prefix (cascade) decode: one walk over the hot pages for all lanes
+# ---------------------------------------------------------------------------
+
+def _gqa_prefix_kernel(pages, nlive, plen_ref, q_ref, k_ref, v_ref, *refs,
+                       bs, n_bt, scale, logit_cap):
+    *outs, m_scr, l_scr, acc_scr = refs
+    j = pl.program_id(0)
+
+    @pl.when(j == 0)
+    def _reset():
+        _reset_state(m_scr, l_scr, acc_scr)
+
+    @pl.when(j * bs < nlive[0])
+    def _accumulate():
+        # every lane's queries stacked into one MXU call against the SAME
+        # page: the page DMA happens once per page grid step, not once per
+        # lane — that is the cascade win.  Flat row i belongs to lane
+        # i // G; its prefix_len gates how much of the shared run it
+        # attends (0 = lane outside the group)
+        for h in range(q_ref.shape[0]):
+            q = q_ref[h]                                   # (S*G, hd)
+            col = j * bs + jax.lax.broadcasted_iota(
+                jnp.int32, (q.shape[0], bs), 1)
+            _fold_page(q, k_ref[0, :, h, :], v_ref[0, :, h, :],
+                       col < plen_ref[...], h, m_scr, l_scr, acc_scr,
+                       scale=scale, logit_cap=logit_cap)
+
+    @pl.when(j == n_bt - 1)
+    def _emit():
+        _emit_state(outs, m_scr, l_scr, acc_scr)
+
+
+def paged_gqa_prefix_pallas(q, k_arena, v_arena, prefix_pages, prefix_lens,
+                            scale: float, interpret: bool, *, group: int,
+                            logit_cap: float = 0.0):
+    """Shared-prefix phase of cascade decode: ONE grid walk over the hot
+    prefix pages serves every lane at once.
+
+    q: (KVH, S*G, hd) every lane's decode queries per KV head, flat row
+    ``s * G + g`` (``group`` = G); prefix_pages: (P,) int32 physical pages
+    of the shared prefix in logical order (tail-pad with the last id);
+    prefix_lens: (S,) int32 prefix rows lane s attends (0 = lane not in the
+    sharing group).  The grid is (P,) — lanes are NOT a grid dimension; all
+    lanes' queries hit each page block together, so a prefix shared by k
+    lanes is streamed once instead of k times.  Returns (o (KVH, S*G, hd_v)
+    normalized, m (KVH, S*G, 1) f32, l (KVH, S*G, 1) f32); lanes with
+    prefix_lens == 0 come back as (0, NEG, 0) so the merge degenerates to
+    the unique phase."""
+    KVH, R, hd = q.shape
+    bs = k_arena.shape[1]
+    hd_v = v_arena.shape[-1]
+    P = prefix_pages.shape[0]
+    # scalar skip bound for padded tail columns (every sharing lane spans
+    # the same page run, so max == the run's row count)
+    nlive = jnp.max(prefix_lens).astype(jnp.int32).reshape(1)
+    # per-row lengths ride as a VMEM operand (not scalar prefetch): the
+    # kernel needs them as a vector to mask the stacked (S*G, bs) scores
+    plens = jnp.repeat(prefix_lens.astype(jnp.int32), group).reshape(R, 1)
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda j, t, nl: (0,) * len(shape))
+
+    def page_block(width):
+        return pl.BlockSpec((1, bs, KVH, width),
+                            lambda j, t, nl: (t[j], 0, 0, 0))
+
+    return pl.pallas_call(
+        functools.partial(_gqa_prefix_kernel, bs=bs, n_bt=P, scale=scale,
                           logit_cap=logit_cap),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, G, hd),
-                             lambda s, h, j, t, ln: (s, h, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda s, h, j, t, ln: (t[s, j], 0, h, 0)),
-                pl.BlockSpec((1, bs, 1, hd_v),
-                             lambda s, h, j, t, ln: (t[s, j], 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, hd_v),
-                                   lambda s, h, j, t, ln: (s, h, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((1, G), jnp.float32),
-                            pltpu.VMEM((1, G), jnp.float32),
-                            pltpu.VMEM((G, hd_v), jnp.float32)],
+            grid=(P,),
+            in_specs=[whole((R, 1)), whole((KVH, R, hd)), page_block(hd),
+                      page_block(hd_v)],
+            out_specs=[whole((KVH, R, hd_v)), whole((KVH, R, 1)),
+                       whole((KVH, R, 1))],
+            scratch_shapes=[pltpu.VMEM((KVH, R, 1), jnp.float32),
+                            pltpu.VMEM((KVH, R, 1), jnp.float32),
+                            pltpu.VMEM((KVH, R, hd_v), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((S, KVH, G, hd_v), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=[jax.ShapeDtypeStruct((KVH, R, hd_v), q.dtype),
+                   jax.ShapeDtypeStruct((KVH, R, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((KVH, R, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tables, lengths, q, k_arena, v_arena)
-    return out
+    )(prefix_pages, nlive, plens, q, k_arena, v_arena)
 
 
 # ---------------------------------------------------------------------------
@@ -215,108 +364,10 @@ def paged_mla_decode_pallas(q_abs, q_rope, ckv_arena, krope_arena, tables,
                             pltpu.VMEM((H, r), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((S, H, r), q_abs.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, lengths, q_abs, q_rope, ckv_arena, krope_arena)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# chunked prefill: C causal queries per lane over previously-written pages
-# ---------------------------------------------------------------------------
-
-def _gqa_prefill_kernel(tables, starts, lengths, q_ref, k_ref, v_ref, o_ref,
-                        m_scr, l_scr, acc_scr, *, bs, n_bt, scale, logit_cap):
-    lane = pl.program_id(0)
-    j = pl.program_id(2)
-    kv_len = lengths[lane]          # rows valid AFTER this chunk's write
-    q0 = starts[lane]               # absolute position of chunk row 0
-    C, G = q_ref.shape[1], q_ref.shape[3]
-    CG = C * G
-
-    @pl.when(j == 0)
-    def _reset():
-        m_scr[...] = jnp.full_like(m_scr, NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(j * bs < kv_len)
-    def _accumulate():
-        q = q_ref[0, :, 0].reshape(CG, q_ref.shape[-1])    # (C*G, hd)
-        k = k_ref[0, :, 0, :]                              # (bs, hd)
-        v = v_ref[0, :, 0, :]                              # (bs, hd_v)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if logit_cap > 0.0:
-            s = jnp.tanh(s / logit_cap) * logit_cap
-        col = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # flat row i is chunk row i // G at absolute position q0 + i // G;
-        # the causal mask makes each chunk query see only keys at or before
-        # its own position (block 0 always has col 0 <= q0 + row, so every
-        # live row accumulates a finite max there — no exp(0) blowups)
-        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // G
-        s = jnp.where((col < kv_len) & (col <= qpos), s, NEG)
-        m_prev, l_prev = m_scr[0], l_scr[0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[0] = m_new
-        l_scr[0] = l_prev * corr + jnp.sum(p, axis=-1)
-
-    @pl.when(j == n_bt - 1)
-    def _emit():
-        # empty lanes (kv_len == 0) never accumulate: l == 0 -> zeros out
-        l = jnp.maximum(l_scr[0], 1e-30)
-        o = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        o_ref[0, :, 0] = o.reshape(C, G, o_ref.shape[-1])
-
-
-def paged_gqa_prefill_pallas(q, k_arena, v_arena, tables, starts, lengths,
-                             scale: float, interpret: bool,
-                             logit_cap: float = 0.0) -> jnp.ndarray:
-    """q: (S, C, KVH, G, hd) one prompt chunk per lane; k_arena: (NB, bs,
-    KVH, hd); v_arena: (NB, bs, KVH, hd_v); tables: (S, W) int32 physical
-    block ids in logical order (tail-pad with the last live id); starts:
-    (S,) int32 absolute position of each lane's chunk row 0; lengths: (S,)
-    int32 valid tokens *including* the chunk (``starts + chunk_len``).
-    The chunk's own K/V rows must already be in the arena.  Returns
-    (S, C, KVH, G, hd_v); rows past a lane's chunk are garbage the caller
-    discards, lanes with length 0 yield zeros."""
-    S, C, KVH, G, hd = q.shape
-    bs = k_arena.shape[1]
-    hd_v = v_arena.shape[-1]
-    W = tables.shape[1]
-
-    grid = (S, KVH, W)
-    out = pl.pallas_call(
-        functools.partial(_gqa_prefill_kernel, bs=bs, n_bt=W, scale=scale,
-                          logit_cap=logit_cap),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, C, 1, G, hd),
-                             lambda s, h, j, t, st, ln: (s, 0, h, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda s, h, j, t, st, ln: (t[s, j], 0, h, 0)),
-                pl.BlockSpec((1, bs, 1, hd_v),
-                             lambda s, h, j, t, st, ln: (t[s, j], 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, C, 1, G, hd_v),
-                                   lambda s, h, j, t, st, ln: (s, 0, h, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((1, C * G), jnp.float32),
-                            pltpu.VMEM((1, C * G), jnp.float32),
-                            pltpu.VMEM((C * G, hd_v), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((S, C, KVH, G, hd_v), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(tables, starts, lengths, q, k_arena, v_arena)
     return out
 
 
@@ -366,221 +417,12 @@ def _mla_prefill_kernel(tables, starts, lengths, qa_ref, qr_ref, ckv_ref,
         o_ref[0] = o.reshape(C, H, o_ref.shape[-1])
 
 
-def _gqa_lse_kernel(tables, lengths, q_ref, k_ref, v_ref, o_ref, m_ref,
-                    l_ref, m_scr, l_scr, acc_scr, *, bs, n_bt, scale,
-                    logit_cap):
-    lane = pl.program_id(0)
-    j = pl.program_id(2)
-    kv_len = lengths[lane]
-
-    @pl.when(j == 0)
-    def _reset():
-        m_scr[...] = jnp.full_like(m_scr, NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(j * bs < kv_len)
-    def _accumulate():
-        q = q_ref[0, 0]                                    # (G, hd)
-        k = k_ref[0, :, 0, :]                              # (bs, hd)
-        v = v_ref[0, :, 0, :]                              # (bs, hd_v)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if logit_cap > 0.0:
-            s = jnp.tanh(s / logit_cap) * logit_cap
-        col = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col < kv_len, s, NEG)
-        m_prev, l_prev = m_scr[0], l_scr[0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[0] = m_new
-        l_scr[0] = l_prev * corr + jnp.sum(p, axis=-1)
-
-    @pl.when(j == n_bt - 1)
-    def _emit():
-        # empty lanes (kv_len == 0) never accumulate: the (0, NEG, 0) state
-        # makes the softmax-state merge degenerate to the other phase
-        l = jnp.maximum(l_scr[0], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        m_ref[0, 0] = m_scr[0]
-        l_ref[0, 0] = l_scr[0]
-
-
-def paged_gqa_decode_lse_pallas(q, k_arena, v_arena, tables, lengths,
-                                scale: float, interpret: bool,
-                                logit_cap: float = 0.0):
-    """:func:`paged_gqa_decode_pallas` that also emits the online-softmax
-    state — the per-lane *unique* phase of cascade decode, whose result is
-    merged with the shared-prefix phase outside the kernel.  Returns
-    (o (S, KVH, G, hd_v) normalized, m (S, KVH, G) f32 running max,
-    l (S, KVH, G) f32 exp-sum)."""
-    S, KVH, G, hd = q.shape
-    bs = k_arena.shape[1]
-    hd_v = v_arena.shape[-1]
-    W = tables.shape[1]
-
-    grid = (S, KVH, W)
-    out, m, l = pl.pallas_call(
-        functools.partial(_gqa_lse_kernel, bs=bs, n_bt=W, scale=scale,
-                          logit_cap=logit_cap),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, G, hd),
-                             lambda s, h, j, t, ln: (s, h, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda s, h, j, t, ln: (t[s, j], 0, h, 0)),
-                pl.BlockSpec((1, bs, 1, hd_v),
-                             lambda s, h, j, t, ln: (t[s, j], 0, h, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, G, hd_v),
-                             lambda s, h, j, t, ln: (s, h, 0, 0)),
-                pl.BlockSpec((1, 1, G), lambda s, h, j, t, ln: (s, h, 0)),
-                pl.BlockSpec((1, 1, G), lambda s, h, j, t, ln: (s, h, 0)),
-            ],
-            scratch_shapes=[pltpu.VMEM((1, G), jnp.float32),
-                            pltpu.VMEM((1, G), jnp.float32),
-                            pltpu.VMEM((G, hd_v), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((S, KVH, G, hd_v), q.dtype),
-                   jax.ShapeDtypeStruct((S, KVH, G), jnp.float32),
-                   jax.ShapeDtypeStruct((S, KVH, G), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(tables, lengths, q, k_arena, v_arena)
-    return out, m, l
-
-
-# ---------------------------------------------------------------------------
-# shared-prefix (cascade) decode: one walk over the hot pages for all lanes
-# ---------------------------------------------------------------------------
-
-def _gqa_prefix_kernel(tables, nlive, plen_ref, q_ref, k_ref, v_ref,
-                       o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr, *,
-                       bs, n_bt, scale, logit_cap):
-    j = pl.program_id(1)
-    S, G = q_ref.shape[0], q_ref.shape[2]
-    SG = S * G
-
-    @pl.when(j == 0)
-    def _reset():
-        m_scr[...] = jnp.full_like(m_scr, NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(j * bs < nlive[0])
-    def _accumulate():
-        # every lane's queries stacked into one MXU call against the SAME
-        # page: the page DMA happens once per (kv_head, page) grid step,
-        # not once per lane — that is the cascade win
-        q = q_ref[:, 0].reshape(SG, q_ref.shape[-1])       # (S*G, hd)
-        k = k_ref[0, :, 0, :]                              # (bs, hd)
-        v = v_ref[0, :, 0, :]                              # (bs, hd_v)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if logit_cap > 0.0:
-            s = jnp.tanh(s / logit_cap) * logit_cap
-        col = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # flat row i belongs to lane i // G; its prefix_len gates how much
-        # of the shared run it attends (0 = lane outside the group).  The
-        # explicit mask on p — not just on s — keeps fully-masked rows at
-        # l == 0: with m == NEG every masked exp(s - m) would be exp(0)
-        plen = jnp.broadcast_to(plen_ref[...], (S, G)).reshape(SG, 1)
-        live = col < plen
-        s = jnp.where(live, s, NEG)
-        m_prev, l_prev = m_scr[0], l_scr[0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.where(live, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[0] = m_new
-        l_scr[0] = l_prev * corr + jnp.sum(p, axis=-1)
-
-    @pl.when(j == n_bt - 1)
-    def _emit():
-        l = jnp.maximum(l_scr[0], 1e-30)
-        o = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        o_ref[:, 0] = o.reshape(S, G, o_ref.shape[-1])
-        m_ref[:, 0] = m_scr[0].reshape(S, G)
-        l_ref[:, 0] = l_scr[0].reshape(S, G)
-
-
-def paged_gqa_prefix_pallas(q, k_arena, v_arena, prefix_pages, prefix_lens,
-                            scale: float, interpret: bool,
-                            logit_cap: float = 0.0):
-    """Shared-prefix phase of cascade decode: ONE grid walk over the hot
-    prefix pages serves every lane at once.
-
-    q: (S, KVH, G, hd); prefix_pages: (P,) int32 physical pages of the
-    shared prefix in logical order (tail-pad with the last id);
-    prefix_lens: (S,) int32 prefix rows lane s attends (0 = lane not in the
-    sharing group).  The grid is (KVH, P) — lanes are NOT a grid dimension;
-    all S lanes' queries hit each page block together, so a prefix shared
-    by k lanes is streamed once instead of k times.  Returns (o (S, KVH, G,
-    hd_v) normalized, m (S, KVH, G) f32, l (S, KVH, G) f32); lanes with
-    prefix_lens == 0 come back as (0, NEG, 0) so the merge degenerates to
-    the unique phase."""
-    S, KVH, G, hd = q.shape
-    bs = k_arena.shape[1]
-    hd_v = v_arena.shape[-1]
-    P = prefix_pages.shape[0]
-    # scalar skip bound for padded tail columns (every sharing lane spans
-    # the same page run, so max == the run's row count)
-    nlive = jnp.max(prefix_lens).astype(jnp.int32).reshape(1)
-    # per-lane lengths ride as a VMEM operand (not scalar prefetch): the
-    # kernel needs them as a vector to mask the stacked (S*G, bs) scores
-    plens2d = prefix_lens.astype(jnp.int32).reshape(S, 1)
-
-    grid = (KVH, P)
-    out, m, l = pl.pallas_call(
-        functools.partial(_gqa_prefix_kernel, bs=bs, n_bt=P, scale=scale,
-                          logit_cap=logit_cap),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((S, 1), lambda h, j, t, nl: (0, 0)),
-                pl.BlockSpec((S, 1, G, hd), lambda h, j, t, nl: (0, h, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
-                             lambda h, j, t, nl: (t[j], 0, h, 0)),
-                pl.BlockSpec((1, bs, 1, hd_v),
-                             lambda h, j, t, nl: (t[j], 0, h, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((S, 1, G, hd_v),
-                             lambda h, j, t, nl: (0, h, 0, 0)),
-                pl.BlockSpec((S, 1, G), lambda h, j, t, nl: (0, h, 0)),
-                pl.BlockSpec((S, 1, G), lambda h, j, t, nl: (0, h, 0)),
-            ],
-            scratch_shapes=[pltpu.VMEM((1, S * G), jnp.float32),
-                            pltpu.VMEM((1, S * G), jnp.float32),
-                            pltpu.VMEM((S * G, hd_v), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((S, KVH, G, hd_v), q.dtype),
-                   jax.ShapeDtypeStruct((S, KVH, G), jnp.float32),
-                   jax.ShapeDtypeStruct((S, KVH, G), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(prefix_pages, nlive, plens2d, q, k_arena, v_arena)
-    return out, m, l
-
-
 def paged_mla_prefill_pallas(q_abs, q_rope, ckv_arena, krope_arena, tables,
                              starts, lengths, scale: float,
                              interpret: bool) -> jnp.ndarray:
     """q_abs: (S, C, H, r) pre-absorbed chunk queries; q_rope: (S, C, H, rd);
     ckv_arena: (NB, bs, r); krope_arena: (NB, bs, rd); tables: (S, W) int32;
-    starts / lengths: (S,) int32 as in :func:`paged_gqa_prefill_pallas`.
+    starts / lengths: (S,) int32 as in :func:`paged_gqa_pallas`.
     Returns the latent mix o_lat: (S, C, H, r)."""
     S, C, H, r = q_abs.shape
     rd = q_rope.shape[-1]
@@ -610,7 +452,7 @@ def paged_mla_prefill_pallas(q_abs, q_rope, ckv_arena, krope_arena, tables,
                             pltpu.VMEM((C * H, r), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((S, C, H, r), q_abs.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, starts, lengths, q_abs, q_rope, ckv_arena, krope_arena)

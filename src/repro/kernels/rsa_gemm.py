@@ -9,9 +9,15 @@ VMEM while the grid iterates — is the dataflow:
       accumulator tile lives in VMEM scratch for the whole K loop.
   WS (weight-stationary): grid (Nt, Kt, Mt), M innermost; the B (weight)
       tile is revisited with a constant index over the whole M sweep, so it
-      stays resident; partial sums accumulate into the output tile.
+      stays resident.
   IS (input-stationary):  grid (Mt, Kt, Nt), N innermost; the A (input)
-      tile stays resident; partial sums accumulate into the output tile.
+      tile stays resident.
+
+In WS and IS an output tile is revisited once per K block, but not on
+consecutive grid steps, and the TPU pipeline never reads an output block
+back from HBM: it cannot accumulate there.  Each K block's partial product
+goes to its own f32 slice of a (Kt, M, N) output instead, summed after the
+kernel — the partial-sum traffic these dataflows spill on the array too.
 
 Block shapes are the SARA-recommended configuration (core/sara.py); MXU
 alignment wants multiples of 128 in M/N and the lane dim.  Validated in
@@ -29,10 +35,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hw import IS, OS, WS
 
-# jax<0.5 ships the class as TPUCompilerParams; newer as CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _kernel_os(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     @pl.when(pl.program_id(2) == 0)
@@ -47,18 +49,10 @@ def _kernel_os(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _kernel_psum(a_ref, b_ref, o_ref, *, k_axis: int):
-    """WS/IS: accumulate partial sums directly into the revisited out tile."""
-    prod = jnp.dot(a_ref[...], b_ref[...],
-                   preferred_element_type=jnp.float32).astype(o_ref.dtype)
-
-    @pl.when(pl.program_id(k_axis) == 0)
-    def _init():
-        o_ref[...] = prod
-
-    @pl.when(pl.program_id(k_axis) != 0)
-    def _acc():
-        o_ref[...] = o_ref[...] + prod
+def _kernel_partial(a_ref, b_ref, o_ref):
+    """WS/IS: one K block's partial product, written once."""
+    o_ref[0] = jnp.dot(a_ref[...], b_ref[...],
+                       preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def rsa_gemm_pallas(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int,
@@ -71,7 +65,6 @@ def rsa_gemm_pallas(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int,
     assert K == K2, (a.shape, b.shape)
     assert M % block_m == 0 and N % block_n == 0 and K % block_k == 0
     mt, nt, kt = M // block_m, N // block_n, K // block_k
-    out_shape = jax.ShapeDtypeStruct((M, N), a.dtype)
 
     if mode == OS:
         grid = (mt, nt, kt)
@@ -84,45 +77,38 @@ def rsa_gemm_pallas(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int,
             ],
             out_specs=pl.BlockSpec((block_m, block_n),
                                    lambda m, n, k: (m, n)),
-            out_shape=out_shape,
+            out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(a, b)
 
-    if mode == WS:
-        grid = (nt, kt, mt)       # B tile constant over the M sweep
-        return pl.pallas_call(
-            functools.partial(_kernel_psum, k_axis=1),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_m, block_k), lambda n, k, m: (m, k)),
-                pl.BlockSpec((block_k, block_n), lambda n, k, m: (k, n)),
-            ],
-            out_specs=pl.BlockSpec((block_m, block_n),
-                                   lambda n, k, m: (m, n)),
-            out_shape=out_shape,
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-            interpret=interpret,
-        )(a, b)
+    if mode in (WS, IS):
+        if mode == WS:            # B tile constant over the M sweep
+            grid, mnk = (nt, kt, mt), lambda n, k, m: (m, n, k)
+        else:                     # A tile constant over the N sweep
+            grid, mnk = (mt, kt, nt), lambda m, k, n: (m, n, k)
 
-    if mode == IS:
-        grid = (mt, kt, nt)       # A tile constant over the N sweep
-        return pl.pallas_call(
-            functools.partial(_kernel_psum, k_axis=1),
+        def at(index):            # an index map over (m, n, k), on the grid
+            return lambda *g: index(*mnk(*g))
+
+        # a single K block needs no sum: write it in the output dtype
+        parts = pl.pallas_call(
+            _kernel_partial,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((block_m, block_k), lambda m, k, n: (m, k)),
-                pl.BlockSpec((block_k, block_n), lambda m, k, n: (k, n)),
+                pl.BlockSpec((block_m, block_k), at(lambda m, n, k: (m, k))),
+                pl.BlockSpec((block_k, block_n), at(lambda m, n, k: (k, n))),
             ],
-            out_specs=pl.BlockSpec((block_m, block_n),
-                                   lambda m, k, n: (m, n)),
-            out_shape=out_shape,
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            out_specs=pl.BlockSpec((1, block_m, block_n),
+                                   at(lambda m, n, k: (k, m, n))),
+            out_shape=jax.ShapeDtypeStruct(
+                (kt, M, N), a.dtype if kt == 1 else jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(a, b)
+        return parts[0] if kt == 1 else parts.sum(0).astype(a.dtype)
 
     raise ValueError(f"unknown mode {mode}")

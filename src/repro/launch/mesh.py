@@ -7,22 +7,32 @@ can optionally host a 2-stage pipeline (ArchConfig.pipeline_stages=2).
 
 Defined as functions so importing this module never touches jax device state
 (the dry-run sets XLA_FLAGS before any jax import; smoke tests see 1 device).
+
+Every axis is ``AxisType.Auto``: GSPMD propagates shardings from the
+``NamedSharding`` placements and the ``hint`` constraints (parallel/hints.py).
+``jax.make_mesh`` would otherwise default to ``Explicit`` axes, under which
+untyped ops on sharded operands (the embedding gather) refuse to trace.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (CPU tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Mesh over the first ``data * model`` devices of this host."""
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict:

@@ -291,6 +291,8 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="fast CI smoke: tiny trace, assert completion")
     a = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if a.smoke and a.chaos is not None:
         # Chaos smoke: the same greedy workload served twice — fault-free,
         # then with every injector armed at boosted probabilities.  The
@@ -299,8 +301,8 @@ def main():
         # non-faulted request's tokens identical to the fault-free run.
         from repro.serving import ChaosConfig
         common = dict(
-            arch=a.arch, num_requests=4, num_slots=2, prompt_len=12,
-            gen=6, temperature=0.0, execute=a.execute,
+            arch=a.arch, preset=a.preset, num_requests=4, num_slots=2,
+            prompt_len=12, gen=6, temperature=0.0, execute=a.execute,
             dispatcher=a.dispatcher, adaptnet_ckpt=a.adaptnet_ckpt,
             kv_layout="paged", prefill_chunk=a.prefill_chunk or 8,
             sanitize=True, log=False)
@@ -334,8 +336,8 @@ def main():
         # token-for-token under greedy sampling while the cached run
         # actually reuses pages.
         common = dict(
-            arch=a.arch, num_requests=4, num_slots=2, prompt_len=24,
-            gen=6, temperature=0.0, execute=a.execute,
+            arch=a.arch, preset=a.preset, num_requests=4, num_slots=2,
+            prompt_len=24, gen=6, temperature=0.0, execute=a.execute,
             dispatcher=a.dispatcher, adaptnet_ckpt=a.adaptnet_ckpt,
             kv_layout="paged", prefill_chunk=a.prefill_chunk or 8,
             shared_prefix_len=16, defrag_threshold=a.defrag_threshold,
@@ -368,8 +370,8 @@ def main():
         # actually accepts draft tokens and commits more than one token
         # per verify step.
         common = dict(
-            arch=a.arch, num_requests=4, num_slots=2, prompt_len=12,
-            gen=6, temperature=0.0, execute=a.execute,
+            arch=a.arch, preset=a.preset, num_requests=4, num_slots=2,
+            prompt_len=12, gen=6, temperature=0.0, execute=a.execute,
             dispatcher=a.dispatcher, adaptnet_ckpt=a.adaptnet_ckpt,
             kv_layout="paged", prefill_chunk=a.prefill_chunk or 8,
             sanitize=a.sanitize, log=False)
@@ -398,8 +400,9 @@ def main():
         return
     if a.smoke:
         outputs, engine = serve_continuous(
-            arch=a.arch, num_requests=3, num_slots=2, prompt_len=12, gen=6,
-            temperature=0.0, execute=a.execute, dispatcher=a.dispatcher,
+            arch=a.arch, preset=a.preset, num_requests=3, num_slots=2,
+            prompt_len=12, gen=6, temperature=0.0, execute=a.execute,
+            dispatcher=a.dispatcher,
             adaptnet_ckpt=a.adaptnet_ckpt, kv_layout=a.kv_layout,
             trace_out=a.trace_out, sanitize=a.sanitize)
         assert all(len(v) == 6 for v in outputs.values()), outputs

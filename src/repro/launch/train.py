@@ -19,6 +19,45 @@ import jax
 import numpy as np
 
 
+def build_trainer(cfg, data_axis: int = 1, model_axis: int = 1, *,
+                  global_batch: int, seq_len: int, lr: float = 1e-3,
+                  seed: int = 0, execute: str = "auto", registry=None):
+    """Sharded initial state and the jitted train step on a
+    ``(data_axis, model_axis)`` mesh over this host's first devices.
+    Training keeps params, activations and optimizer state in float32.
+
+    Returns ``(params, opt_state, train_step, batch_sharding)``;
+    ``train_step(params, opt_state, batch) -> (params, opt_state, metrics)``
+    donates its state arguments and traces under the mesh and the dispatch
+    policy (recorded in ``registry`` under the ``train_step`` scope)."""
+    from repro.launch.mesh import make_host_mesh
+    from repro.launch.steps import (_dispatch_ctx, build_train_step,
+                                    make_optimizer)
+    from repro.configs.shapes import input_specs, ShapeSpec
+    from repro.parallel.hints import use_mesh
+    from repro.parallel.sharding import batch_specs, to_named
+
+    cfg = cfg.replace(param_dtype="float32", compute_dtype="float32",
+                      opt_state_dtype="float32")
+    mesh = make_host_mesh(data_axis, model_axis)
+    model, step_fn, _, (p_sh, o_sh) = build_train_step(cfg, mesh, lr=lr)
+    params = jax.device_put(model.init(jax.random.PRNGKey(seed)), p_sh)
+    opt_state = jax.device_put(make_optimizer(cfg, lr).init(params), o_sh)
+
+    shape = ShapeSpec("train", seq_len, global_batch, "train")
+    b_sh = to_named(batch_specs(input_specs(cfg, shape), mesh), mesh)
+    jitted = jax.jit(step_fn, in_shardings=(p_sh, o_sh, b_sh),
+                     out_shardings=(p_sh, o_sh, None),
+                     donate_argnums=(0, 1))
+
+    def train_step(params, opt_state, batch):
+        with use_mesh(mesh, cfg.tp_strategy), mesh, \
+                _dispatch_ctx("train_step", execute, registry):
+            return jitted(params, opt_state, batch)
+
+    return params, opt_state, train_step, b_sh
+
+
 def train_main(arch: str = "llama3.2-1b", preset: str = "reduced",
                steps: int = 50, global_batch: int = 8, seq_len: int = 128,
                data_axis: int = 1, model_axis: int = 1,
@@ -32,11 +71,6 @@ def train_main(arch: str = "llama3.2-1b", preset: str = "reduced",
     from repro import dispatch
     from repro.configs.registry import get_arch
     from repro.data.pipeline import make_loader
-    from repro.launch.mesh import make_host_mesh
-    from repro.launch.steps import _dispatch_ctx, build_train_step
-    from repro.configs.shapes import input_specs, ShapeSpec
-    from repro.parallel.hints import use_mesh
-    from repro.parallel.sharding import batch_specs, to_named
     from repro.runtime.driver import DriverConfig, TrainDriver
 
     cfg = override_cfg if override_cfg is not None else get_arch(arch)
@@ -48,23 +82,15 @@ def train_main(arch: str = "llama3.2-1b", preset: str = "reduced",
                           d_ff=4 * d_model)
     if num_layers:
         cfg = cfg.replace(num_layers=num_layers)
-    cfg = cfg.replace(param_dtype="float32", compute_dtype="float32",
-                      opt_state_dtype="float32")
 
-    mesh = make_host_mesh(data_axis, model_axis)
-    model, step_fn, (params_aval, opt_aval), (p_sh, o_sh) = \
-        build_train_step(cfg, mesh, lr=lr)
-
-    params = jax.device_put(model.init(jax.random.PRNGKey(seed)), p_sh)
-    from repro.launch.steps import make_optimizer
-    opt = make_optimizer(cfg, lr)
-    opt_state = jax.device_put(opt.init(params), o_sh)
-
-    shape = ShapeSpec("train", seq_len, global_batch, "train")
-    b_sh = to_named(batch_specs(input_specs(cfg, shape), mesh), mesh)
-    jitted = jax.jit(step_fn, in_shardings=(p_sh, o_sh, b_sh),
-                     out_shardings=(p_sh, o_sh, None),
-                     donate_argnums=(0, 1))
+    # the dispatch policy is consulted at trace time (first train_step
+    # call), so every training GEMM — fwd and the custom-VJP bwd pair —
+    # executes with the SARA-recommended configuration
+    registry = dispatch.SiteRegistry()
+    params, opt_state, train_step, b_sh = build_trainer(
+        cfg, data_axis, model_axis, global_batch=global_batch,
+        seq_len=seq_len, lr=lr, seed=seed, execute=execute,
+        registry=registry)
 
     loader = make_loader(cfg.vocab_size, seq_len, global_batch, seed=seed)
     batches = {}
@@ -80,20 +106,10 @@ def train_main(arch: str = "llama3.2-1b", preset: str = "reduced",
         arr = batches.get(step) or next(loader)
         return jax.device_put({"tokens": arr["tokens"]}, b_sh)
 
-    # the dispatch policy is consulted at trace time (first wrapped_step
-    # call), so every training GEMM — fwd and the custom-VJP bwd pair —
-    # executes with the SARA-recommended configuration
-    registry = dispatch.SiteRegistry()
-
-    def wrapped_step(params, opt_state, batch):
-        with use_mesh(mesh, cfg.tp_strategy), mesh, \
-                _dispatch_ctx("train_step", execute, registry):
-            return jitted(params, opt_state, batch)
-
     driver = TrainDriver(
         DriverConfig(checkpoint_dir=checkpoint_dir,
                      checkpoint_every=checkpoint_every),
-        train_step=wrapped_step, make_batch=make_batch,
+        train_step=train_step, make_batch=make_batch,
         fail_injector=fail_injector)
 
     t0 = time.time()
@@ -133,6 +149,8 @@ def main():
                     choices=["auto", "pallas", "xla"],
                     help="GEMM backend for the dispatch layer")
     a = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     train_main(arch=a.arch, preset=a.preset, steps=a.steps,
                global_batch=a.batch, seq_len=a.seq, data_axis=a.data_axis,
                model_axis=a.model_axis, lr=a.lr, checkpoint_dir=a.ckpt,

@@ -178,7 +178,7 @@ def _wkv_pallas_sharded(r, k, v, logw, u, state0, cfg: ArchConfig):
 
     S = r.shape[1]
     chunk = min(cfg.ssm.chunk, S)
-    kw = dict(chunk=chunk, interpret=True)
+    kw = dict(chunk=chunk)
     mesh = current_mesh()
     if mesh is None:
         return kops.wkv_attention(r, k, v, logw, u, state0, **kw)

@@ -79,7 +79,15 @@ def _causal_rows_ref(q, k_lin, v_lin, start, length):
 # kernel parity
 # ---------------------------------------------------------------------------
 
-def test_gqa_prefill_kernel_matches_reference_and_causal_dense():
+@pytest.mark.parametrize("row_block", [None, 4])
+def test_gqa_prefill_kernel_matches_reference_and_causal_dense(row_block,
+                                                              monkeypatch):
+    """``row_block=4`` splits each lane's C*G = 15 query rows into padded
+    4-row grid blocks that straddle chunk rows (the long-chunk path)."""
+    if row_block is not None:
+        from repro.kernels import paged_attn
+        monkeypatch.setattr(paged_attn, "ROW_BLOCK", row_block)
+        ops.paged_prefill_attention.clear_cache()
     rng = np.random.default_rng(0)
     S, KVH, G, hd = len(STARTS), 2, 3, 16
     lengths = STARTS + CHUNKS
@@ -94,6 +102,7 @@ def test_gqa_prefill_kernel_matches_reference_and_causal_dense():
     o_ref = ops.paged_prefill_attention(q, k, v, t, st, ln, impl="xla")
     o_pal = ops.paged_prefill_attention(q, k, v, t, st, ln, impl="pallas",
                                         interpret=True)
+    ops.paged_prefill_attention.clear_cache()   # drop the patched trace
     np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),
                                rtol=1e-5, atol=1e-5)
     for s in range(S):

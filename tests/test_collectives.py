@@ -12,10 +12,11 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, json, functools
     import numpy as np
     from jax.sharding import PartitionSpec as P, NamedSharding
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
+    from repro.launch.mesh import make_host_mesh
     from repro.parallel.collectives import quantized_psum
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_host_mesh(data=8)
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 1024)) * 3.0
 
     @functools.partial(shard_map, mesh=mesh,

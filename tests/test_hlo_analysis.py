@@ -13,8 +13,9 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, json
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.launch.hlo_analysis import analyze_hlo
+    from repro.launch.mesh import make_host_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(data=4, model=2)
 
     def f(x, w):
         def body(c, _):

@@ -66,6 +66,28 @@ def test_rsa_gemm_property_shapes(M, K, N, mode):
     np.testing.assert_allclose(np.asarray(out), 0.5 * K, rtol=1e-5)
 
 
+@pytest.mark.parametrize("mode", [OS, WS, IS], ids=["OS", "WS", "IS"])
+def test_rsa_gemm_output_blocks_never_revisited(mode):
+    """The TPU pipeline writes an output block back when the grid moves off
+    it and never reads it again, so a kernel may only accumulate into a
+    block over consecutive grid steps.  Interpret mode keeps the whole
+    output in memory and cannot show the difference; walk the grid the
+    way the TPU does instead."""
+    import itertools
+    from repro.kernels.rsa_gemm import rsa_gemm_pallas
+    a = jax.ShapeDtypeStruct((256, 256), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda x, y: rsa_gemm_pallas(
+        x, y, block_m=128, block_n=128, block_k=128, mode=mode))(a, a)
+    call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"]
+    out_map = grid.block_mappings[-1].index_map_jaxpr
+    visits = [tuple(int(i) for i in jax.core.eval_jaxpr(
+        out_map.jaxpr, out_map.consts, *step))
+        for step in itertools.product(*map(range, grid.grid))]
+    runs = [b for i, b in enumerate(visits) if i == 0 or visits[i - 1] != b]
+    assert len(runs) == len(set(runs)), visits
+
+
 # ---------------------------------------------------------------------------
 # adaptnetx
 # ---------------------------------------------------------------------------

@@ -63,6 +63,23 @@ def test_dispatcher_gemm_pallas_path():
                                np.asarray(x @ w), rtol=2e-4, atol=2e-4)
 
 
+def test_auto_backend_resolves_from_platform_and_mesh(monkeypatch):
+    """execute="auto" runs the compiled RSA kernel only where it can: on a
+    TPU, and not under a multi-device mesh (the compiler cannot partition
+    a Pallas kernel across devices)."""
+    from types import SimpleNamespace
+
+    from repro.dispatch.context import DispatchPolicy
+    from repro.parallel.hints import use_mesh
+    pol = DispatchPolicy(dispatcher=SaraDispatcher())
+    assert pol.backend() == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pol.backend() == "pallas"
+    for devices, want in ((1, "pallas"), (4, "xla")):
+        with use_mesh(SimpleNamespace(devices=np.empty((devices, 1)))):
+            assert pol.backend() == want
+
+
 def test_sharding_planner_sensible():
     # huge square GEMM -> use the whole mesh (2d)
     assert tcm.plan_gemm_sharding(8192, 8192, 8192).name in ("2d",)
