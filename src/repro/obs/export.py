@@ -16,7 +16,7 @@ open one).  Conventions:
 
 ``write_jsonl`` emits the same events one JSON object per line — the
 grep/pandas-friendly form — with a leading ``meta`` line carrying
-counters, gauges and per-scope wall times.
+counters and gauges.
 
 ``validate_trace`` is the schema gate used by ``scripts/check_trace.py``
 and the tests: structural checks plus the cross-event invariants the
@@ -67,9 +67,6 @@ def to_chrome_trace(rec: TraceRecorder,
             "displayTimeUnit": "ms",
             "otherData": {"counters": dict(rec.counters),
                           "gauges": dict(rec.gauges),
-                          "scope_wall_s": {k: {"calls": v[0],
-                                               "seconds": v[1]}
-                                           for k, v in rec.scope_wall.items()},
                           "dropped_events": rec.dropped,
                           **(meta or {})}}
 
@@ -82,13 +79,11 @@ def write_chrome_trace(path: str, rec: TraceRecorder,
 
 def write_jsonl(path: str, rec: TraceRecorder,
                 meta: Optional[Dict[str, Any]] = None) -> None:
-    """One JSON object per line: a ``meta`` record (counters / gauges /
-    per-scope wall time), then every buffered event in ts order."""
+    """One JSON object per line: a ``meta`` record (counters / gauges),
+    then every buffered event in ts order."""
     with open(path, "w") as f:
         head = {"record": "meta", "counters": dict(rec.counters),
                 "gauges": dict(rec.gauges),
-                "scope_wall_s": {k: {"calls": v[0], "seconds": v[1]}
-                                 for k, v in rec.scope_wall.items()},
                 "dropped_events": rec.dropped, **(meta or {})}
         f.write(json.dumps(head) + "\n")
         for ev in sorted(rec.events(), key=lambda e: e.ts):

@@ -24,6 +24,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, Optional
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 from repro.obs.trace import PH_SLICE, TraceError, TraceRecorder
 from repro.obs.trace import TraceEvent
 
@@ -146,35 +148,75 @@ class RequestTracker:
         self.closed += 1
 
 
+class _Phase:
+    """One step phase, opened and closed on both clocks together: a
+    ``TraceAnnotation`` on the profiler's clock (always on; about a
+    microsecond when no profiler session runs) and the recorder's slice
+    for the Perfetto export (behind the recorder's ``spans`` toggle)."""
+
+    __slots__ = ("_tm", "_span")
+
+    def __init__(self, tm: TraceAnnotation, span):
+        self._tm = tm
+        self._span = span
+
+    def __enter__(self) -> "_Phase":
+        self._tm.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._span.__exit__(*exc)
+        self._tm.__exit__(*exc)
+
+
 class StepTimeline:
-    """Engine-step timeline: one root slice per step on the ``engine``
-    track with sequential child phases (schedule / prefill / decode /
-    sample / sync).  Phases are measured with the recorder's monotonic
-    clock inside a single thread, so per-step phase slices are
-    monotonic and non-overlapping by construction."""
+    """Engine-step timeline: one root span per step with sequential child
+    phases (schedule / inputs / the step program / sync / readback /
+    sample / commit), written to two sinks from the same calls.
+
+    * The profiler trace: each step is a ``StepTraceAnnotation``
+      ``engine.step`` (``step_num`` plus the args given to ``end``), each
+      phase a ``TraceAnnotation`` ``engine.<phase>`` with its args as
+      xplane stats.  These are always on, so a ``jax.profiler.trace``
+      around a serve run puts the host's phases on the same clock as the
+      device's ops; profiler scopes must nest, so phases never overlap.
+    * The recorder: an ``engine_step`` root slice and one child slice per
+      phase on the ``engine`` track, for ``--trace-out``.  Phases are
+      measured with the recorder's monotonic clock inside a single
+      thread, so per-step phase slices are monotonic and non-overlapping
+      by construction."""
 
     def __init__(self, rec: TraceRecorder):
         self.rec = rec
         self.steps = 0
         self._open: Optional[float] = None
+        self._step: Optional[StepTraceAnnotation] = None
 
     def begin(self) -> int:
         if self._open is not None:
             raise TraceError("step span already open")
         self._open = self.rec.now()
+        self._step = StepTraceAnnotation("engine.step", step_num=self.steps)
+        self._step.__enter__()
         return self.steps
 
-    def phase(self, name: str, **args):
-        """``with tl.phase("decode"): ...`` — one child slice."""
+    def phase(self, name: str, **args) -> _Phase:
+        """``with tl.phase("paged_decode", lanes=4): ...`` — one child
+        span on both clocks."""
         if self._open is None:
             raise TraceError("phase() outside an open step")
-        return self.rec.span("step", name, track="engine", step=self.steps,
-                             **args)
+        return _Phase(TraceAnnotation(f"engine.{name}", **args),
+                      self.rec.span("step", name, track="engine",
+                                    step=self.steps, **args))
 
     def end(self, **args) -> None:
         if self._open is None:
             raise TraceError("step span not open")
         now = self.rec.now()
+        self._step.set_metadata(**args)
+        self._step.__exit__(None, None, None)
+        self._step = None
         if self.rec.spans:
             # root emitted after its children; the export sorts by ts so
             # Perfetto still nests the phases underneath it

@@ -15,10 +15,11 @@ Every event carries a *category* from :data:`CATEGORIES`:
   ``request``   per-request lifecycle spans (queue / active / prefill
                 chunks / first token) — see ``obs/spans.py``
   ``step``      engine-step timeline with phase breakdown (schedule /
-                prefill / decode / sample / sync)
+                inputs / the step program / sync / readback / sample /
+                commit); the same phases are always on the profiler's
+                clock as ``engine.*`` annotations (``obs/spans.py``)
   ``dispatch``  GEMM-site resolution at trace time (site, (M,K,N), chosen
-                tile, recommendation source, analytic cost) plus per-call
-                wall time of each traced scope
+                tile, recommendation source, analytic cost)
   ``compile``   a jit cache gained an entry (a retrace) — the raw signal
                 behind width-bucket / shape-diversity retrace storms
   ``arena``     KV block pool traffic (reserve / grow / free / defrag)
@@ -52,9 +53,9 @@ REQUIRED_CATEGORIES = ("request", "step", "dispatch", "compile", "arena")
 # obs-taxonomy pass enforces all four tuples statically at every
 # recorder call site, so a typo'd literal fails CI instead of silently
 # creating an orphan series.
-STEP_PHASES = ("schedule", "prefill", "prefill_chunk", "decode",
-               "paged_decode", "spec_draft", "spec_verify", "sample",
-               "sync")
+STEP_PHASES = ("schedule", "inputs", "prefill", "prefill_chunk", "decode",
+               "paged_decode", "spec_draft", "spec_verify", "sync",
+               "readback", "sample", "commit")
 
 COUNTERS = ("jit_compiles", "dispatch_records", "kv_defrag_auto",
             "shared_prefix_steps", "prefix_cache_inserted_pages",
@@ -138,9 +139,6 @@ class TraceRecorder:
         self.spans = spans
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, float] = {}
-        # per-scope wall-clock accumulation for the dispatch layer: the
-        # measured-runtime side of profile-calibrated dispatch
-        self.scope_wall: Dict[str, List[float]] = {}   # scope -> [calls, s]
         self.dropped = 0
         self._events: deque = deque(maxlen=capacity)
         self._t0 = time.perf_counter()
@@ -160,13 +158,6 @@ class TraceRecorder:
         if self.spans:
             self._append(TraceEvent("step", name, PH_COUNTER, self.now(),
                                     0.0, track, {"value": value}))
-
-    def add_scope_wall(self, scope: str, seconds: float) -> None:
-        """Attribute one traced-scope call's wall time (always on — this is
-        the per-site measured timing profile-calibrated dispatch needs)."""
-        cell = self.scope_wall.setdefault(scope, [0, 0.0])
-        cell[0] += 1
-        cell[1] += seconds
 
     # -- events (span recording) ---------------------------------------------
     def _append(self, ev: TraceEvent) -> None:
@@ -217,7 +208,6 @@ class TraceRecorder:
         self._events.clear()
         self.counters.clear()
         self.gauges.clear()
-        self.scope_wall.clear()
         self.dropped = 0
 
 

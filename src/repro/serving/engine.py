@@ -79,7 +79,7 @@ from __future__ import annotations
 import contextlib
 import time
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -276,6 +276,23 @@ class EngineConfig:
     # the same configs resumes mid-trace via ``restore()``.
     snapshot_dir: Optional[str] = None
     snapshot_every: int = 0
+
+
+class _PagedFeed(NamedTuple):
+    """One paged decode's inputs, built on the host before the call:
+    ``args`` after ``params`` (tokens, state, arena, tables, lengths,
+    write mask), the shared-prefix cascade's extra arrays (None without a
+    shared group), and what the step's accounting and the sanitizer
+    read back."""
+
+    args: tuple
+    shared: Optional[tuple]
+    width: int
+    kv_read: int
+    rids: list
+    tables: np.ndarray
+    gens: Optional[np.ndarray]
+    shared_pages: int = 0
 
 
 class ServingEngine:
@@ -590,26 +607,28 @@ class ServingEngine:
         context = req.context()
         n = int(context.shape[0])
         bucket = self.bucket_for(n)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :n] = context
-        batch = {"tokens": jnp.asarray(toks)}
-        if cfg.family == "vlm":
-            batch["patch_embeds"] = jnp.asarray(
-                (req.extras or {}).get(
-                    "patch_embeds",
-                    np.zeros((1, cfg.frontend.num_tokens,
-                              cfg.frontend.feature_dim), np.float32)),
-                jnp.dtype(cfg.compute_dtype))
-        if cfg.family == "encdec":
-            batch["src_features"] = jnp.asarray(
-                (req.extras or {}).get(
-                    "src_features",
-                    np.zeros((1, e.src_len, cfg.frontend.feature_dim),
-                             np.float32)),
-                jnp.dtype(cfg.compute_dtype))
+        with self.timeline.phase("inputs"):
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :n] = context
+            batch = {"tokens": jnp.asarray(toks)}
+            if cfg.family == "vlm":
+                batch["patch_embeds"] = jnp.asarray(
+                    (req.extras or {}).get(
+                        "patch_embeds",
+                        np.zeros((1, cfg.frontend.num_tokens,
+                                  cfg.frontend.feature_dim), np.float32)),
+                    jnp.dtype(cfg.compute_dtype))
+            if cfg.family == "encdec":
+                batch["src_features"] = jnp.asarray(
+                    (req.extras or {}).get(
+                        "src_features",
+                        np.zeros((1, e.src_len, cfg.frontend.feature_dim),
+                                 np.float32)),
+                    jnp.dtype(cfg.compute_dtype))
+            fresh = self.model.init_cache(1, self._prefill_rows,
+                                          src_len=e.src_len)
 
         scope = f"prefill:m{bucket}"
-        fresh = self.model.init_cache(1, self._prefill_rows, src_len=e.src_len)
         t0 = time.time()
         with self._dispatch_scope(scope), \
                 self.timeline.phase("prefill", rid=req.rid, bucket=bucket):
@@ -618,45 +637,49 @@ class ServingEngine:
         with self.timeline.phase("sync"):
             logits, new_cache = jax.block_until_ready((logits, new_cache))
         dt = time.time() - t0
-        self.obs.add_scope_wall(scope, dt)
-        self.req_spans.on_prefill_chunk(req.rid, n, dt, bucket=bucket)
         self._dispatch(scope)
-        if self.kv_layout == "paged":
-            # commit the prefilled KV rows into this request's pool pages
-            # (bucket-granularity scatter); the scratch dense cache is gone
-            # after this — only pages + the row count persist per slot
-            rows = n + self._fe_rows
-            nblk = self.pool.blocks_for(rows)
-            table = self.pool.table(req.rid).blocks
-            # saralint: ok[cow-gate] bucketed prefill writes only freshly alloc'd pages; this path never coexists with prefix-cache sharing (cache requires prefill_chunk)
-            self.arena.leaves = self._paged_write(
-                self.arena.leaves, new_cache["layers"],
-                jnp.asarray(table[:nblk], jnp.int32))
-            self._kv_rows[req.slot] = rows
-            self.metrics.on_prefill(
-                n, dt, kv_write_rows=nblk * e.block_size,
-                kv_write_rows_padded=bucket + self._fe_rows)
-            if cfg.family == "encdec":
-                self._state["cross_k"] = self._state["cross_k"].at[
-                    :, req.slot].set(new_cache["cross_k"][:, 0])
-                self._state["cross_v"] = self._state["cross_v"].at[
-                    :, req.slot].set(new_cache["cross_v"][:, 0])
-        else:
-            self.metrics.on_prefill(n, dt)
-            self._slot_restore(req.slot, new_cache)
-        req.prefill_pos = n
-        req.prefilling = False
-
-        self._key, k = jax.random.split(self._key)
-        tok = int(np.asarray(sample_logits(
-            k, logits, e.temperature, e.top_k))[0])
-        first = not req.generated
-        req.generated.append(tok)
-        self._last_tok[req.slot, 0] = tok
-        if first and req.t_first_token < 0:
-            req.t_first_token = self.now()
-            self.metrics.on_first_token(req.arrival_time, req.t_first_token)
-            self.req_spans.on_first_token(req.rid)
+        with self.timeline.phase("sample"):
+            self._key, k = jax.random.split(self._key)
+            tok = int(np.asarray(sample_logits(
+                k, logits, e.temperature, e.top_k))[0])
+        with self.timeline.phase("commit"):
+            self.req_spans.on_prefill_chunk(req.rid, n, dt, bucket=bucket)
+            if self.kv_layout == "paged":
+                # commit the prefilled KV rows into this request's pool
+                # pages (bucket-granularity scatter); the scratch dense
+                # cache is gone after this — only pages + the row count
+                # persist per slot
+                rows = n + self._fe_rows
+                nblk = self.pool.blocks_for(rows)
+                table = self.pool.table(req.rid).blocks
+                # saralint: ok[cow-gate] bucketed prefill writes only freshly alloc'd pages; this path never coexists with prefix-cache sharing (cache requires prefill_chunk)
+                self.arena.leaves = self._paged_write(
+                    self.arena.leaves, new_cache["layers"],
+                    jnp.asarray(table[:nblk], jnp.int32))
+                self._kv_rows[req.slot] = rows
+                self.metrics.on_prefill(
+                    n, dt, kv_write_rows=nblk * e.block_size,
+                    kv_write_rows_padded=bucket + self._fe_rows)
+                if cfg.family == "encdec":
+                    self._state["cross_k"] = self._state["cross_k"].at[
+                        :, req.slot].set(new_cache["cross_k"][:, 0])
+                    self._state["cross_v"] = self._state["cross_v"].at[
+                        :, req.slot].set(new_cache["cross_v"][:, 0])
+            else:
+                self.metrics.on_prefill(n, dt)
+                self._slot_restore(req.slot, new_cache)
+            req.prefill_pos = n
+            req.prefilling = False
+            first = not req.generated
+            req.generated.append(tok)
+            self._last_tok[req.slot, 0] = tok
+            if first and req.t_first_token < 0:
+                req.t_first_token = self.now()
+                self.metrics.on_first_token(req.arrival_time,
+                                            req.t_first_token)
+                self.req_spans.on_first_token(req.rid)
+            if req.done():
+                self._retire(req)
 
     def _do_chunk_prefills(self) -> None:
         """One chunked-prefill step over every mid-prefill lane.
@@ -676,53 +699,76 @@ class ServingEngine:
         lanes = {s: r for s, r in self.sched.active.items() if r.prefilling}
         if not lanes:
             return
-        toks = np.zeros((S, C), np.int32)
-        chunk = np.zeros((S,), np.int32)
-        for slot, req in sorted(lanes.items()):
-            n = min(C, req.context_len - req.prefill_pos)
-            # the coming chunk writes n KV rows: the block table must cover
-            # them (chunk-incremental reservation extends here; a failed
-            # extension stalls the lane's prefill until pages free up)
-            if not self.sched.grow(req, req.prefill_pos + n):
-                self.metrics.stalls += 1
-                continue
-            if not self._cow_chunk_pages(req, req.prefill_pos, n):
-                self.metrics.stalls += 1
-                continue
-            ctx = req.context()
-            toks[slot, :n] = ctx[req.prefill_pos:req.prefill_pos + n]
-            chunk[slot] = n
-        if not chunk.any():
-            return                       # every prefilling lane stalled
-        kv = np.where(chunk > 0, self._kv_rows, 0).astype(np.int32)
-        # fixed table width -> the chunk step compiles exactly once.  Unlike
-        # decode (where narrow tables ARE the read-scaling win), a chunk
-        # must attend over its lane's whole prefix anyway, and dead table
-        # columns cost (almost) nothing in the kernel: the DMA is elided
-        # for repeated trailing ids and `j*bs < kv_len` skips the compute.
-        width = self._max_blocks_per_slot
-        rids = [lanes[s].rid if chunk[s] > 0 else None for s in range(S)]
-        tables = self.pool.dense_block_table(rids, width)
+        with self.timeline.phase("inputs"):
+            toks = np.zeros((S, C), np.int32)
+            chunk = np.zeros((S,), np.int32)
+            for slot, req in sorted(lanes.items()):
+                n = min(C, req.context_len - req.prefill_pos)
+                # the coming chunk writes n KV rows: the block table must
+                # cover them (chunk-incremental reservation extends here; a
+                # failed extension stalls the lane's prefill until pages
+                # free up)
+                if not self.sched.grow(req, req.prefill_pos + n):
+                    self.metrics.stalls += 1
+                    continue
+                if not self._cow_chunk_pages(req, req.prefill_pos, n):
+                    self.metrics.stalls += 1
+                    continue
+                ctx = req.context()
+                toks[slot, :n] = ctx[req.prefill_pos:req.prefill_pos + n]
+                chunk[slot] = n
+            if not chunk.any():
+                return                   # every prefilling lane stalled
+            kv = np.where(chunk > 0, self._kv_rows, 0).astype(np.int32)
+            # fixed table width -> the chunk step compiles exactly once.
+            # Unlike decode (where narrow tables ARE the read-scaling win),
+            # a chunk must attend over its lane's whole prefix anyway, and
+            # dead table columns cost (almost) nothing in the kernel: the
+            # DMA is elided for repeated trailing ids and `j*bs < kv_len`
+            # skips the compute.
+            width = self._max_blocks_per_slot
+            rids = [lanes[s].rid if chunk[s] > 0 else None for s in range(S)]
+            tables = self.pool.dense_block_table(rids, width)
+            args = (jnp.asarray(toks), self.arena.leaves, jnp.asarray(tables),
+                    jnp.asarray(kv), jnp.asarray(chunk))
 
         scope = "prefill_chunk"
         t0 = time.time()
         with self._dispatch_scope(scope), \
                 self.timeline.phase("prefill_chunk",
-                                    lanes=int((chunk > 0).sum())):
-            logits, leaves = self._chunk_prefill(
-                self.params, jnp.asarray(toks), self.arena.leaves,
-                jnp.asarray(tables), jnp.asarray(kv), jnp.asarray(chunk))
+                                    lanes=int((chunk > 0).sum()),
+                                    live_rows=int(chunk.sum()),
+                                    rows=S * C):
+            logits, leaves = self._chunk_prefill(self.params, *args)
         with self.timeline.phase("sync"):
             logits, leaves = jax.block_until_ready((logits, leaves))
         dt = time.time() - t0
-        self.obs.add_scope_wall(scope, dt)
         self.arena.leaves = leaves
         self._dispatch(scope)
+
+        # only a lane whose FINAL chunk landed this step consumes logits;
+        # skip the key split + sampling entirely when none did (keeps the
+        # hot loop lean and the RNG stream free of discarded draws)
+        sampled = None
+        if any(chunk[s] and r.prefill_pos + chunk[s] >= r.context_len
+               for s, r in lanes.items()):
+            with self.timeline.phase("sample"):
+                self._key, k = jax.random.split(self._key)
+                sampled = np.asarray(sample_logits(
+                    k, logits, e.temperature, e.top_k))
+        with self.timeline.phase("commit"):
+            self._commit_chunks(lanes, chunk, dt, sampled)
+
+    def _commit_chunks(self, lanes: Dict[int, Request], chunk: np.ndarray,
+                       dt: float, sampled: Optional[np.ndarray]) -> None:
+        """Advance every lane by its chunk; a lane whose prompt completed
+        takes its first token from ``sampled``, is indexed in the prefix
+        cache, and retires if that token ends it."""
+        e = self.ecfg
         for slot, req in sorted(lanes.items()):
             if chunk[slot] > 0:
                 self.req_spans.on_prefill_chunk(req.rid, int(chunk[slot]),
                                                 dt, pos=req.prefill_pos)
-
         total = int(chunk.sum())
         # padded-bucket equivalent accrues proportionally per chunk
         # (telescoping integer shares that sum to bucket_for(ctx) over a
@@ -739,15 +785,6 @@ class ServingEngine:
             padded += (b * (pos + n)) // ctx - (b * pos) // ctx
         self.metrics.on_prefill(total, dt, kv_write_rows=total,
                                 kv_write_rows_padded=padded)
-        # only a lane whose FINAL chunk landed this step consumes logits;
-        # skip the key split + sampling entirely when none did (keeps the
-        # hot loop lean and the RNG stream free of discarded draws)
-        sampled = None
-        if any(chunk[s] and r.prefill_pos + chunk[s] >= r.context_len
-               for s, r in lanes.items()):
-            self._key, k = jax.random.split(self._key)
-            sampled = np.asarray(sample_logits(
-                k, logits, e.temperature, e.top_k))
         for slot, req in sorted(lanes.items()):
             n = int(chunk[slot])
             if n == 0:
@@ -929,6 +966,7 @@ class ServingEngine:
         delay = self.ecfg.retry_backoff_s
         for attempt in range(retries + 1):
             fault = None
+            stalls, preempts = self.metrics.stalls, self.metrics.preemptions
             self.timeline.begin()
             try:
                 self._step_body()
@@ -947,8 +985,11 @@ class ServingEngine:
                 self.obs.gauge("kv_fragmentation", self.pool.fragmentation())
                 self.obs.gauge("slot_occupancy",
                                len(self.sched.active) / e.num_slots)
-                self.timeline.end(active=len(self.sched.active),
-                                  waiting=self.sched.pending())
+                self.timeline.end(
+                    active=len(self.sched.active),
+                    waiting=self.sched.pending(),
+                    stalls=self.metrics.stalls - stalls,
+                    preempts=self.metrics.preemptions - preempts)
             if fault is None:
                 break
             if self._contain_fault(fault):
@@ -1032,8 +1073,6 @@ class ServingEngine:
             for req in [r for _, r in sorted(self.sched.active.items())
                         if r.prefilling]:
                 self._do_prefill(req)
-                if req.done():
-                    self._retire(req)
 
         # a request can finish at prefill (first token == budget/EOS) and
         # chunked lanes may still be mid-prefill, so re-check the planned
@@ -1044,24 +1083,29 @@ class ServingEngine:
         if active and self.spec is not None:
             self._spec_decode_step(active)
         elif active:
-            # decide stalls BEFORE decoding: the coming step writes the KV of
-            # each lane's pending token, so its block table must cover
-            # prompt + generated tokens
             snaps = {}
-            for slot, req in active.items():
-                if not self.sched.grow(req,
-                                       req.prompt_len + len(req.generated)):
-                    self.metrics.stalls += 1
-                    if self.kv_layout == "dense":
-                        snaps[slot] = self._slot_snapshot(slot)
-            if self.chaos is not None:
-                # after grow() (which clears stalled on success), so a
-                # forced stall survives into this step's decode mask
-                self._inject_decode_chaos(active, snaps)
+            with self.timeline.phase("inputs"):
+                # decide stalls BEFORE decoding: the coming step writes the
+                # KV of each lane's pending token, so its block table must
+                # cover prompt + generated tokens
+                for slot, req in active.items():
+                    if not self.sched.grow(
+                            req, req.prompt_len + len(req.generated)):
+                        self.metrics.stalls += 1
+                        if self.kv_layout == "dense":
+                            snaps[slot] = self._slot_snapshot(slot)
+                if self.chaos is not None:
+                    # after grow() (which clears stalled on success), so a
+                    # forced stall survives into this step's decode mask
+                    self._inject_decode_chaos(active, snaps)
+                if self.kv_layout == "paged":
+                    feed = self._paged_decode_inputs(active)
+                else:
+                    toks = jnp.asarray(self._last_tok)[:, :, None]  # (S,1,1)
             if self.kv_layout == "paged":
-                logits, dt, kv_read = self._decode_paged(active)
+                logits, dt = self._decode_paged(active, feed)
+                kv_read = feed.kv_read
             else:
-                toks = jnp.asarray(self._last_tok)[:, :, None]  # (S, 1, 1)
                 t0 = time.time()
                 with self._dispatch_scope("decode"), \
                         self.timeline.phase("decode", lanes=len(active)):
@@ -1071,7 +1115,6 @@ class ServingEngine:
                     logits, self._cache = jax.block_until_ready(
                         (logits, cache))
                 dt = time.time() - t0
-                self.obs.add_scope_wall("decode", dt)
                 logits = logits[:, 0, :]
                 kv_read = self._dense_kv_rows
             self._dispatch("decode")
@@ -1079,6 +1122,7 @@ class ServingEngine:
                 self._key, k = jax.random.split(self._key)
                 sampled = np.asarray(sample_logits(
                     k, logits, self.ecfg.temperature, self.ecfg.top_k))
+            with self.timeline.phase("commit"):
                 committed = 0
                 for slot, req in sorted(active.items()):
                     if req.stalled:
@@ -1100,10 +1144,10 @@ class ServingEngine:
                         self.req_spans.on_first_token(req.rid)
                     if req.done():
                         self._retire(req)
-            self.metrics.on_decode_step(
-                len(active), self.ecfg.num_slots, committed, dt,
-                kv_read_tokens=kv_read,
-                kv_read_tokens_dense=self._dense_kv_rows)
+                self.metrics.on_decode_step(
+                    len(active), self.ecfg.num_slots, committed, dt,
+                    kv_read_tokens=kv_read,
+                    kv_read_tokens_dense=self._dense_kv_rows)
         # every live lane stalled — whether on a decode-step block-table
         # extension or a prefill-chunk one — preempt the newest request so
         # the rest can make progress
@@ -1111,9 +1155,10 @@ class ServingEngine:
                 all(r.stalled for r in self.sched.active.values()):
             self._preempt_newest()
 
-    def _decode_paged(self, active: Dict[int, Request]):
-        """One batched decode over every lane through the page arena.
-        Returns (logits (S, V), seconds, KV rows actually streamed)."""
+    def _paged_decode_inputs(self, active: Dict[int, Request]) -> _PagedFeed:
+        """The host side of one batched paged decode: per-lane lengths and
+        write mask, block tables at the step's width, and their device
+        copies."""
         e = self.ecfg
         S = e.num_slots
         wm = np.zeros((S,), np.int32)
@@ -1139,44 +1184,53 @@ class ServingEngine:
         # replayed after the kernel to trap tables that outlived a free
         gens = (self.pool.table_generations(rids, width)
                 if e.sanitize else None)
-        toks = jnp.asarray(self._last_tok)                   # (S, 1)
         self.obs.gauge("decode_table_width", width)
+        args = (jnp.asarray(self._last_tok), self._state, self.arena.leaves,
+                jnp.asarray(tables), jnp.asarray(kv), jnp.asarray(wm))
         group = None
         if self._paged_shared_decode is not None:
             group = self._shared_prefix_group(active, kv, wm)
+        if group is None:
+            return _PagedFeed(args, None, width, e.block_size * sum(need),
+                              rids, tables, gens)
+        prefix_pages, prefix_lens, utables, ulens, kv_read, npages = group
+        self.obs.count("shared_prefix_steps", 1)
+        self.obs.gauge("shared_prefix_lanes", int((prefix_lens > 0).sum()))
+        shared = (jnp.asarray(prefix_pages), jnp.asarray(prefix_lens),
+                  jnp.asarray(utables), jnp.asarray(ulens))
+        return _PagedFeed(args, shared, width, kv_read, rids, tables, gens,
+                          npages)
+
+    def _decode_paged(self, active: Dict[int, Request], feed: _PagedFeed):
+        """One batched decode over every lane through the page arena.
+        Returns (logits (S, V) on the host, seconds)."""
         t0 = time.time()
-        if group is not None:
-            prefix_pages, prefix_lens, utables, ulens, kv_read, npages = group
-            self.obs.count("shared_prefix_steps", 1)
-            self.obs.gauge("shared_prefix_lanes",
-                           int((prefix_lens > 0).sum()))
+        if feed.shared is not None:
             with self._dispatch_scope("decode"), \
                     self.timeline.phase("paged_decode", lanes=len(active),
-                                        width=width, shared_pages=npages):
+                                        width=feed.width,
+                                        kv_rows=feed.kv_read,
+                                        shared_pages=feed.shared_pages):
                 # saralint: ok[cow-gate] decode appends one row into the lane's exclusively-owned tail page; shared prefix pages cover only rows < kv_len
                 logits, leaves = self._paged_shared_decode(
-                    self.params, toks, self._state, self.arena.leaves,
-                    jnp.asarray(tables), jnp.asarray(kv), jnp.asarray(wm),
-                    jnp.asarray(prefix_pages), jnp.asarray(prefix_lens),
-                    jnp.asarray(utables), jnp.asarray(ulens))
+                    self.params, *feed.args, *feed.shared)
         else:
-            kv_read = e.block_size * sum(need)
             with self._dispatch_scope("decode"), \
                     self.timeline.phase("paged_decode", lanes=len(active),
-                                        width=width):
+                                        width=feed.width,
+                                        kv_rows=feed.kv_read):
                 # saralint: ok[cow-gate] decode appends one row into the lane's exclusively-owned tail page; shared prefix pages cover only rows < kv_len
-                logits, leaves = self._paged_decode(
-                    self.params, toks, self._state, self.arena.leaves,
-                    jnp.asarray(tables), jnp.asarray(kv), jnp.asarray(wm))
+                logits, leaves = self._paged_decode(self.params, *feed.args)
         with self.timeline.phase("sync"):
             logits, leaves = jax.block_until_ready((logits, leaves))
         dt = time.time() - t0
-        self.obs.add_scope_wall("decode", dt)
         self.arena.leaves = leaves
-        logits = np.asarray(logits)
-        if e.sanitize:
-            self._sanitize_decode(active, rids, tables, gens, logits)
-        return logits, dt, kv_read
+        with self.timeline.phase("readback"):
+            logits = np.asarray(logits)
+        if self.ecfg.sanitize:
+            self._sanitize_decode(active, feed.rids, feed.tables, feed.gens,
+                                  logits)
+        return logits, dt
 
     def _sanitize_decode(self, active: Dict[int, Request],
                          rids, tables, gens, logits: np.ndarray) -> None:
@@ -1225,22 +1279,24 @@ class ServingEngine:
         # never draft past the remaining budget; reserve verify rows
         # [L-1, L-1+k] up front, degrading k -> 0 before stalling
         quota: Dict[int, int] = {}
-        for slot, req in sorted(active.items()):
-            L = req.prompt_len + len(req.generated)
-            k = max(0, min(K, req.max_new_tokens - len(req.generated) - 1))
-            if not self.sched.grow(req, L + k):
-                k = 0
-                if not self.sched.grow(req, L):
+        with self.timeline.phase("inputs"):
+            for slot, req in sorted(active.items()):
+                L = req.prompt_len + len(req.generated)
+                k = max(0, min(K,
+                               req.max_new_tokens - len(req.generated) - 1))
+                if not self.sched.grow(req, L + k):
+                    k = 0
+                    if not self.sched.grow(req, L):
+                        self.metrics.stalls += 1
+                quota[slot] = k
+            if self.chaos is not None:
+                self._inject_decode_chaos(active, {})
+            for slot, req in sorted(active.items()):
+                if req.stalled:
+                    continue             # writes nothing: no fork needed
+                L = req.prompt_len + len(req.generated)
+                if not self._cow_chunk_pages(req, L - 1, quota[slot] + 1):
                     self.metrics.stalls += 1
-            quota[slot] = k
-        if self.chaos is not None:
-            self._inject_decode_chaos(active, {})
-        for slot, req in sorted(active.items()):
-            if req.stalled:
-                continue                 # writes nothing: no fork needed
-            L = req.prompt_len + len(req.generated)
-            if not self._cow_chunk_pages(req, L - 1, quota[slot] + 1):
-                self.metrics.stalls += 1
 
         draft_lanes = {s: (r, quota[s]) for s, r in active.items()
                        if not r.stalled and quota[s] > 0}
@@ -1254,7 +1310,6 @@ class ServingEngine:
                                         lanes=len(draft_lanes)):
                 drafts, preempts = self.spec.draft(draft_lanes)
             dt_draft = time.time() - t0
-            self.obs.add_scope_wall("spec_draft", dt_draft)
             if preempts:
                 self.obs.count("spec_draft_preempts", preempts)
 
@@ -1263,46 +1318,48 @@ class ServingEngine:
         # process), rows 1..k are the drafts; stalled lanes ride along
         # with chunk 0 (rows land in the trash page, logits ignored).
         C = K + 1
-        toks = np.zeros((S, C), np.int32)
-        clens = np.zeros((S,), np.int32)
-        for slot, req in sorted(active.items()):
-            if req.stalled:
-                continue
-            d = drafts.get(slot, [])
-            toks[slot, 0] = self._last_tok[slot, 0]
-            toks[slot, 1:1 + len(d)] = d
-            clens[slot] = 1 + len(d)
-        kv = np.where(clens > 0, self._kv_rows, 0).astype(np.int32)
-        width = self._max_blocks_per_slot
-        rids = [active[s].rid if s in active and clens[s] > 0 else None
-                for s in range(S)]
-        tables = self.pool.dense_block_table(rids, width)
-        gens = (self.pool.table_generations(rids, width)
-                if e.sanitize else None)
-        kv_read = e.block_size * sum(
-            self.pool.blocks_for(int(kv[s]) + int(clens[s]))
-            for s in range(S))
+        with self.timeline.phase("inputs"):
+            toks = np.zeros((S, C), np.int32)
+            clens = np.zeros((S,), np.int32)
+            for slot, req in sorted(active.items()):
+                if req.stalled:
+                    continue
+                d = drafts.get(slot, [])
+                toks[slot, 0] = self._last_tok[slot, 0]
+                toks[slot, 1:1 + len(d)] = d
+                clens[slot] = 1 + len(d)
+            kv = np.where(clens > 0, self._kv_rows, 0).astype(np.int32)
+            width = self._max_blocks_per_slot
+            rids = [active[s].rid if s in active and clens[s] > 0 else None
+                    for s in range(S)]
+            tables = self.pool.dense_block_table(rids, width)
+            gens = (self.pool.table_generations(rids, width)
+                    if e.sanitize else None)
+            kv_read = e.block_size * sum(
+                self.pool.blocks_for(int(kv[s]) + int(clens[s]))
+                for s in range(S))
+            args = (jnp.asarray(toks), self.arena.leaves, jnp.asarray(tables),
+                    jnp.asarray(kv), jnp.asarray(clens))
         t0 = time.time()
         with self._dispatch_scope("spec_verify"), \
                 self.timeline.phase("spec_verify",
                                     lanes=int((clens > 0).sum()),
                                     width=width):
             # saralint: ok[cow-gate] verify rows are COW-forked above (_cow_chunk_pages over [L-1, L-1+k]) before this write
-            logits, leaves = self._spec_verify(
-                self.params, jnp.asarray(toks), self.arena.leaves,
-                jnp.asarray(tables), jnp.asarray(kv), jnp.asarray(clens))
+            logits, leaves = self._spec_verify(self.params, *args)
         with self.timeline.phase("sync"):
             logits, leaves = jax.block_until_ready((logits, leaves))
         dt = time.time() - t0
-        self.obs.add_scope_wall("spec_verify", dt)
         self.arena.leaves = leaves
         self._dispatch("spec_verify")
-        logits = np.asarray(logits)          # (S, C, V)
+        with self.timeline.phase("readback"):
+            logits = np.asarray(logits)      # (S, C, V)
         if e.sanitize:
             self._sanitize_spec(active, rids, tables, gens, logits, clens)
 
         with self.timeline.phase("sample"):
             argm = np.argmax(logits, -1)     # (S, C) greedy verify picks
+        with self.timeline.phase("commit"):
             committed = accepted = bonus = drafted = live = 0
             for slot, req in sorted(active.items()):
                 if req.stalled:
@@ -1558,19 +1615,6 @@ class ServingEngine:
         return step
 
     # -- observability export -------------------------------------------------
-    def site_timings(self) -> Dict[str, Dict]:
-        """Measured wall time per traced scope joined with the sites that
-        scope executes — the raw material for profile-calibrated dispatch
-        (the ROADMAP item this subsystem feeds): every (site, M, K, N,
-        tile) gets the wall-clock of the compiled call it ran inside."""
-        out: Dict[str, Dict] = {}
-        for scope, (calls, secs) in self.obs.scope_wall.items():
-            sites = {name: {"m": r.m, "k": r.k, "n": r.n,
-                            "tile": r.describe(), "source": r.source}
-                     for name, r in self.registry.sites(scope).items()}
-            out[scope] = {"calls": calls, "seconds": secs, "sites": sites}
-        return out
-
     def export_trace(self, path: str) -> str:
         """Write the trace as Chrome/Perfetto trace-event JSON at ``path``
         plus a structured JSONL sibling (``.jsonl``).  Loadable in
@@ -1578,8 +1622,7 @@ class ServingEngine:
         docs/OBSERVABILITY.md.  Returns the JSONL path."""
         meta = {"arch": self.cfg.name, "kv_layout": self.kv_layout,
                 "prefill_chunk": self.prefill_chunk,
-                "dispatcher_mode": self.ecfg.dispatcher_mode,
-                "site_timings": self.site_timings()}
+                "dispatcher_mode": self.ecfg.dispatcher_mode}
         write_chrome_trace(path, self.obs, meta)
         jsonl = (path[:-5] if path.endswith(".json") else path) + ".jsonl"
         write_jsonl(jsonl, self.obs, meta)

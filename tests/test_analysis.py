@@ -215,6 +215,27 @@ def test_obs_taxonomy_checks_literals_against_declarations(tmp_path):
     assert all(f.severity == "error" for f in found)
 
 
+def test_obs_taxonomy_reads_the_real_step_phases(tmp_path):
+    """The program's own taxonomy: every engine step phase is declared,
+    and a typo of one is refused."""
+    from repro.obs import trace
+    write(tmp_path, "obs/trace.py", Path(trace.__file__).read_text())
+    p = write(tmp_path, "serving/emit.py", """\
+        def step(timeline):
+            timeline.phase("inputs")
+            timeline.phase("prefill_chunk")
+            timeline.phase("paged_decode")
+            timeline.phase("readback")
+            timeline.phase("commit")
+            timeline.phase("readbak")
+        """)
+    found = by_check(run_paths([str(tmp_path)]), "obs-taxonomy")
+    assert [f.line for f in found] == [lineno(p, '"readbak"')]
+    assert set(trace.STEP_PHASES) >= {"schedule", "inputs", "prefill_chunk",
+                                      "paged_decode", "sync", "readback",
+                                      "sample", "commit"}
+
+
 def test_obs_taxonomy_skips_without_trace_module(tmp_path):
     write(tmp_path, "serving/emit.py", """\
         def record(obs):

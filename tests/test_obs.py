@@ -15,6 +15,7 @@
 """
 
 import json
+import re
 
 import numpy as np
 import pytest
@@ -57,13 +58,6 @@ def test_ring_capacity_drops_oldest_and_counts():
     assert len(rec) == 4
     assert rec.dropped == 6
     assert [e.name for e in rec.events()] == ["e6", "e7", "e8", "e9"]
-
-
-def test_scope_wall_accrues():
-    rec = TraceRecorder()
-    rec.add_scope_wall("decode", 0.25)
-    rec.add_scope_wall("decode", 0.75)
-    assert rec.scope_wall["decode"] == [2, 1.0]
 
 
 # ---------------------------------------------------------------------------
@@ -294,12 +288,94 @@ def test_traced_engine_run_exports_all_categories(tmp_path):
     doc = json.loads(p.read_text())
     assert validate_trace(doc, require_categories=REQUIRED_CATEGORIES) == []
     assert doc["otherData"]["counters"]["jit_compiles"] >= 2
-    assert doc["otherData"]["site_timings"]            # scope wall joined
     assert (tmp_path / "trace.jsonl").exists() and jsonl.endswith(".jsonl")
 
     # satellite: retrace counter surfaced for benchmark assertions
     assert eng.dispatch_stats()["jit_compiles"] == \
         doc["otherData"]["counters"]["jit_compiles"]
-    # timings substrate: every traced scope accrued wall time
-    st = eng.site_timings()
-    assert all(v["seconds"] > 0 and v["calls"] > 0 for v in st.values())
+    # the registry still names every executed site of every traced scope
+    assert eng.registry.scopes()
+    assert all(eng.registry.sites(scope) for scope in eng.registry.scopes())
+
+
+# ---------------------------------------------------------------------------
+# step phases on the profiler's clock
+# ---------------------------------------------------------------------------
+
+# one step: schedule, then per step program its host inputs, the jitted
+# call, the sync, the logits readback (paged decode), sampling (when a
+# lane takes a token) and the commit
+_STEP_GRAMMAR = re.compile(
+    r"schedule(,inputs(,(prefill|prefill_chunk|decode|paged_decode),sync"
+    r"(,readback)?(,sample)?,commit)?)*")
+
+
+def _host_spans(trace_dir, prefix):
+    import glob
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+                        for ev in line.events if ev.name.startswith(prefix)]
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+@pytest.mark.parametrize("layout,chunk", [("paged", 8), ("dense", None)])
+def test_step_phases_on_profiler_clock(tmp_path, layout, chunk):
+    """Every step is an ``engine.step`` on the profiler's clock, with no
+    EngineConfig.trace: its phases nest inside it, never overlap, come in
+    the step's order, and a chunk step's row args are the engine's."""
+    import jax
+
+    from repro.configs.registry import get_arch
+    from repro.serving import EngineConfig, Request, ServingEngine
+
+    cfg = get_arch(ARCH).reduced()
+    eng = ServingEngine(cfg, EngineConfig(
+        num_slots=2, max_len=48, block_size=8, temperature=0.0,
+        max_prefills_per_step=2, kv_layout=layout, prefill_chunk=chunk))
+    chunks = []
+    if chunk:
+        real = eng._chunk_prefill
+
+        def spy(params, toks, leaves, tables, kv, n):
+            chunks.append(np.asarray(n))
+            return real(params, toks, leaves, tables, kv, n)
+
+        eng._chunk_prefill = spy
+    rng = np.random.default_rng(1)
+    reqs = [Request(f"r{i}", rng.integers(0, cfg.vocab_size, n).astype(
+        np.int32), 3) for i, n in enumerate((21, 5, 13))]
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run(reqs)
+    assert not eng.obs.spans and len(eng.obs) == 0   # recorder stayed off
+
+    spans = _host_spans(tmp_path, "engine.")
+    steps = [s for s in spans if s[0] == "engine.step"]
+    assert [s[3]["step_num"] for s in steps] == list(range(eng._step_idx))
+    assert all({"stalls", "preempts", "active", "waiting"} <= set(s[3])
+               for s in steps)
+    phases = [s for s in spans if s[0] != "engine.step"]
+    owned = 0
+    for _, t0, t1, _ in steps:
+        kids = [p for p in phases if t0 <= p[1] and p[2] <= t1]
+        owned += len(kids)
+        for a, b in zip(kids, kids[1:]):
+            assert a[2] <= b[1], (a[0], b[0])       # siblings never overlap
+        names = ",".join(p[0][len("engine."):] for p in kids)
+        assert _STEP_GRAMMAR.fullmatch(names), names
+    assert owned == len(phases)                     # no phase outside a step
+
+    if chunk:
+        got = [p[3] for p in phases if p[0] == "engine.prefill_chunk"]
+        assert [a["live_rows"] for a in got] == [int(n.sum()) for n in chunks]
+        assert [a["lanes"] for a in got] == [int((n > 0).sum())
+                                             for n in chunks]
+        assert {a["rows"] for a in got} == {2 * chunk}
+        decodes = [p[3] for p in phases if p[0] == "engine.paged_decode"]
+        assert decodes and all(a["kv_rows"] > 0 and a["width"] >= 1
+                               for a in decodes)
