@@ -364,17 +364,18 @@ def init_gqa_cache(cfg: ArchConfig, batch: int, max_len: int, dtype) -> KVCache:
 # ---------------------------------------------------------------------------
 
 def gqa_paged_decode(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
-                     cfg: ArchConfig, *, k_arena, v_arena, block_tables,
+                     cfg: ArchConfig, *, k_arena, v_arena, layer, block_tables,
                      kv_lens, write_mask):
     """One-token batched decode through the paged KV arena.
 
     x: (S, 1, d) — one pending token per lane; positions: (S, 1);
-    k_arena/v_arena: (NB, bs, KVH, hd) physical pages (trailing block is the
-    write-discard scratch); block_tables: (S, W) int32 pages in logical
-    order; kv_lens: (S,) tokens already in the arena; write_mask: (S,) int32
-    — 1 writes the new token's KV and attends over kv_len+1 tokens, 0
-    leaves the arena unchanged (the lane's output is discarded by the
-    engine).  Returns (out (S, 1, d), new_k_arena, new_v_arena).
+    k_arena/v_arena: (L, NB, bs, KVH, hd) the whole stack's physical pages
+    (trailing block is the write-discard scratch), of which this layer is
+    ``layer``; block_tables: (S, W) int32 pages in logical order; kv_lens:
+    (S,) tokens already in the arena; write_mask: (S,) int32 — 1 writes the
+    new token's KV and attends over kv_len+1 tokens, 0 leaves the arena
+    unchanged (the lane's output is discarded by the engine).  Returns
+    (out (S, 1, d), new_k_arena, new_v_arena), both still stacked.
     """
     cdt = jnp.dtype(cfg.compute_dtype)
     q, k, v = _proj_qkv(params, x, x, cfg, cdt)
@@ -382,16 +383,17 @@ def gqa_paged_decode(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
     k = apply_rope(k, positions, cfg.rope_theta)
 
     from repro.kernels import ops as kops
-    NB, bs = k_arena.shape[0], k_arena.shape[1]
+    NB, bs = k_arena.shape[1], k_arena.shape[2]
     # decode is the C=1 case of the chunk write: write_mask doubles as the
     # 0/1 chunk length (masked lanes land in the trash block)
     wm = (write_mask > 0).astype(kv_lens.dtype)
     rows = _paged_chunk_rows(block_tables, kv_lens, wm, 1, bs, NB)
     # saralint: ok[cow-gate] decode appends at row kv_len of the lane's exclusively-owned tail page (or the trash block when masked); shared prefix pages cover only rows < kv_len
-    k_arena = _arena_write_chunk(k_arena, rows, k[:, :1])
-    v_arena = _arena_write_chunk(v_arena, rows, v[:, :1])
+    k_arena = _arena_write_chunk(k_arena, layer, rows, k[:, :1])
+    v_arena = _arena_write_chunk(v_arena, layer, rows, v[:, :1])
     attn_len = kv_lens + wm
-    o = kops.paged_attention(q[:, 0], k_arena, v_arena, block_tables,
+    o = kops.paged_attention(q[:, 0], _arena_layer(k_arena, layer),
+                             _arena_layer(v_arena, layer), block_tables,
                              attn_len, logit_cap=cfg.attn_logit_softcap)
     S = x.shape[0]
     out = hint(o.reshape(S, 1, cfg.q_dim), "B", None, "M")
@@ -402,7 +404,7 @@ def gqa_paged_decode(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
 
 def gqa_paged_shared_decode(params: Params, x: jnp.ndarray,
                             positions: jnp.ndarray, cfg: ArchConfig, *,
-                            k_arena, v_arena, block_tables, kv_lens,
+                            k_arena, v_arena, layer, block_tables, kv_lens,
                             write_mask, prefix_pages, prefix_lens,
                             unique_tables, unique_lens):
     """Cascade-decode twin of :func:`gqa_paged_decode`: the KV *write* goes
@@ -421,15 +423,16 @@ def gqa_paged_shared_decode(params: Params, x: jnp.ndarray,
     k = apply_rope(k, positions, cfg.rope_theta)
 
     from repro.kernels import ops as kops
-    NB, bs = k_arena.shape[0], k_arena.shape[1]
+    NB, bs = k_arena.shape[1], k_arena.shape[2]
     wm = (write_mask > 0).astype(kv_lens.dtype)
     rows = _paged_chunk_rows(block_tables, kv_lens, wm, 1, bs, NB)
     # saralint: ok[cow-gate] decode appends at row kv_len of the lane's exclusively-owned tail page (or the trash block when masked); shared prefix pages cover only rows < kv_len
-    k_arena = _arena_write_chunk(k_arena, rows, k[:, :1])
-    v_arena = _arena_write_chunk(v_arena, rows, v[:, :1])
+    k_arena = _arena_write_chunk(k_arena, layer, rows, k[:, :1])
+    v_arena = _arena_write_chunk(v_arena, layer, rows, v[:, :1])
     o = kops.shared_paged_attention(
-        q[:, 0], k_arena, v_arena, unique_tables, unique_lens,
-        prefix_pages, prefix_lens, logit_cap=cfg.attn_logit_softcap)
+        q[:, 0], _arena_layer(k_arena, layer), _arena_layer(v_arena, layer),
+        unique_tables, unique_lens, prefix_pages, prefix_lens,
+        logit_cap=cfg.attn_logit_softcap)
     S = x.shape[0]
     out = hint(o.reshape(S, 1, cfg.q_dim), "B", None, "M")
     out = hint(dense(out, params["wo"], None, cdt, site="layer.attn.out"),
@@ -455,33 +458,44 @@ def _paged_chunk_rows(tables, kv_lens, chunk_lens, num_rows: int,
     return jnp.where(valid, rows, (num_blocks - 1) * block_size)
 
 
-def _arena_write_chunk(arena: jnp.ndarray, rows: jnp.ndarray,
+def _arena_write_chunk(arena: jnp.ndarray, layer, rows: jnp.ndarray,
                        new: jnp.ndarray):
-    """Scatter C new rows per lane into the flattened (NB*bs) arena.
-    rows: (S, C); new: (S, C, *feat).  Masked rows all target the trash
-    block's row 0 — colliding writes there are fine, it is discard space."""
-    NB, bs = arena.shape[0], arena.shape[1]
-    flat = arena.reshape((NB * bs,) + arena.shape[2:])
-    flat = flat.at[rows.reshape(-1)].set(
+    """Scatter C new rows per lane into layer ``layer`` of the stacked
+    (L, NB, bs, *feat) arena, seen flat as (L*NB*bs) rows, so the stack
+    is updated in place and never sliced out and written back.
+    rows: (S, C) rows within the layer; new: (S, C, *feat).  Masked rows
+    all target the trash block's row 0 — colliding writes there are fine,
+    it is discard space."""
+    L, NB, bs = arena.shape[:3]
+    flat = arena.reshape((L * NB * bs,) + arena.shape[3:])
+    flat = flat.at[(layer * (NB * bs) + rows).reshape(-1)].set(
         new.reshape((-1,) + new.shape[2:]).astype(arena.dtype))
     return flat.reshape(arena.shape)
 
 
+def _arena_layer(arena: jnp.ndarray, layer) -> jnp.ndarray:
+    """Layer ``layer``'s (NB, bs, *feat) pages of the stacked arena: the
+    operand a paged kernel reads."""
+    return jax.lax.dynamic_index_in_dim(arena, layer, 0, keepdims=False)
+
+
 def gqa_paged_prefill(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
-                      cfg: ArchConfig, *, k_arena, v_arena, block_tables,
-                      kv_lens, chunk_lens):
+                      cfg: ArchConfig, *, k_arena, v_arena, layer,
+                      block_tables, kv_lens, chunk_lens):
     """Chunked-prefill attention through the paged KV arena.
 
     x: (S, C, d) — one prompt chunk per lane; positions: (S, C) absolute;
-    k_arena/v_arena: (NB, bs, KVH, hd) physical pages (trailing block is
-    the write-discard scratch); block_tables: (S, W) int32 pages in logical
+    k_arena/v_arena: (L, NB, bs, KVH, hd) the stack's physical pages
+    (trailing block is the write-discard scratch), of which this layer is
+    ``layer``; block_tables: (S, W) int32 pages in logical
     order; kv_lens: (S,) rows already committed per lane (the chunk's
     absolute start); chunk_lens: (S,) valid new rows — rows at or past a
     lane's chunk length write to the trash block and their outputs are
     garbage the caller discards (ragged batch: one call serves
     heterogeneous prompt lengths).  The chunk's K/V rows are written into
     the arena *before* attention, so chunk queries see their own keys
-    causally.  Returns (out (S, C, d), new_k_arena, new_v_arena).
+    causally.  Returns (out (S, C, d), new_k_arena, new_v_arena), both
+    still stacked.
     """
     cdt = jnp.dtype(cfg.compute_dtype)
     q, k, v = _proj_qkv(params, x, x, cfg, cdt)
@@ -489,15 +503,16 @@ def gqa_paged_prefill(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
     k = apply_rope(k, positions, cfg.rope_theta)
 
     from repro.kernels import ops as kops
-    NB, bs = k_arena.shape[0], k_arena.shape[1]
+    NB, bs = k_arena.shape[1], k_arena.shape[2]
     S, C = x.shape[0], x.shape[1]
     rows = _paged_chunk_rows(block_tables, kv_lens, chunk_lens, C, bs, NB)
     # saralint: ok[cow-gate] chunk rows target pages the engine COW-forked via _cow_chunk_pages before this jitted body runs
-    k_arena = _arena_write_chunk(k_arena, rows, k)
-    v_arena = _arena_write_chunk(v_arena, rows, v)
+    k_arena = _arena_write_chunk(k_arena, layer, rows, k)
+    v_arena = _arena_write_chunk(v_arena, layer, rows, v)
     attn_len = kv_lens + chunk_lens
-    o = kops.paged_prefill_attention(q, k_arena, v_arena, block_tables,
-                                     kv_lens, attn_len,
+    o = kops.paged_prefill_attention(q, _arena_layer(k_arena, layer),
+                                     _arena_layer(v_arena, layer),
+                                     block_tables, kv_lens, attn_len,
                                      logit_cap=cfg.attn_logit_softcap)
     out = hint(o.reshape(S, C, cfg.q_dim), "B", None, "M")
     out = hint(dense(out, params["wo"], None, cdt, site="layer.attn.out"),
@@ -531,7 +546,7 @@ def _mla_mix_latent(o_lat, w_uv, cdt, *, site: str):
 
 
 def mla_paged_prefill(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
-                      cfg: ArchConfig, *, ckv_arena, krope_arena,
+                      cfg: ArchConfig, *, ckv_arena, krope_arena, layer,
                       block_tables, kv_lens, chunk_lens):
     """Absorbed-MLA chunked prefill through the paged latent arena.
 
@@ -539,8 +554,8 @@ def mla_paged_prefill(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
     are absorbed through W_UK before the kernel and the latent mix goes
     through W_UV/W_O after — the same formulation as
     :func:`mla_paged_decode`, widened to C causal rows per lane.  Shapes as
-    in :func:`gqa_paged_prefill` with ckv_arena (NB, bs, kv_lora_rank) and
-    krope_arena (NB, bs, qk_rope_head_dim).
+    in :func:`gqa_paged_prefill` with ckv_arena (L, NB, bs, kv_lora_rank)
+    and krope_arena (L, NB, bs, qk_rope_head_dim).
     """
     m = cfg.mla
     cdt = jnp.dtype(cfg.compute_dtype)
@@ -550,18 +565,19 @@ def mla_paged_prefill(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
     c_kv, k_rope = _mla_ckv(params, x, positions, cfg, cdt)    # (S,C,r/rd)
 
     from repro.kernels import ops as kops
-    NB, bs = ckv_arena.shape[0], ckv_arena.shape[1]
+    NB, bs = ckv_arena.shape[1], ckv_arena.shape[2]
     rows = _paged_chunk_rows(block_tables, kv_lens, chunk_lens, C, bs, NB)
     # saralint: ok[cow-gate] chunk rows target pages the engine COW-forked via _cow_chunk_pages before this jitted body runs
-    ckv_arena = _arena_write_chunk(ckv_arena, rows, c_kv)
-    krope_arena = _arena_write_chunk(krope_arena, rows, k_rope)
+    ckv_arena = _arena_write_chunk(ckv_arena, layer, rows, c_kv)
+    krope_arena = _arena_write_chunk(krope_arena, layer, rows, k_rope)
 
     w_uk = params["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_abs = _mla_absorb_q(q_nope, w_uk, cdt, site="layer.mla.q_absorb")
     attn_len = kv_lens + chunk_lens
     o_lat = kops.mla_paged_prefill_attention(
-        q_abs, q_rope, ckv_arena, krope_arena, block_tables, kv_lens,
-        attn_len, qk_dim=m.qk_nope_head_dim + m.qk_rope_head_dim)
+        q_abs, q_rope, _arena_layer(ckv_arena, layer),
+        _arena_layer(krope_arena, layer), block_tables, kv_lens, attn_len,
+        qk_dim=m.qk_nope_head_dim + m.qk_rope_head_dim)
     w_uv = params["w_uv"].reshape(m.kv_lora_rank, H, m.v_head_dim)
     out = _mla_mix_latent(o_lat, w_uv, cdt, site="layer.mla.v_mix")
     out = out.reshape(S, C, H * m.v_head_dim)
@@ -570,15 +586,15 @@ def mla_paged_prefill(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
 
 
 def mla_paged_decode(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
-                     cfg: ArchConfig, *, ckv_arena, krope_arena, block_tables,
-                     kv_lens, write_mask):
+                     cfg: ArchConfig, *, ckv_arena, krope_arena, layer,
+                     block_tables, kv_lens, write_mask):
     """Absorbed-MLA batched decode through the paged latent arena.
 
     The arena stores the compressed (c_kv, k_rope) rows only (the same
     ~70 KB/token layout as the dense absorbed path); queries are absorbed
     through W_UK before the kernel and the latent mix goes through W_UV/W_O
     after.  Shapes as in :func:`gqa_paged_decode` with ckv_arena
-    (NB, bs, kv_lora_rank) and krope_arena (NB, bs, qk_rope_head_dim).
+    (L, NB, bs, kv_lora_rank) and krope_arena (L, NB, bs, qk_rope_head_dim).
     """
     m = cfg.mla
     cdt = jnp.dtype(cfg.compute_dtype)
@@ -588,19 +604,21 @@ def mla_paged_decode(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
     c_kv, k_rope = _mla_ckv(params, x, positions, cfg, cdt)    # (S,1,r/rd)
 
     from repro.kernels import ops as kops
-    NB, bs = ckv_arena.shape[0], ckv_arena.shape[1]
+    NB, bs = ckv_arena.shape[1], ckv_arena.shape[2]
     wm = (write_mask > 0).astype(kv_lens.dtype)
     rows = _paged_chunk_rows(block_tables, kv_lens, wm, 1, bs, NB)
     # saralint: ok[cow-gate] decode appends at row kv_len of the lane's exclusively-owned tail page (or the trash block when masked); shared prefix pages cover only rows < kv_len
-    ckv_arena = _arena_write_chunk(ckv_arena, rows, c_kv[:, :1])
-    krope_arena = _arena_write_chunk(krope_arena, rows, k_rope[:, :1])
+    ckv_arena = _arena_write_chunk(ckv_arena, layer, rows, c_kv[:, :1])
+    krope_arena = _arena_write_chunk(krope_arena, layer, rows,
+                                     k_rope[:, :1])
 
     w_uk = params["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_abs = _mla_absorb_q(q_nope, w_uk, cdt,
                           site="layer.mla.q_absorb")[:, 0]
     attn_len = kv_lens + wm
     o_lat = kops.mla_paged_attention(
-        q_abs, q_rope[:, 0], ckv_arena, krope_arena, block_tables, attn_len,
+        q_abs, q_rope[:, 0], _arena_layer(ckv_arena, layer),
+        _arena_layer(krope_arena, layer), block_tables, attn_len,
         qk_dim=m.qk_nope_head_dim + m.qk_rope_head_dim)       # (S, H, r)
     w_uv = params["w_uv"].reshape(m.kv_lora_rank, H, m.v_head_dim)
     out = _mla_mix_latent(o_lat[:, None], w_uv, cdt,

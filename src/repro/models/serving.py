@@ -162,23 +162,31 @@ def paged_prefill_write(arena: Dict[str, Any], layers_cache: KVCache,
             "v": put(arena["v"], layers_cache.v)}
 
 
-def _scan_paged_layers(body, x, params: Params, arena: Dict[str, Any]):
-    """Scan a decoder stack's layer body over per-layer arena pages,
-    splitting the layer axis for MoE models with a leading dense stack
-    (deepseek-v3).  ``body(h, (layer_p, k_pages, v_pages)) -> (h, (nk,
-    nv))``; returns (x, {"k": nk, "v": nv})."""
+def _scan_paged_layers(layer_fn, x, params: Params, arena: Dict[str, Any],
+                       extra: Tuple = ()):
+    """Scan a decoder stack's layers with the stacked arena in the carry.
+
+    ``layer_fn(h, layer_p, k, v, layer, *extra_l) -> (h, k, v)`` takes the
+    whole (L, NB, bs, ...) leaves and its layer index, scatters the step's
+    new rows into them at ``layer`` and returns them, so the arena is
+    updated in place through the loop (and, donated by the caller's jit,
+    aliases the program's output) instead of being sliced per layer and
+    stacked back.  A leading dense stack (deepseek-v3) runs first on the
+    same carry, so the layer index runs on across both stacks.  ``extra``:
+    per-layer xs of the main stack (encdec's cross K/V).  Returns (x,
+    {"k": k, "v": v})."""
+
+    def body(carry, xs):
+        h, k, v, layer = carry
+        h, k, v = layer_fn(h, xs[0], k, v, layer, *xs[1:])
+        return (h, k, v, layer + 1), None
+
+    carry = (x, arena["k"], arena["v"], jnp.zeros((), jnp.int32))
     if "dense_layers" in params:
-        nd = jax.tree_util.tree_leaves(params["dense_layers"])[0].shape[0]
-        x, (hk, hv) = jax.lax.scan(
-            body, x, (params["dense_layers"], arena["k"][:nd],
-                      arena["v"][:nd]))
-        x, (tk, tv) = jax.lax.scan(
-            body, x, (params["layers"], arena["k"][nd:], arena["v"][nd:]))
-        return x, {"k": jnp.concatenate([hk, tk], axis=0),
-                   "v": jnp.concatenate([hv, tv], axis=0)}
-    x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], arena["k"],
-                                         arena["v"]))
-    return x, {"k": nk, "v": nv}
+        carry, _ = jax.lax.scan(body, carry, (params["dense_layers"],))
+    (x, k, v, _), _ = jax.lax.scan(body, carry,
+                                   (params["layers"],) + tuple(extra))
+    return x, {"k": k, "v": v}
 
 
 def paged_decode_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
@@ -203,27 +211,16 @@ def paged_decode_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
     positions = kv_lens[:, None]
     wm = write_mask.astype(jnp.int32)
 
-    def body(h, xs):
-        if fam == "encdec":
-            layer_p, ak, av, ck, cv = xs
-            enc_kv = (ck, cv)
-        else:
-            layer_p, ak, av = xs
-            enc_kv = None
-        h, nk, nv = paged_decoder_layer_apply(
-            layer_p, h, positions, cfg, k_arena=ak, v_arena=av,
+    def layer_fn(h, layer_p, ak, av, layer, *enc_kv):
+        return paged_decoder_layer_apply(
+            layer_p, h, positions, cfg, k_arena=ak, v_arena=av, layer=layer,
             block_tables=block_tables, kv_lens=kv_lens, write_mask=wm,
-            enc_kv=enc_kv)
-        return h, (nk, nv)
+            enc_kv=enc_kv or None)
 
-    body = _maybe_remat(body, cfg)
-    if fam == "encdec":
-        x, (nk, nv) = jax.lax.scan(
-            body, x, (params["layers"], arena["k"], arena["v"],
-                      state["cross_k"], state["cross_v"]))
-        new_arena = {"k": nk, "v": nv}
-    else:
-        x, new_arena = _scan_paged_layers(body, x, params, arena)
+    extra = ((state["cross_k"], state["cross_v"]) if fam == "encdec"
+             else ())
+    x, new_arena = _scan_paged_layers(_maybe_remat(layer_fn, cfg), x,
+                                      params, arena, extra)
 
     x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
     return _lm_head(params, x[:, -1, :], cfg), new_arena
@@ -256,17 +253,15 @@ def paged_shared_decode_step(params: Params, tokens: jnp.ndarray,
     positions = kv_lens[:, None]
     wm = write_mask.astype(jnp.int32)
 
-    def body(h, xs):
-        layer_p, ak, av = xs
-        h, nk, nv = paged_shared_decoder_layer_apply(
-            layer_p, h, positions, cfg, k_arena=ak, v_arena=av,
+    def layer_fn(h, layer_p, ak, av, layer):
+        return paged_shared_decoder_layer_apply(
+            layer_p, h, positions, cfg, k_arena=ak, v_arena=av, layer=layer,
             block_tables=block_tables, kv_lens=kv_lens, write_mask=wm,
             prefix_pages=prefix_pages, prefix_lens=prefix_lens,
             unique_tables=unique_tables, unique_lens=unique_lens)
-        return h, (nk, nv)
 
-    body = _maybe_remat(body, cfg)
-    x, new_arena = _scan_paged_layers(body, x, params, arena)
+    x, new_arena = _scan_paged_layers(_maybe_remat(layer_fn, cfg), x,
+                                      params, arena)
 
     x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
     return _lm_head(params, x[:, -1, :], cfg), new_arena
@@ -298,16 +293,14 @@ def paged_prefill_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
     x = _embed(params, tokens, cfg)
     positions = kv_lens[:, None] + jnp.arange(C)[None, :]
 
-    def body(h, xs):
-        layer_p, ak, av = xs
-        h, nk, nv = paged_prefill_layer_apply(
-            layer_p, h, positions, cfg, k_arena=ak, v_arena=av,
+    def layer_fn(h, layer_p, ak, av, layer):
+        return paged_prefill_layer_apply(
+            layer_p, h, positions, cfg, k_arena=ak, v_arena=av, layer=layer,
             block_tables=block_tables, kv_lens=kv_lens,
             chunk_lens=chunk_lens)
-        return h, (nk, nv)
 
-    body = _maybe_remat(body, cfg)
-    x, new_arena = _scan_paged_layers(body, x, params, arena)
+    x, new_arena = _scan_paged_layers(_maybe_remat(layer_fn, cfg), x,
+                                      params, arena)
 
     x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
     last = jnp.clip(chunk_lens - 1, 0, C - 1)
@@ -339,16 +332,14 @@ def paged_verify_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
     x = _embed(params, tokens, cfg)
     positions = kv_lens[:, None] + jnp.arange(C)[None, :]
 
-    def body(h, xs):
-        layer_p, ak, av = xs
-        h, nk, nv = paged_prefill_layer_apply(
-            layer_p, h, positions, cfg, k_arena=ak, v_arena=av,
+    def layer_fn(h, layer_p, ak, av, layer):
+        return paged_prefill_layer_apply(
+            layer_p, h, positions, cfg, k_arena=ak, v_arena=av, layer=layer,
             block_tables=block_tables, kv_lens=kv_lens,
             chunk_lens=chunk_lens)
-        return h, (nk, nv)
 
-    body = _maybe_remat(body, cfg)
-    x, new_arena = _scan_paged_layers(body, x, params, arena)
+    x, new_arena = _scan_paged_layers(_maybe_remat(layer_fn, cfg), x,
+                                      params, arena)
 
     x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
     return _lm_head(params, x, cfg), new_arena

@@ -105,16 +105,17 @@ def decoder_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
 
 
 def paged_decoder_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
-                              k_arena, v_arena, block_tables, kv_lens,
+                              k_arena, v_arena, layer, block_tables, kv_lens,
                               write_mask, enc_kv=None):
     """One decoder layer's batched single-token decode through the paged KV
     arena (mirrors :func:`decoder_layer_apply`; see
-    models/attention.py::gqa_paged_decode for the arena contract).
+    models/attention.py::gqa_paged_decode for the arena contract: the
+    stacked arena, of which this is layer ``layer``).
     Returns (x, new_k_arena, new_v_arena)."""
     from repro.models.attention import gqa_paged_decode, mla_paged_decode
 
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    paged = dict(block_tables=block_tables, kv_lens=kv_lens,
+    paged = dict(layer=layer, block_tables=block_tables, kv_lens=kv_lens,
                  write_mask=write_mask)
     if cfg.attention_type == "mla":
         a, nk, nv = mla_paged_decode(p["attn"], h, positions, cfg,
@@ -139,7 +140,7 @@ def paged_decoder_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
 
 def paged_shared_decoder_layer_apply(p: Params, x, positions,
                                      cfg: ArchConfig, *, k_arena, v_arena,
-                                     block_tables, kv_lens, write_mask,
+                                     layer, block_tables, kv_lens, write_mask,
                                      prefix_pages, prefix_lens,
                                      unique_tables, unique_lens):
     """Cascade-decode twin of :func:`paged_decoder_layer_apply`: attention
@@ -152,9 +153,9 @@ def paged_shared_decoder_layer_apply(p: Params, x, positions,
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     a, nk, nv = gqa_paged_shared_decode(
         p["attn"], h, positions, cfg, k_arena=k_arena, v_arena=v_arena,
-        block_tables=block_tables, kv_lens=kv_lens, write_mask=write_mask,
-        prefix_pages=prefix_pages, prefix_lens=prefix_lens,
-        unique_tables=unique_tables, unique_lens=unique_lens)
+        layer=layer, block_tables=block_tables, kv_lens=kv_lens,
+        write_mask=write_mask, prefix_pages=prefix_pages,
+        prefix_lens=prefix_lens, unique_tables=unique_tables, unique_lens=unique_lens)
     x = x + a.astype(x.dtype)
     h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
     if "moe" in p:
@@ -166,7 +167,7 @@ def paged_shared_decoder_layer_apply(p: Params, x, positions,
 
 
 def paged_prefill_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
-                              k_arena, v_arena, block_tables, kv_lens,
+                              k_arena, v_arena, layer, block_tables, kv_lens,
                               chunk_lens):
     """One decoder layer's chunked-prefill pass through the paged KV arena
     (mirrors :func:`paged_decoder_layer_apply` widened to C causal rows per
@@ -175,7 +176,7 @@ def paged_prefill_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
     from repro.models.attention import gqa_paged_prefill, mla_paged_prefill
 
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    paged = dict(block_tables=block_tables, kv_lens=kv_lens,
+    paged = dict(layer=layer, block_tables=block_tables, kv_lens=kv_lens,
                  chunk_lens=chunk_lens)
     if cfg.attention_type == "mla":
         a, nk, nv = mla_paged_prefill(p["attn"], h, positions, cfg,

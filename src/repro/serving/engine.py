@@ -425,16 +425,19 @@ class ServingEngine:
             self._state = self.model.init_paged_state(e.num_slots,
                                                       src_len=e.src_len)
             self._kv_rows = np.zeros((e.num_slots,), np.int32)
+            # every step program donates the arena it is handed: the new
+            # rows are written into it in place and the result replaces
+            # arena.leaves at once, so no path reads a donated leaf
             self._paged_decode = JitWatch(
-                jax.jit(self.model.paged_decode_step), "paged_decode",
-                self.obs)
+                jax.jit(self.model.paged_decode_step, donate_argnums=(3,)),
+                "paged_decode", self.obs)
             self._paged_write = JitWatch(
-                jax.jit(self.model.paged_prefill_write), "paged_write",
-                self.obs)
+                jax.jit(self.model.paged_prefill_write, donate_argnums=(0,)),
+                "paged_write", self.obs)
             if self.prefill_chunk is not None:
                 self._chunk_prefill = JitWatch(
-                    jax.jit(self.model.paged_prefill_step), "chunk_prefill",
-                    self.obs)
+                    jax.jit(self.model.paged_prefill_step,
+                            donate_argnums=(2,)), "chunk_prefill", self.obs)
             self._paged_shared_decode = None
             if e.shared_prefix_decode:
                 if self.prefix_cache is None:
@@ -446,7 +449,8 @@ class ServingEngine:
                         "shared_prefix_decode is GQA-only (absorbed MLA "
                         "keeps the plain paged decode)")
                 self._paged_shared_decode = JitWatch(
-                    jax.jit(self.model.paged_shared_decode_step),
+                    jax.jit(self.model.paged_shared_decode_step,
+                            donate_argnums=(3,)),
                     "paged_shared_decode", self.obs)
             self._cache = None
         else:
@@ -492,8 +496,8 @@ class ServingEngine:
                 chunk=self.prefill_chunk, spec_k=e.spec_k,
                 recorder=self.obs)
             self._spec_verify = JitWatch(
-                jax.jit(self.model.paged_verify_step), "spec_verify",
-                self.obs)
+                jax.jit(self.model.paged_verify_step, donate_argnums=(2,)),
+                "spec_verify", self.obs)
         # what one masked-dense decode step would stream: every slot's full
         # capacity (recurrent-state families have no KV rows to speak of)
         self._dense_kv_rows = (e.num_slots * self._cache_len

@@ -149,9 +149,10 @@ class SpecDecoder:
             self.pool.attach_recorder(recorder)
         self._state = self.model.init_paged_state(num_slots)
         self._rows: Dict[str, int] = {}
+        # both draft programs donate the draft arena, as the target's do
         self._draft_prefill = JitWatch(
-            jax.jit(self.model.paged_prefill_step), "spec_draft_prefill",
-            recorder)
+            jax.jit(self.model.paged_prefill_step, donate_argnums=(2,)),
+            "spec_draft_prefill", recorder)
 
         # all k draft rounds fused into one dispatch: feed each round's
         # greedy argmax back inside a lax.scan, so drafting costs one
@@ -171,8 +172,8 @@ class SpecDecoder:
                 body, (first, leaves, kv), jnp.arange(self.spec_k))
             return toks, leaves      # toks: (spec_k, S)
 
-        self._draft_loop = JitWatch(jax.jit(_loop), "spec_draft_loop",
-                                    recorder)
+        self._draft_loop = JitWatch(jax.jit(_loop, donate_argnums=(3,)),
+                                    "spec_draft_loop", recorder)
 
     # -- lifecycle ------------------------------------------------------------
     def rows(self, rid: str) -> int:
