@@ -19,16 +19,16 @@ import json  # noqa: E402
 import sys  # noqa: E402
 
 
-def control_gaps(cfg, seed, picked):
-    """The numbers compared, read for the tokens that the float8 reference
-    puts first at the positions the program served (their gaps in the
-    float32 reference)."""
+def control_gaps(model, cfg, seed, picked):
+    """The numbers compared, read for the tokens that the model's float8
+    reference puts first at the positions the program served (their gaps
+    in the float32 reference)."""
     import numpy as np
     from harness import gap_numbers, served
-    from reference import logit_gaps, served_logits
+    from reference import logit_gaps
     seqs, rows, _ = served(picked)
-    ref = served_logits(seed, cfg, seqs, rows)
-    low = served_logits(seed, cfg, seqs, rows, precision="fp8")
+    ref = model.served_logits(seed, cfg, seqs, rows)
+    low = model.served_logits(seed, cfg, seqs, rows, precision="fp8")
     gaps = [logit_gaps(a, b.argmax(-1)) for a, b in zip(ref, low)]
     return gap_numbers(np.concatenate(gaps))
 
@@ -53,8 +53,9 @@ def main() -> int:
         picked = harness.sample(run, seed)
         del engine
         gc.collect()
-        row = {"seed": seed, "program": harness.compare(cfg, seed, picked),
-               "control": control_gaps(cfg, seed, picked)}
+        row = {"seed": seed,
+               "program": harness.compare(cell.model, cfg, seed, picked),
+               "control": control_gaps(cell.model, cfg, seed, picked)}
         row["seconds"] = time.perf_counter() - t0
         rows.append(row)
         print(json.dumps(row), file=sys.stderr, flush=True)

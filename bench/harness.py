@@ -6,6 +6,9 @@ Everything that belongs to one configuration, traffic mix, cell or per-layer
 metric is a file of its own, found by name:
 
 - ``BENCHMARK.json``: the cells, their metrics and the configuration files;
+- ``bench/models/<model_type>.py``: a model family's weights, plain
+  reference and counts, by the ``model_type`` its configuration file states
+  (the interface is in ``bench/models/llama.py``'s docstring);
 - ``bench/traffic/<mix>.json``: the mix's parameters (``traffic.py``);
 - ``bench/limits/<cell>.json``: the limit of each number compared;
 - ``bench/metrics/<metric>.py``: a reader ``read(run) -> float | None``.
@@ -13,6 +16,7 @@ metric is a file of its own, found by name:
 
 from __future__ import annotations
 
+import functools
 import gc
 import importlib.util
 import json
@@ -23,6 +27,7 @@ import sys
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 import numpy as np
@@ -60,6 +65,7 @@ class Cell:
     limits: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    model: ModuleType
     bench_dir: Path = BENCH
 
 
@@ -82,6 +88,7 @@ def load_cell(root: Path, name: str) -> Cell:
     conf = json.loads((root / configs[w["config"]]["file"]).read_text())
     conf.setdefault("name", w["config"])
     bench = root / "bench"
+    model = model_module(conf.get("model_type"), bench)
     mix = json.loads((bench / "traffic" / f"{w['traffic']}.json")
                      .read_text())
     limits_path = bench / "limits" / f"{name}.json"
@@ -92,19 +99,37 @@ def load_cell(root: Path, name: str) -> Cell:
     names = [m["name"] for m in e2e]
     per_layer = [m for m in spec["per_layer"] if _applies(m, name, names)]
     return Cell(name, int(w["chips"]), w["traffic"], conf, mix, limits, e2e,
-                per_layer, bench)
+                per_layer, model, bench)
+
+
+def _load(path: Path, name: str, what: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    if spec is None or not path.exists():
+        raise BenchError(f"no {what} at {path}")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(metric: str, bench: Path = BENCH) -> Callable:
     """The ``read`` function of ``bench/metrics/<metric>.py``."""
-    path = bench / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}",
-                                                  path)
-    if spec is None or not path.exists():
-        raise BenchError(f"no reader for metric {metric!r} at {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(bench / "metrics" / f"{metric}.py", f"bench_metric_{metric}",
+                 f"reader for metric {metric!r}").read
+
+
+# one module object per file, so that its jitted reference is traced once
+# in a process however many cells name it
+@functools.lru_cache(maxsize=None)
+def model_module(model_type, bench: Path = BENCH) -> ModuleType:
+    """``bench/models/<model_type>.py``: the weights, reference and counts
+    of the model family a configuration file names by ``model_type``."""
+    if not isinstance(model_type, str) or not re.fullmatch(
+            r"[A-Za-z0-9_][A-Za-z0-9_-]*", model_type):
+        raise BenchError(f"configuration states no usable model_type: "
+                         f"{model_type!r}")
+    return _load(bench / "models" / f"{model_type}.py",
+                 f"bench_model_{model_type}",
+                 f"model module for model_type {model_type!r}")
 
 
 def peaks_for(kind: str) -> Dict:
@@ -306,6 +331,10 @@ class RunData:
     trace_window: tuple = (0.0, 0.0)
     device: int = 0
 
+    @property
+    def model(self) -> ModuleType:
+        return self.cell.model
+
 
 def _spy(engine, run: RunData) -> None:
     """Record the lanes of every step dispatch (traced runs only: reading
@@ -481,11 +510,12 @@ def gap_numbers(gaps: np.ndarray) -> Dict[str, float]:
             "mean_logit_gap": float(gaps.mean())}
 
 
-def compare(cfg, seed: int, picked: List[Rec]) -> Dict[str, float]:
-    """The sample's numbers against the float32 reference."""
-    from reference import logit_gaps, served_logits
+def compare(model: ModuleType, cfg, seed: int,
+            picked: List[Rec]) -> Dict[str, float]:
+    """The sample's numbers against the model's float32 reference."""
+    from reference import logit_gaps
     seqs, rows, toks = served(picked)
-    ref = served_logits(seed, cfg, seqs, rows)
+    ref = model.served_logits(seed, cfg, seqs, rows)
     gaps = np.concatenate([logit_gaps(lg, t) for lg, t in zip(ref, toks)])
     return dict(gap_numbers(gaps), served_tokens=int(len(gaps)),
                 requests=len(picked))
@@ -499,14 +529,13 @@ def setup(cell: Cell, seed: int, seconds: float):
     """Weights, engine and warm-up; returns (cfg, engine, plan)."""
     import jax
     from repro.serving import ServingEngine
-    from weights import arch_config, make_params
-    cfg = arch_config(cell.conf)
+    cfg = cell.model.arch_config(cell.conf)
     s = cell.conf["serving"]
     if traffic.max_context(cell.mix) + 1 > s["max_len"]:
         raise BenchError(f"{cell.traffic_name} reaches "
                          f"{traffic.max_context(cell.mix)} tokens, over "
                          f"max_len {s['max_len']}")
-    params = jax.block_until_ready(make_params(seed, cfg))
+    params = jax.block_until_ready(cell.model.make_params(seed, cfg))
     engine = ServingEngine(cfg, engine_config(cell, seed), params=params)
     if engine.kv_layout != "paged" or engine.prefill_chunk is None:
         raise BenchError("the engine did not take the paged chunked path")
@@ -576,7 +605,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     del engine
     gc.collect()
     if picked:
-        got = compare(cfg, seed, picked)
+        got = compare(cell.model, cfg, seed, picked)
         names = [n for n in ("max_logit_gap", "mean_logit_gap")
                  if cell.limits.get(n) is not None]
         compared = {n: {"value": got[n], "limit": cell.limits.get(n)}

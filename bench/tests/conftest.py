@@ -36,4 +36,5 @@ def tiny_cell():
            (("ttft_p90_ms", "ms"), ("itl_p95_ms", "ms"),
             ("out_tok_per_s", "tokens/s"), ("setup_s", "s"))]
     return harness.Cell("tiny.chat", 1, "tiny", dict(TINY_CONF),
-                        dict(TINY_MIX), {"max_logit_gap": 1e-3}, e2e, [])
+                        dict(TINY_MIX), {"max_logit_gap": 1e-3}, e2e, [],
+                        harness.model_module("llama"))

@@ -120,12 +120,11 @@ def recorded_run(tiny_cell, tmp_path):
     decode, no engine spans) with hand-made host records around it, laid
     out where the harness keeps a run's trace."""
     from repro.serving import Request
-    from weights import arch_config
     where = tmp_path / ".trace" / "tiny.chat-1" / "plugins" / "profile" / "x"
     where.mkdir(parents=True)
     shutil.copy(os.path.join(DATA, "small.xplane.pb"), where)
     tiny_cell.bench_dir = tmp_path
-    run = harness.RunData(tiny_cell, arch_config(TINY_CONF),
+    run = harness.RunData(tiny_cell, tiny_cell.model.arch_config(TINY_CONF),
                           harness.peaks_for("TPU v5 lite"))
     run.trace = tr.load(DATA)
     run.trace_window = run.trace.window()

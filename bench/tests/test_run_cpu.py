@@ -90,8 +90,9 @@ def test_control_fails_the_limit(tiny_cell):
     picked = harness.sample(run, 5)
     del engine
     gc.collect()
-    program = harness.compare(cfg, 5, picked)["max_logit_gap"]
-    control = control_gaps(cfg, 5, picked)["max_logit_gap"]
+    model = tiny_cell.model
+    program = harness.compare(model, cfg, 5, picked)["max_logit_gap"]
+    control = control_gaps(model, cfg, 5, picked)["max_logit_gap"]
     limit = tiny_cell.limits["max_logit_gap"]
     assert program <= limit < control, (program, limit, control)
 

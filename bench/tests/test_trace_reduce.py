@@ -4,9 +4,12 @@ import os
 
 import pytest
 
-import kernels
+import harness
 import trace_reduce as tr
 from trace_reduce import Op
+
+RSA_GEMM = ("%rsa_gemm",)
+PAGED_ATTN = harness.model_module("llama").PAGED_ATTN_KERNELS
 
 
 def _trace():
@@ -43,13 +46,13 @@ def test_idle_within_step_spans():
 
 def test_kernel_seconds_and_breakdown():
     t = _trace()
-    s, n = tr.op_seconds(t.ops[0], kernels.RSA_GEMM, 0, 100)
+    s, n = tr.op_seconds(t.ops[0], RSA_GEMM, 0, 100)
     assert (s, n) == (pytest.approx(15e-9), 1)
-    s, n = tr.op_seconds(t.ops[0], kernels.PAGED_ATTN, 60, 100)
+    s, n = tr.op_seconds(t.ops[0], PAGED_ATTN, 60, 100)
     assert (s, n) == (pytest.approx(10e-9), 1)
     # a fusion that merely runs inside the kernel's wrapper is no kernel
     assert not tr.matches(Op("%rsa_gemm_pad.1 = bf16[8]{0} pad(%a)", 0, 1),
-                          kernels.RSA_GEMM)
+                          RSA_GEMM)
     top = tr.top_ops(t.ops[0], 0, 100, n=2)
     assert [k for k, _ in top] == ["fusion bf16[8]", "paged_attention bf16[4,8]"]
     assert top[0][1] == pytest.approx(20e-9)
@@ -81,8 +84,8 @@ def test_recorded_chip_trace():
     steps = [(s, e) for n, s, e in t.spans if n == "bench.step"]
     assert len(steps) == 3 and all(lo <= s < e <= hi for s, e in steps)
     early = lo - 2.5e6
-    rsa_s, rsa_n = tr.op_seconds(t.ops[0], kernels.RSA_GEMM, early, hi)
-    pa_s, pa_n = tr.op_seconds(t.ops[0], kernels.PAGED_ATTN, early, hi)
+    rsa_s, rsa_n = tr.op_seconds(t.ops[0], RSA_GEMM, early, hi)
+    pa_s, pa_n = tr.op_seconds(t.ops[0], PAGED_ATTN, early, hi)
     assert (rsa_n, pa_n) == (3, 3) and rsa_s > 0 and pa_s > 0
     busy = tr.covered(tr.busy(t.ops[0], early, hi)) * 1e-9
     assert rsa_s + pa_s <= busy < (hi - early) * 1e-9

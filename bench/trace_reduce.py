@@ -115,7 +115,10 @@ def gaps(busy_iv: Sequence[Interval], lo: float,
 
 def matches(op: Op, patterns: Sequence[str]) -> bool:
     """A kernel op: a custom call whose instruction name starts with one
-    of ``patterns``."""
+    of ``patterns``.  A device op's name is its HLO instruction,
+    ``%<name>.<n> = <type> <opcode>(...)``; a Pallas kernel is a
+    ``custom-call`` named after the jitted wrapper that launches it
+    (``kernels/ops.py``)."""
     return ("custom-call(" in op.name
             and any(op.name.startswith(p) for p in patterns))
 
