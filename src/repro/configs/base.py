@@ -27,13 +27,24 @@ class MoEConfig:
     # Experts are padded up to a multiple of the model axis for even EP
     # sharding; the router never selects padding experts.
     router_dtype: str = "float32"
+    # How the router turns its logits into a choice and combine weights:
+    # "softmax" (qwen2-moe) or "sigmoid" (deepseek-v3); "greedy" selects on
+    # the scores, "noaux_tc" on score + e_score_correction_bias (a router
+    # leaf).  The weights are the chosen scores, divided by their sum when
+    # norm_topk_prob, times routed_scaling_factor.
+    scoring_func: str = "softmax"
+    topk_method: str = "greedy"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-style Multi-head Latent Attention."""
+    """DeepSeek-style Multi-head Latent Attention.  ``q_lora_rank`` None:
+    queries are projected directly from the hidden state (DeepSeek-V2-Lite,
+    Moonlight), with no low-rank q path."""
 
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -162,7 +173,8 @@ class ArchConfig:
             opt_state_dtype="float32",
         )
         if self.moe is not None:
-            kw["moe"] = MoEConfig(
+            kw["moe"] = dataclasses.replace(
+                self.moe,
                 num_experts=8,
                 num_shared_experts=min(self.moe.num_shared_experts, 1),
                 experts_per_token=2,
@@ -174,7 +186,8 @@ class ArchConfig:
             kw["first_dense_layers"] = min(self.first_dense_layers, 1)
         if self.mla is not None:
             kw["mla"] = MLAConfig(
-                q_lora_rank=32, kv_lora_rank=16,
+                q_lora_rank=None if self.mla.q_lora_rank is None else 32,
+                kv_lora_rank=16,
                 qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
             )
         if self.ssm is not None:

@@ -25,8 +25,10 @@ kernel on TPU with no flag plumbing.
 
 from repro.dispatch.context import (DispatchPolicy, active, default_registry,
                                     use)
-from repro.dispatch.executor import gemm
+from repro.dispatch.executor import (GroupLayout, gemm, group_block_m,
+                                     grouped_gemm, segment_rows)
 from repro.dispatch.registry import SiteRecord, SiteRegistry
 
-__all__ = ["DispatchPolicy", "SiteRecord", "SiteRegistry", "active",
-           "default_registry", "gemm", "use"]
+__all__ = ["DispatchPolicy", "GroupLayout", "SiteRecord", "SiteRegistry",
+           "active", "default_registry", "gemm", "group_block_m",
+           "grouped_gemm", "segment_rows", "use"]

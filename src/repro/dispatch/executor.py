@@ -21,12 +21,19 @@ Backends:
 Expert banks (w of shape (E, K, N) against x (..., E, C, K)) execute as
 a vmap of the 2D path over E — the MoE expert GEMMs see the same
 recommendation machinery as every other site.
+
+``grouped_gemm(x, w, layout, site=...)`` multiplies rows sorted by expert
+(ragged segments, each padded to the row tile) by an expert bank in one
+call: ``kernels/ops.moe_grouped_gemm`` on the pallas backend (the grouped
+RSA kernel), its twin ``kernels/ref.moe_grouped_gemm_ref`` on xla.  The
+row tile comes from the recommendation at the mean segment's rows
+(``group_block_m``).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -184,3 +191,95 @@ def _gemm_experts(x, w, site: str, exec_backend: str, policy):
         out = jnp.moveaxis(out.reshape(E, B, C, N), 0, 1)        # (B,E,C,N)
         return out.reshape(lead + (E, C, N))
     return jnp.einsum("...eck,ekn->...ecn", x, w)
+
+
+# ---------------------------------------------------------------------------
+# grouped GEMM over ragged expert segments
+# ---------------------------------------------------------------------------
+
+SUBLANE = {2: 16, 4: 8}        # rows of a packed (sublane, 128) tile
+GROUP_VMEM = 12 << 20          # block budget under v5e's 16 MiB scoped VMEM
+
+
+class GroupLayout(NamedTuple):
+    """Rows sorted by group, each group's segment padded to whole row
+    tiles of ``block_m``: ``group`` (R // block_m,) int32 the group of each
+    row tile, ``used`` (1,) int32 the tiles that hold rows, ``sizes`` (G,)
+    int32 each group's padded rows (in order, from row 0); ``seg_rows``
+    the mean segment's rows that the tiles were recommended at."""
+
+    group: jnp.ndarray
+    used: jnp.ndarray
+    sizes: jnp.ndarray
+    block_m: int
+    seg_rows: int
+
+
+def segment_rows(rows: int, groups: int) -> int:
+    """Rows of the mean segment of ``rows`` rows over ``groups`` groups."""
+    return max(1, -(-int(rows) // int(groups)))
+
+
+def group_block_m(rows: int, groups: int, k: int, n: int, dtype) -> int:
+    """The row tile of a grouped GEMM over ``rows`` routed rows in
+    ``groups`` segments: the recommendation's block_m at the mean
+    segment's rows, no taller than that segment rounded up to the dtype's
+    sublane tile (short segments pad to 16 rows, not 128)."""
+    from repro.dispatch.context import active
+    m_seg = segment_rows(rows, groups)
+    cfg = active().dispatcher.recommend(m_seg, k, n)
+    return min(cfg.block_m,
+               _round_up(m_seg, SUBLANE[jnp.dtype(dtype).itemsize]))
+
+
+def _whole_or(block: int, dim: int) -> int:
+    """The recommended block where it tiles ``dim`` exactly, else all of
+    ``dim``: an expert bank is never padded on the call."""
+    return block if block < dim and dim % block == 0 else dim
+
+
+def _group_blocks(cfg, bm: int, k: int, n: int, itemsize: int
+                  ) -> Tuple[int, int]:
+    """(block_n, block_k) for a grouped GEMM: the recommendation fitted to
+    the bank's dims, block_k then block_n halved while the double-buffered
+    blocks and the f32 accumulator exceed ``GROUP_VMEM``."""
+    bn, bk = _whole_or(cfg.block_n, n), _whole_or(cfg.block_k, k)
+
+    def vmem(bn, bk):
+        return 2 * (bm * bk + bk * bn + bm * bn) * itemsize + 4 * bm * bn
+
+    while vmem(bn, bk) > GROUP_VMEM and bk % 256 == 0:
+        bk //= 2
+    while vmem(bn, bk) > GROUP_VMEM and bn % 256 == 0:
+        bn //= 2
+    return bn, bk
+
+
+def grouped_gemm(x: jnp.ndarray, w: jnp.ndarray, layout: GroupLayout, *,
+                 site: str, backend: Optional[str] = None) -> jnp.ndarray:
+    """x (R, K), rows sorted by group as ``layout`` says, @ w (G, K, N) ->
+    (R, N): each row by its group's weights.  Rows past the used tiles
+    are unspecified (zero on xla, unwritten by the kernel)."""
+    from repro.dispatch.context import active
+    policy = active()
+    exec_backend = backend or policy.backend()
+    R, K = (int(s) for s in x.shape)
+    G, K2, N = (int(s) for s in w.shape)
+    if K2 != K or R % layout.block_m:
+        raise ValueError(f"grouped gemm shape mismatch: x {x.shape}, "
+                         f"w {w.shape}, block_m {layout.block_m}")
+    bm, m_seg = layout.block_m, layout.seg_rows
+    cfg = policy.dispatcher.recommend(m_seg, K, N)
+    bn, bk = _group_blocks(cfg, bm, K, N, jnp.dtype(x.dtype).itemsize)
+    if policy.registry is not None:
+        from repro.core.hw import OS
+        policy.registry.record(site, R, K, N, cfg, bm, bn, bk, OS,
+                               exec_backend, "",
+                               _rec_source(policy, m_seg, K, N))
+    if exec_backend == "pallas":
+        from repro.kernels import ops
+        return ops.moe_grouped_gemm(x, w, layout.group, layout.used,
+                                    block_m=bm, block_n=bn, block_k=bk,
+                                    interpret=policy.interpret)
+    from repro.kernels.ref import moe_grouped_gemm_ref
+    return moe_grouped_gemm_ref(x, w, layout.sizes)

@@ -19,7 +19,7 @@ from repro.core.hw import OS
 from repro.kernels.adaptnetx import adaptnetx_pallas
 from repro.kernels.flash_attn import flash_attention_pallas
 from repro.kernels.linear_attn import linear_attn_pallas
-from repro.kernels.rsa_gemm import rsa_gemm_pallas
+from repro.kernels.rsa_gemm import rsa_gemm_pallas, rsa_grouped_gemm_pallas
 
 
 def default_interpret() -> bool:
@@ -54,6 +54,26 @@ def rsa_gemm(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int = 128,
                           block_k=block_k, mode=mode,
                           interpret=_interpret(interpret))
     return out[:M, :N]
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
+                                             "interpret"))
+def moe_grouped_gemm(a: jnp.ndarray, b: jnp.ndarray, group: jnp.ndarray,
+                     used: jnp.ndarray, *, block_m: int, block_n: int,
+                     block_k: int,
+                     interpret: Optional[bool] = None) -> jnp.ndarray:
+    """(R, K) rows in row tiles of ``block_m``, tile t of group
+    ``group[t]``, @ the bank b (G, K, N) -> (R, N); ``used``: (1,) tiles in
+    use, the rows of the rest are left unwritten.  R is a multiple of
+    ``block_m``; K and N are padded to their blocks (callers choose blocks
+    that divide them, so the bank is never copied)."""
+    N = b.shape[-1]
+    a2 = _pad_to(a, 1, block_k)
+    b2 = _pad_to(_pad_to(b, 1, block_k), 2, block_n)
+    out = rsa_grouped_gemm_pallas(a2, b2, group, used, block_m=block_m,
+                                  block_n=block_n, block_k=block_k,
+                                  interpret=_interpret(interpret))
+    return out[:, :N]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -11,6 +11,14 @@ def rsa_gemm_ref(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return jnp.dot(a, b, preferred_element_type=jnp.float32).astype(a.dtype)
 
 
+def moe_grouped_gemm_ref(a: jnp.ndarray, b: jnp.ndarray,
+                         sizes: jnp.ndarray) -> jnp.ndarray:
+    """XLA twin of ``ops.moe_grouped_gemm``: a (R, K) rows in group order,
+    ``sizes[g]`` rows of group g (padding included), each times b[g]
+    (G, K, N); rows past the groups are zero."""
+    return jax.lax.ragged_dot(a, b, sizes)
+
+
 def adaptnetx_ref(ids, emb_m, emb_k, emb_n, w1, b1, w2, b2) -> jnp.ndarray:
     x = jnp.concatenate([emb_m[ids[0]], emb_k[ids[1]], emb_n[ids[2]]], -1)
     h = jnp.maximum(x.astype(jnp.float32) @ w1.astype(jnp.float32)

@@ -112,3 +112,75 @@ def rsa_gemm_pallas(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int,
         return parts[0] if kt == 1 else parts.sum(0).astype(a.dtype)
 
     raise ValueError(f"unknown mode {mode}")
+
+
+def _kernel_grouped(group, used, a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
+    live = pl.program_id(0) < used[0]
+    k = pl.program_id(2)
+
+    @pl.when(live & (k == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _acc():
+        acc_ref[...] += jnp.dot(a_ref[...], b_ref[0],
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(live & (k == n_k - 1))
+    def _flush():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def rsa_grouped_gemm_pallas(a: jnp.ndarray, b: jnp.ndarray,
+                            group: jnp.ndarray, used: jnp.ndarray, *,
+                            block_m: int, block_n: int, block_k: int,
+                            interpret: bool = True) -> jnp.ndarray:
+    """The grouped RSA GEMM (OS): rows sorted into groups, each group's
+    segment padded to whole row tiles, times a bank of per-group weights.
+    The group of each row tile and the number of tiles in use ride in
+    scalar prefetch, so the weight BlockSpec's index map picks the tile's
+    own group.  Tiles past the used ones compute and write nothing, and
+    map to the last used tile's blocks, so nothing is fetched for them.
+
+    a: (R, K) rows, row tile t of group ``group[t]``; b: (G, K, N); used:
+    (1,) int32 row tiles in use.  R, K, N multiples of the blocks.
+    Returns (R, N); the rows of unused tiles are left unwritten."""
+    R, K = a.shape
+    _, K2, N = b.shape
+    assert K == K2, (a.shape, b.shape)
+    assert R % block_m == 0 and N % block_n == 0 and K % block_k == 0
+    mt, nt, kt = R // block_m, N // block_n, K // block_k
+
+    def blk(m, n, k, u):
+        # an unused tile repeats the grid's last used step, (u-1, nt-1, kt-1)
+        dead = m >= u[0]
+        return (jnp.where(dead, jnp.maximum(u[0] - 1, 0), m),
+                jnp.where(dead, nt - 1, n), jnp.where(dead, kt - 1, k))
+
+    def a_map(m, n, k, g, u):
+        mm, _, kk = blk(m, n, k, u)
+        return mm, kk
+
+    def b_map(m, n, k, g, u):
+        mm, nn, kk = blk(m, n, k, u)
+        return g[mm], kk, nn
+
+    def o_map(m, n, k, g, u):
+        mm, nn, _ = blk(m, n, k, u)
+        return mm, nn
+
+    return pl.pallas_call(
+        functools.partial(_kernel_grouped, n_k=kt),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(mt, nt, kt),
+            in_specs=[pl.BlockSpec((block_m, block_k), a_map),
+                      pl.BlockSpec((1, block_k, block_n), b_map)],
+            out_specs=pl.BlockSpec((block_m, block_n), o_map),
+            scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((R, N), a.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(group, used, a, b)

@@ -12,6 +12,12 @@ from repro.models import serving, transformer
 from repro.models.modules import param_count
 
 
+def _loads(moe_loads: bool) -> Dict[str, bool]:
+    """The step programs' ``moe_loads`` option, passed only where it is on
+    (a step function without it keeps the plain signature)."""
+    return {"moe_loads": True} if moe_loads else {}
+
+
 class Model:
     """Functional model wrapper — all methods are pure and jit-friendly."""
 
@@ -64,9 +70,10 @@ class Model:
         return serving.paged_prefill_write(arena, layers_cache, block_ids)
 
     def paged_prefill_step(self, params, tokens, arena, block_tables,
-                           kv_lens, chunk_lens):
+                           kv_lens, chunk_lens, moe_loads=False):
         return serving.paged_prefill_step(params, tokens, self.cfg, arena,
-                                          block_tables, kv_lens, chunk_lens)
+                                          block_tables, kv_lens, chunk_lens,
+                                          **_loads(moe_loads))
 
     def paged_verify_step(self, params, tokens, arena, block_tables,
                           kv_lens, chunk_lens):
@@ -74,10 +81,10 @@ class Model:
                                          block_tables, kv_lens, chunk_lens)
 
     def paged_decode_step(self, params, tokens, state, arena, block_tables,
-                          kv_lens, write_mask):
+                          kv_lens, write_mask, moe_loads=False):
         return serving.paged_decode_step(params, tokens, self.cfg, state,
                                          arena, block_tables, kv_lens,
-                                         write_mask)
+                                         write_mask, **_loads(moe_loads))
 
     def paged_shared_decode_step(self, params, tokens, state, arena,
                                  block_tables, kv_lens, write_mask,

@@ -5,10 +5,12 @@ All attention paths use a memory-bounded blockwise ("flash-style") computation
 32K-token prefill never materializes an S×S score matrix.  Decode paths attend
 over a fixed-capacity KV cache with a length mask.
 
-MLA implements the real DeepSeek-V3 structure: low-rank q projection, compressed
-KV latent + decoupled shared RoPE key; decode uses the *absorbed* formulation and
-caches only (c_kv, k_rope) — this is what makes deepseek-v3-671b's decode_32k
-cell fit (≈70 KB/token instead of ≈8 MB/token).
+MLA implements the real DeepSeek-V3 structure: low-rank q projection (or a
+direct one where ``q_lora_rank`` is None), compressed KV latent + decoupled
+shared RoPE key in DeepSeek's rope layout; decode uses the *absorbed*
+formulation and caches only (c_kv, k_rope) — this is what makes
+deepseek-v3-671b's decode_32k cell fit (≈70 KB/token instead of ≈8
+MB/token).
 """
 
 from __future__ import annotations
@@ -637,11 +639,15 @@ def init_mla(key, cfg: ArchConfig) -> Params:
     dt = cfg.param_dtype
     ks = jax.random.split(key, 6)
     H = cfg.num_heads
+    q_out = H * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if m.q_lora_rank is None:
+        q = {"w_q": dense_init(ks[0], cfg.d_model, q_out, dt)}
+    else:
+        q = {"w_dq": dense_init(ks[0], cfg.d_model, m.q_lora_rank, dt),
+             "q_norm": jnp.zeros((m.q_lora_rank,), dt),
+             "w_uq": dense_init(ks[1], m.q_lora_rank, q_out, dt)}
     return {
-        "w_dq": dense_init(ks[0], cfg.d_model, m.q_lora_rank, dt),
-        "q_norm": jnp.zeros((m.q_lora_rank,), dt),
-        "w_uq": dense_init(ks[1], m.q_lora_rank,
-                           H * (m.qk_nope_head_dim + m.qk_rope_head_dim), dt),
+        **q,
         "w_dkv": dense_init(ks[2], cfg.d_model,
                             m.kv_lora_rank + m.qk_rope_head_dim, dt),
         "kv_norm": jnp.zeros((m.kv_lora_rank,), dt),
@@ -653,18 +659,31 @@ def init_mla(key, cfg: ArchConfig) -> Params:
     }
 
 
+def _mla_rope(x, positions, theta: float):
+    """DeepSeek's rope on the decoupled rope dims, as its
+    ``modeling_deepseek.py`` applies it: the dims are first de-interleaved
+    (even then odd: ``view(..., d // 2, 2).transpose``), then rotated in
+    halves.  x: (..., S, heads, rd)."""
+    rd = x.shape[-1]
+    x = x.reshape(x.shape[:-1] + (rd // 2, 2))
+    x = jnp.swapaxes(x, -1, -2).reshape(x.shape[:-2] + (rd,))
+    return apply_rope(x, positions, theta)
+
+
 def _mla_q(params, x, positions, cfg: ArchConfig, cdt):
     m = cfg.mla
     B, S = x.shape[0], x.shape[1]
     H = cfg.num_heads
-    cq = rmsnorm(dense(x, params["w_dq"], None, cdt,
-                       site="layer.mla.q_down"), params["q_norm"],
-                 cfg.norm_eps)
-    q = dense(cq, params["w_uq"], None, cdt,
-              site="layer.mla.q_up").reshape(
-        B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if "w_q" in params:
+        q = dense(x, params["w_q"], None, cdt, site="layer.mla.q_proj")
+    else:
+        cq = rmsnorm(dense(x, params["w_dq"], None, cdt,
+                           site="layer.mla.q_down"), params["q_norm"],
+                     cfg.norm_eps)
+        q = dense(cq, params["w_uq"], None, cdt, site="layer.mla.q_up")
+    q = q.reshape(B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, positions, cfg.rope_theta)
     return q_nope, q_rope
 
 
@@ -673,7 +692,8 @@ def _mla_ckv(params, x, positions, cfg: ArchConfig, cdt):
     dkv = dense(x, params["w_dkv"], None, cdt, site="layer.mla.kv_down")
     c_kv, k_rope = jnp.split(dkv, [m.kv_lora_rank], axis=-1)
     c_kv = rmsnorm(c_kv, params["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
+    k_rope = _mla_rope(k_rope[:, :, None, :], positions,
+                       cfg.rope_theta)[:, :, 0, :]
     return c_kv, k_rope
 
 

@@ -174,25 +174,26 @@ def _scan_paged_layers(layer_fn, x, params: Params, arena: Dict[str, Any],
     stacked back.  A leading dense stack (deepseek-v3) runs first on the
     same carry, so the layer index runs on across both stacks.  ``extra``:
     per-layer xs of the main stack (encdec's cross K/V).  Returns (x,
-    {"k": k, "v": v})."""
+    {"k": k, "v": v}); where ``layer_fn`` returns a fourth value (each MoE
+    layer's expert load), the main stack's, stacked, come third."""
 
     def body(carry, xs):
         h, k, v, layer = carry
-        h, k, v = layer_fn(h, xs[0], k, v, layer, *xs[1:])
-        return (h, k, v, layer + 1), None
+        out = layer_fn(h, xs[0], k, v, layer, *xs[1:])
+        return (*out[:3], layer + 1), out[3:]
 
     carry = (x, arena["k"], arena["v"], jnp.zeros((), jnp.int32))
     if "dense_layers" in params:
         carry, _ = jax.lax.scan(body, carry, (params["dense_layers"],))
-    (x, k, v, _), _ = jax.lax.scan(body, carry,
-                                   (params["layers"],) + tuple(extra))
-    return x, {"k": k, "v": v}
+    (x, k, v, _), ys = jax.lax.scan(body, carry,
+                                    (params["layers"],) + tuple(extra))
+    return (x, {"k": k, "v": v}) + tuple(ys)
 
 
 def paged_decode_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
                       state: Dict[str, Any], arena: Dict[str, Any],
                       block_tables: jnp.ndarray, kv_lens: jnp.ndarray,
-                      write_mask: jnp.ndarray
+                      write_mask: jnp.ndarray, moe_loads: bool = False
                       ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """One batched decode step over every lane through the paged KV arena.
 
@@ -201,7 +202,10 @@ def paged_decode_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
     each lane's position — vlm frontend rows included); write_mask: (S,)
     int32 — lanes with 0 (stalled / empty slots) leave the arena untouched
     and their logits are discarded by the caller, so there is nothing to
-    snapshot or roll back.  Returns ((S, V) logits, new arena).
+    snapshot or roll back; they route to no expert.  Returns ((S, V)
+    logits, new arena); with ``moe_loads`` the first element is (logits,
+    the MoE layers' (L, 2, E) int32 expert loads: routed rows and
+    grouped-GEMM rows per expert, ``models/moe.py`` ``moe_serve``).
     """
     fam = cfg.family
     if fam not in PAGED_FAMILIES:
@@ -212,18 +216,20 @@ def paged_decode_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
     wm = write_mask.astype(jnp.int32)
 
     def layer_fn(h, layer_p, ak, av, layer, *enc_kv):
-        return paged_decoder_layer_apply(
+        out = paged_decoder_layer_apply(
             layer_p, h, positions, cfg, k_arena=ak, v_arena=av, layer=layer,
             block_tables=block_tables, kv_lens=kv_lens, write_mask=wm,
             enc_kv=enc_kv or None)
+        return out if moe_loads else out[:3]
 
     extra = ((state["cross_k"], state["cross_v"]) if fam == "encdec"
              else ())
-    x, new_arena = _scan_paged_layers(_maybe_remat(layer_fn, cfg), x,
-                                      params, arena, extra)
+    x, new_arena, *loads = _scan_paged_layers(_maybe_remat(layer_fn, cfg),
+                                              x, params, arena, extra)
 
     x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    return _lm_head(params, x[:, -1, :], cfg), new_arena
+    logits = _lm_head(params, x[:, -1, :], cfg)
+    return ((logits, *loads) if moe_loads else logits), new_arena
 
 
 def paged_shared_decode_step(params: Params, tokens: jnp.ndarray,
@@ -258,7 +264,7 @@ def paged_shared_decode_step(params: Params, tokens: jnp.ndarray,
             layer_p, h, positions, cfg, k_arena=ak, v_arena=av, layer=layer,
             block_tables=block_tables, kv_lens=kv_lens, write_mask=wm,
             prefix_pages=prefix_pages, prefix_lens=prefix_lens,
-            unique_tables=unique_tables, unique_lens=unique_lens)
+            unique_tables=unique_tables, unique_lens=unique_lens)[:3]
 
     x, new_arena = _scan_paged_layers(_maybe_remat(layer_fn, cfg), x,
                                       params, arena)
@@ -269,7 +275,8 @@ def paged_shared_decode_step(params: Params, tokens: jnp.ndarray,
 
 def paged_prefill_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
                        arena: Dict[str, Any], block_tables: jnp.ndarray,
-                       kv_lens: jnp.ndarray, chunk_lens: jnp.ndarray
+                       kv_lens: jnp.ndarray, chunk_lens: jnp.ndarray,
+                       moe_loads: bool = False
                        ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """One chunked-prefill step over every lane through the paged KV arena.
 
@@ -281,8 +288,9 @@ def paged_prefill_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
     ignores).  Each layer writes the chunk's K/V rows directly into the
     lane's pages, then attends causally over everything written so far —
     no dense scratch cache, no bucket-granularity copy, so prefill KV
-    traffic is exactly the chunk's real tokens.  Returns ((S, V) logits at
-    each lane's last valid chunk row, new arena).
+    traffic is exactly the chunk's real tokens, and only those rows route
+    to experts.  Returns ((S, V) logits at each lane's last valid chunk
+    row, new arena); ``moe_loads`` as in :func:`paged_decode_step`.
     """
     fam = cfg.family
     if fam not in CHUNKED_PREFILL_FAMILIES:
@@ -294,18 +302,20 @@ def paged_prefill_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
     positions = kv_lens[:, None] + jnp.arange(C)[None, :]
 
     def layer_fn(h, layer_p, ak, av, layer):
-        return paged_prefill_layer_apply(
+        out = paged_prefill_layer_apply(
             layer_p, h, positions, cfg, k_arena=ak, v_arena=av, layer=layer,
             block_tables=block_tables, kv_lens=kv_lens,
             chunk_lens=chunk_lens)
+        return out if moe_loads else out[:3]
 
-    x, new_arena = _scan_paged_layers(_maybe_remat(layer_fn, cfg), x,
-                                      params, arena)
+    x, new_arena, *loads = _scan_paged_layers(_maybe_remat(layer_fn, cfg),
+                                              x, params, arena)
 
     x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
     last = jnp.clip(chunk_lens - 1, 0, C - 1)
     h_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0, :]
-    return _lm_head(params, h_last, cfg), new_arena
+    logits = _lm_head(params, h_last, cfg)
+    return ((logits, *loads) if moe_loads else logits), new_arena
 
 
 def paged_verify_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
@@ -336,7 +346,7 @@ def paged_verify_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
         return paged_prefill_layer_apply(
             layer_p, h, positions, cfg, k_arena=ak, v_arena=av, layer=layer,
             block_tables=block_tables, kv_lens=kv_lens,
-            chunk_lens=chunk_lens)
+            chunk_lens=chunk_lens)[:3]
 
     x, new_arena = _scan_paged_layers(_maybe_remat(layer_fn, cfg), x,
                                       params, arena)

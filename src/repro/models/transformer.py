@@ -31,7 +31,7 @@ from repro.models.attention import (KVCache, cross_attention_kv,
                                     init_gqa, init_gqa_cache, init_mla,
                                     init_mla_cache, mla_self_attention)
 from repro.models.mlp import init_mlp, mlp_apply
-from repro.models.moe import init_moe, moe_apply
+from repro.models.moe import init_moe, moe_apply, moe_serve
 from repro.models.modules import (dense, dense_init, embed_init, rmsnorm,
                                   stack_layer_params)
 from repro.parallel.hints import hint
@@ -104,6 +104,15 @@ def decoder_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
     return x, new_cache, aux
 
 
+def _paged_ffn(p: Params, h, cfg: ArchConfig, live):
+    """The feed-forward of a paged step: the serving MoE, which routes the
+    ``live`` rows of the whole step with no capacity limit and returns its
+    (2, E) expert load (``moe_serve``), or the dense MLP (load None)."""
+    if "moe" in p:
+        return moe_serve(p["moe"], h, cfg, live)
+    return mlp_apply(p["mlp"], h, cfg), None
+
+
 def paged_decoder_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
                               k_arena, v_arena, layer, block_tables, kv_lens,
                               write_mask, enc_kv=None):
@@ -130,12 +139,9 @@ def paged_decoder_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
         hc = rmsnorm(x, p["ln_cross"], cfg.norm_eps)
         x = x + gqa_cross_attention(p["cross"], hc, enc_kv, cfg).astype(x.dtype)
     h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    if "moe" in p:
-        f, _ = moe_apply(p["moe"], h2, cfg)
-    else:
-        f = mlp_apply(p["mlp"], h2, cfg)
+    f, load = _paged_ffn(p, h2, cfg, (write_mask > 0)[:, None])
     x = x + f.astype(x.dtype)
-    return x, nk, nv
+    return x, nk, nv, load
 
 
 def paged_shared_decoder_layer_apply(p: Params, x, positions,
@@ -147,7 +153,7 @@ def paged_shared_decoder_layer_apply(p: Params, x, positions,
     over a shared page prefix is computed once per step for every lane in
     the sharing group (models/attention.py::gqa_paged_shared_decode).  GQA
     families only — absorbed MLA keeps the plain paged path.  Returns
-    (x, new_k_arena, new_v_arena)."""
+    (x, new_k_arena, new_v_arena, expert load or None)."""
     from repro.models.attention import gqa_paged_shared_decode
 
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
@@ -158,12 +164,9 @@ def paged_shared_decoder_layer_apply(p: Params, x, positions,
         prefix_lens=prefix_lens, unique_tables=unique_tables, unique_lens=unique_lens)
     x = x + a.astype(x.dtype)
     h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    if "moe" in p:
-        f, _ = moe_apply(p["moe"], h2, cfg)
-    else:
-        f = mlp_apply(p["mlp"], h2, cfg)
+    f, load = _paged_ffn(p, h2, cfg, (write_mask > 0)[:, None])
     x = x + f.astype(x.dtype)
-    return x, nk, nv
+    return x, nk, nv, load
 
 
 def paged_prefill_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
@@ -172,7 +175,8 @@ def paged_prefill_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
     """One decoder layer's chunked-prefill pass through the paged KV arena
     (mirrors :func:`paged_decoder_layer_apply` widened to C causal rows per
     lane; see models/attention.py::gqa_paged_prefill for the arena
-    contract).  Returns (x, new_k_arena, new_v_arena)."""
+    contract).  Rows at or past a lane's ``chunk_lens`` route to no expert.
+    Returns (x, new_k_arena, new_v_arena, expert load or None)."""
     from repro.models.attention import gqa_paged_prefill, mla_paged_prefill
 
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
@@ -188,12 +192,10 @@ def paged_prefill_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
                                       **paged)
     x = x + a.astype(x.dtype)
     h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    if "moe" in p:
-        f, _ = moe_apply(p["moe"], h2, cfg)
-    else:
-        f = mlp_apply(p["mlp"], h2, cfg)
+    live = jnp.arange(x.shape[1])[None, :] < chunk_lens[:, None]
+    f, load = _paged_ffn(p, h2, cfg, live)
     x = x + f.astype(x.dtype)
-    return x, nk, nv
+    return x, nk, nv, load
 
 
 # ---------------------------------------------------------------------------

@@ -169,6 +169,13 @@ class _Phase:
         self._span.__exit__(*exc)
         self._tm.__exit__(*exc)
 
+    def note(self, **args) -> None:
+        """Add args to the open phase, on both clocks: for what is known
+        only once the phase has run."""
+        self._tm.set_metadata(**args)
+        if self._span._ev is not None:
+            self._span._ev.args.update(args)
+
 
 class StepTimeline:
     """Engine-step timeline: one root span per step with sequential child

@@ -63,6 +63,7 @@ def _param_rules(F):
         (r"cross/w[qkv]$",   lambda: P(F, "model")),
         (r"cross/wo$",       lambda: P("model", F)),
         # MLA
+        (r"attn/w_q$",       lambda: P(F, "model")),
         (r"attn/w_dq$",      lambda: P(F, None)),
         (r"attn/w_uq$",      lambda: P(None, "model")),
         (r"attn/w_dkv$",     lambda: P(F, None)),
@@ -74,6 +75,7 @@ def _param_rules(F):
         (r"mlp/w_down$",     lambda: P("model", F)),
         # MoE (EP over model)
         (r"moe/router$",     lambda: P(F, None)),
+        (r"moe/e_score_correction_bias$", lambda: P(None)),
         (r"moe/w_gate$",     lambda: P("model", F, None)),
         (r"moe/w_up$",       lambda: P("model", F, None)),
         (r"moe/w_down$",     lambda: P("model", None, F)),

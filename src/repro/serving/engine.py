@@ -77,6 +77,7 @@ in engine-step units — deterministic, used by tests and trace benchmarks).
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from dataclasses import dataclass
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -134,8 +135,10 @@ def gemm_sites(cfg: ArchConfig, m_tokens: int) -> List[Tuple[str, int, int, int]
                   ("attn_out", m, cfg.q_dim, d)]
     elif cfg.attention_type == "mla":
         a = cfg.mla
+        q_in = a.q_lora_rank or cfg.num_heads * (a.qk_nope_head_dim
+                                                 + a.qk_rope_head_dim)
         sites += [("mla_down", m, d,
-                   a.q_lora_rank + a.kv_lora_rank + a.qk_rope_head_dim),
+                   q_in + a.kv_lora_rank + a.qk_rope_head_dim),
                   ("mla_out", m, cfg.num_heads * a.v_head_dim, d)]
     if cfg.moe is not None:
         sites += [("moe_expert",
@@ -427,16 +430,22 @@ class ServingEngine:
             self._kv_rows = np.zeros((e.num_slots,), np.int32)
             # every step program donates the arena it is handed: the new
             # rows are written into it in place and the result replaces
-            # arena.leaves at once, so no path reads a donated leaf
+            # arena.leaves at once, so no path reads a donated leaf.  A MoE
+            # model's step programs also return each MoE layer's expert
+            # load inside their first output (``_moe_stats``)
+            self._moe_loads = cfg.moe is not None
+            loads = dict(moe_loads=True) if self._moe_loads else {}
             self._paged_decode = JitWatch(
-                jax.jit(self.model.paged_decode_step, donate_argnums=(3,)),
+                jax.jit(functools.partial(self.model.paged_decode_step,
+                                          **loads), donate_argnums=(3,)),
                 "paged_decode", self.obs)
             self._paged_write = JitWatch(
                 jax.jit(self.model.paged_prefill_write, donate_argnums=(0,)),
                 "paged_write", self.obs)
             if self.prefill_chunk is not None:
                 self._chunk_prefill = JitWatch(
-                    jax.jit(self.model.paged_prefill_step,
+                    jax.jit(functools.partial(self.model.paged_prefill_step,
+                                              **loads),
                             donate_argnums=(2,)), "chunk_prefill", self.obs)
             self._paged_shared_decode = None
             if e.shared_prefix_decode:
@@ -744,8 +753,9 @@ class ServingEngine:
                                     live_rows=int(chunk.sum()),
                                     rows=S * C):
             logits, leaves = self._chunk_prefill(self.params, *args)
-        with self.timeline.phase("sync"):
+        with self.timeline.phase("sync") as sync:
             logits, leaves = jax.block_until_ready((logits, leaves))
+            logits = self._moe_stats(sync, logits)
         dt = time.time() - t0
         self.arena.leaves = leaves
         self._dispatch(scope)
@@ -1225,8 +1235,10 @@ class ServingEngine:
                                         kv_rows=feed.kv_read):
                 # saralint: ok[cow-gate] decode appends one row into the lane's exclusively-owned tail page; shared prefix pages cover only rows < kv_len
                 logits, leaves = self._paged_decode(self.params, *feed.args)
-        with self.timeline.phase("sync"):
+        with self.timeline.phase("sync") as sync:
             logits, leaves = jax.block_until_ready((logits, leaves))
+            if feed.shared is None:
+                logits = self._moe_stats(sync, logits)
         dt = time.time() - t0
         self.arena.leaves = leaves
         with self.timeline.phase("readback"):
@@ -1235,6 +1247,24 @@ class ServingEngine:
             self._sanitize_decode(active, feed.rids, feed.tables, feed.gens,
                                   logits)
         return logits, dt
+
+    def _moe_stats(self, sync, out):
+        """A MoE model's step program returns (logits, loads): loads (L, 2,
+        E) int32, each MoE layer's routed rows and grouped-GEMM rows per
+        expert.  Put their sums over layers on the ``engine.sync`` span
+        that ends the program (its own span closes at the enqueue, before
+        the counts exist): ``moe_routed_rows`` (live tokens x experts per
+        token), ``moe_gemm_rows`` (rows the grouped GEMMs computed, padding
+        included) and ``moe_experts_hit`` (experts with a row, summed over
+        layers).  Returns the logits."""
+        if not self._moe_loads:
+            return out
+        logits, loads = out
+        loads = np.asarray(loads)
+        sync.note(moe_routed_rows=int(loads[:, 0].sum()),
+                  moe_gemm_rows=int(loads[:, 1].sum()),
+                  moe_experts_hit=int((loads[:, 0] > 0).sum()))
+        return logits
 
     def _sanitize_decode(self, active: Dict[int, Request],
                          rids, tables, gens, logits: np.ndarray) -> None:

@@ -5,7 +5,10 @@ topology (``v5e:2x2``) with no chip attached, so these tests catch what
 interpret mode cannot: BlockSpecs the Mosaic lowering refuses (unaligned
 block dims), kernels that overflow VMEM, and tiles outside the RSA space.
 Everything is at llama3.2-1b's published widths (KVH 8, G 4, hd 64, d 2048,
-ff 8192, vocab 128256) and the serving engine's page size (16).
+ff 8192, vocab 128256) and the serving engine's page size (16), and the
+absorbed-MLA kernels and the grouped expert GEMM at Moonlight-16B-A3B's
+(16 heads, latent 512 + rope 64, 64 experts top-6 of width 1408, d 2048)
+with the benchmark cell's 64 lanes and 128-row chunks.
 
 The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, and each pytest-xdist worker
@@ -134,3 +137,63 @@ def test_oracle_decode_tiles_compile(spec, site):
     rec = reg.sites("_")[site]
     assert rec.backend == "pallas"
     assert (rec.m, rec.k, rec.n) == (m, k, n)
+
+
+# Moonlight-16B-A3B (bench/configs/moonlight-16b-a3b.json) as its cell
+# serves it: 64 lanes, 128-row chunks, 16-row pages
+ML_LANES, ML_CHUNK, ML_HEADS, ML_R, ML_RD = 64, 128, 16, 512, 64
+ML_D, ML_F, ML_E, ML_K = 2048, 1408, 64, 6
+ML_PAGES = 8192 + 1
+
+
+def _mla_arena(spec, width):
+    return (spec((ML_PAGES, BS, ML_R)), spec((ML_PAGES, BS, ML_RD)),
+            spec((ML_LANES, width), jnp.int32),
+            spec((ML_LANES,), jnp.int32))
+
+
+def test_mla_paged_decode_compiles(spec):
+    ckv, krope, tables, lengths = _mla_arena(spec, 256)
+    hlo = _compile(lambda *a: ops.mla_paged_attention(
+        *a, qk_dim=192, impl="pallas", interpret=False),
+        spec((ML_LANES, ML_HEADS, ML_R)), spec((ML_LANES, ML_HEADS, ML_RD)),
+        ckv, krope, tables, lengths)
+    assert "tpu_custom_call" in hlo
+
+
+def test_mla_paged_prefill_compiles(spec):
+    """128 rows a chunk at 16 heads: 256 needs 24 MB of scoped VMEM."""
+    ckv, krope, tables, lengths = _mla_arena(spec, 512)
+    hlo = _compile(lambda *a: ops.mla_paged_prefill_attention(
+        *a, qk_dim=192, impl="pallas", interpret=False),
+        spec((ML_LANES, ML_CHUNK, ML_HEADS, ML_R)),
+        spec((ML_LANES, ML_CHUNK, ML_HEADS, ML_RD)), ckv, krope, tables,
+        lengths, lengths)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("rows", [ML_LANES, ML_LANES * ML_CHUNK],
+                         ids=["decode", "chunk"])
+@pytest.mark.parametrize("k,n", [(ML_D, ML_F), (ML_F, ML_D)],
+                         ids=["gate", "down"])
+def test_grouped_expert_gemm_compiles(spec, rows, k, n):
+    """The serving MoE's grouped GEMM at the row tile the recommender
+    gives the step's segments (384 routed rows in decode, 49,152 in a
+    chunk step) over its whole static buffer."""
+    from repro.models.moe import group_rows
+    routed = rows * ML_K
+    reg = dispatch.SiteRegistry()
+    with dispatch.use(SaraDispatcher(), execute="pallas", registry=reg,
+                      interpret=False):
+        bm = dispatch.group_block_m(routed, ML_E, ML_D, ML_F, jnp.bfloat16)
+        R = group_rows(routed, ML_E, bm)
+        hlo = _compile(
+            lambda x, w, g, u, z: dispatch.grouped_gemm(
+                x, w, dispatch.GroupLayout(g, u, z, bm, routed // ML_E),
+                site="moe.expert"),
+            spec((R, k)), spec((ML_E, k, n)), spec((R // bm,), jnp.int32),
+            spec((1,), jnp.int32), spec((ML_E,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+    rec = reg.sites("_")["moe.expert"]
+    assert (rec.backend, rec.m, rec.block_m) == ("pallas", R, bm)
+    assert k % rec.block_k == 0 and n % rec.block_n == 0   # no bank padding
