@@ -26,8 +26,9 @@ recommendation machinery as every other site.
 (ragged segments, each padded to the row tile) by an expert bank in one
 call: ``kernels/ops.moe_grouped_gemm`` on the pallas backend (the grouped
 RSA kernel), its twin ``kernels/ref.moe_grouped_gemm_ref`` on xla.  The
-row tile comes from the recommendation at the mean segment's rows
-(``group_block_m``).
+bank may be one layer of a stack of layers' banks (``layer=``), which the
+kernel reads in place.  The row tile comes from the recommendation at the
+mean segment's rows (``group_block_m``).
 """
 
 from __future__ import annotations
@@ -256,15 +257,20 @@ def _group_blocks(cfg, bm: int, k: int, n: int, itemsize: int
 
 
 def grouped_gemm(x: jnp.ndarray, w: jnp.ndarray, layout: GroupLayout, *,
-                 site: str, backend: Optional[str] = None) -> jnp.ndarray:
+                 site: str, layer: Optional[jnp.ndarray] = None,
+                 backend: Optional[str] = None) -> jnp.ndarray:
     """x (R, K), rows sorted by group as ``layout`` says, @ w (G, K, N) ->
-    (R, N): each row by its group's weights.  Rows past the used tiles
-    are unspecified (zero on xla, unwritten by the kernel)."""
+    (R, N): each row by its group's weights.  With ``layer`` (an int32
+    scalar), w is a stack (L, G, K, N) of layers' banks and the rows meet
+    ``w[layer]``, which the kernel reads in place.  Rows past the used
+    tiles are unspecified (zero on xla, unwritten by the kernel)."""
     from repro.dispatch.context import active
     policy = active()
     exec_backend = backend or policy.backend()
+    if layer is None:
+        w, layer = w[None], 0
     R, K = (int(s) for s in x.shape)
-    G, K2, N = (int(s) for s in w.shape)
+    _, _, K2, N = (int(s) for s in w.shape)
     if K2 != K or R % layout.block_m:
         raise ValueError(f"grouped gemm shape mismatch: x {x.shape}, "
                          f"w {w.shape}, block_m {layout.block_m}")
@@ -278,8 +284,9 @@ def grouped_gemm(x: jnp.ndarray, w: jnp.ndarray, layout: GroupLayout, *,
                                _rec_source(policy, m_seg, K, N))
     if exec_backend == "pallas":
         from repro.kernels import ops
-        return ops.moe_grouped_gemm(x, w, layout.group, layout.used,
-                                    block_m=bm, block_n=bn, block_k=bk,
-                                    interpret=policy.interpret)
+        return ops.moe_grouped_gemm(
+            x, w, layout.group, layout.used,
+            jnp.asarray(layer, jnp.int32).reshape(1), block_m=bm,
+            block_n=bn, block_k=bk, interpret=policy.interpret)
     from repro.kernels.ref import moe_grouped_gemm_ref
-    return moe_grouped_gemm_ref(x, w, layout.sizes)
+    return moe_grouped_gemm_ref(x, w[layer], layout.sizes)

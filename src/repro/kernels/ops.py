@@ -59,21 +59,20 @@ def rsa_gemm(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int = 128,
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
                                              "interpret"))
 def moe_grouped_gemm(a: jnp.ndarray, b: jnp.ndarray, group: jnp.ndarray,
-                     used: jnp.ndarray, *, block_m: int, block_n: int,
-                     block_k: int,
+                     used: jnp.ndarray, layer: jnp.ndarray, *, block_m: int,
+                     block_n: int, block_k: int,
                      interpret: Optional[bool] = None) -> jnp.ndarray:
     """(R, K) rows in row tiles of ``block_m``, tile t of group
-    ``group[t]``, @ the bank b (G, K, N) -> (R, N); ``used``: (1,) tiles in
-    use, the rows of the rest are left unwritten.  R is a multiple of
-    ``block_m``; K and N are padded to their blocks (callers choose blocks
-    that divide them, so the bank is never copied)."""
-    N = b.shape[-1]
-    a2 = _pad_to(a, 1, block_k)
-    b2 = _pad_to(_pad_to(b, 1, block_k), 2, block_n)
-    out = rsa_grouped_gemm_pallas(a2, b2, group, used, block_m=block_m,
-                                  block_n=block_n, block_k=block_k,
-                                  interpret=_interpret(interpret))
-    return out[:, :N]
+    ``group[t]``, @ the bank ``b[layer[0]]`` of the stack b (L, G, K, N)
+    -> (R, N); ``used``: (1,) tiles in use, the rows of the rest are left
+    unwritten.  The blocks divide R, K and N: any op on b here would copy
+    every layer's bank, so b goes to the kernel as it is."""
+    assert a.shape[0] % block_m == 0, (a.shape, block_m)
+    assert b.shape[2] % block_k == 0 and b.shape[3] % block_n == 0, (
+        b.shape, block_k, block_n)
+    return rsa_grouped_gemm_pallas(a, b, group, used, layer, block_m=block_m,
+                                   block_n=block_n, block_k=block_k,
+                                   interpret=_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

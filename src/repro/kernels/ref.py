@@ -13,9 +13,9 @@ def rsa_gemm_ref(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
 def moe_grouped_gemm_ref(a: jnp.ndarray, b: jnp.ndarray,
                          sizes: jnp.ndarray) -> jnp.ndarray:
-    """XLA twin of ``ops.moe_grouped_gemm``: a (R, K) rows in group order,
-    ``sizes[g]`` rows of group g (padding included), each times b[g]
-    (G, K, N); rows past the groups are zero."""
+    """XLA twin of ``ops.moe_grouped_gemm`` on one layer's bank: a (R, K)
+    rows in group order, ``sizes[g]`` rows of group g (padding included),
+    each times b[g] (G, K, N); rows past the groups are zero."""
     return jax.lax.ragged_dot(a, b, sizes)
 
 

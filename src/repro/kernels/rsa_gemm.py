@@ -114,7 +114,8 @@ def rsa_gemm_pallas(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int,
     raise ValueError(f"unknown mode {mode}")
 
 
-def _kernel_grouped(group, used, a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
+def _kernel_grouped(group, used, layer, a_ref, b_ref, o_ref, acc_ref, *,
+                    n_k: int):
     live = pl.program_id(0) < used[0]
     k = pl.program_id(2)
 
@@ -133,21 +134,25 @@ def _kernel_grouped(group, used, a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
 
 
 def rsa_grouped_gemm_pallas(a: jnp.ndarray, b: jnp.ndarray,
-                            group: jnp.ndarray, used: jnp.ndarray, *,
+                            group: jnp.ndarray, used: jnp.ndarray,
+                            layer: jnp.ndarray, *,
                             block_m: int, block_n: int, block_k: int,
                             interpret: bool = True) -> jnp.ndarray:
     """The grouped RSA GEMM (OS): rows sorted into groups, each group's
-    segment padded to whole row tiles, times a bank of per-group weights.
-    The group of each row tile and the number of tiles in use ride in
-    scalar prefetch, so the weight BlockSpec's index map picks the tile's
-    own group.  Tiles past the used ones compute and write nothing, and
-    map to the last used tile's blocks, so nothing is fetched for them.
+    segment padded to whole row tiles, times a bank of per-group weights
+    read in place from a stack of layers' banks.  The group of each row
+    tile, the number of tiles in use and the layer ride in scalar
+    prefetch, so the weight BlockSpec's index map picks the tile's own
+    group in the layer's bank, and the stack is never sliced.  Tiles past
+    the used ones compute and write nothing, and map to the last used
+    tile's blocks, so nothing is fetched for them.
 
-    a: (R, K) rows, row tile t of group ``group[t]``; b: (G, K, N); used:
-    (1,) int32 row tiles in use.  R, K, N multiples of the blocks.
-    Returns (R, N); the rows of unused tiles are left unwritten."""
+    a: (R, K) rows, row tile t of group ``group[t]``; b: (L, G, K, N);
+    used: (1,) int32 row tiles in use; layer: (1,) int32 the bank of b.
+    R, K, N multiples of the blocks.  Returns (R, N); the rows of unused
+    tiles are left unwritten."""
     R, K = a.shape
-    _, K2, N = b.shape
+    _, _, K2, N = b.shape
     assert K == K2, (a.shape, b.shape)
     assert R % block_m == 0 and N % block_n == 0 and K % block_k == 0
     mt, nt, kt = R // block_m, N // block_n, K // block_k
@@ -158,29 +163,29 @@ def rsa_grouped_gemm_pallas(a: jnp.ndarray, b: jnp.ndarray,
         return (jnp.where(dead, jnp.maximum(u[0] - 1, 0), m),
                 jnp.where(dead, nt - 1, n), jnp.where(dead, kt - 1, k))
 
-    def a_map(m, n, k, g, u):
+    def a_map(m, n, k, g, u, l):
         mm, _, kk = blk(m, n, k, u)
         return mm, kk
 
-    def b_map(m, n, k, g, u):
+    def b_map(m, n, k, g, u, l):
         mm, nn, kk = blk(m, n, k, u)
-        return g[mm], kk, nn
+        return l[0], g[mm], kk, nn
 
-    def o_map(m, n, k, g, u):
+    def o_map(m, n, k, g, u, l):
         mm, nn, _ = blk(m, n, k, u)
         return mm, nn
 
     return pl.pallas_call(
         functools.partial(_kernel_grouped, n_k=kt),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(mt, nt, kt),
             in_specs=[pl.BlockSpec((block_m, block_k), a_map),
-                      pl.BlockSpec((1, block_k, block_n), b_map)],
+                      pl.BlockSpec((None, 1, block_k, block_n), b_map)],
             out_specs=pl.BlockSpec((block_m, block_n), o_map),
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((R, N), a.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(group, used, a, b)
+    )(group, used, layer, a, b)

@@ -228,7 +228,13 @@ def moe_serve(params: Params, x: jnp.ndarray, cfg: ArchConfig,
     (``dispatch.grouped_gemm``), and each row sums its experts' outputs
     with its combine weights.  Returns (y (..., d), load (2, E_pad) int32:
     the rows routed to each expert, and the rows its segment takes in the
-    grouped GEMM, padding included)."""
+    grouped GEMM, padding included).
+
+    Where ``params`` holds ``bank_layer`` (the paged layer scan,
+    ``models/serving.py`` ``_scan_paged_layers``), the banks are the
+    whole (L, E, ...) stacks, already in the compute dtype (so the casts
+    below emit nothing), and the grouped GEMMs read layer
+    ``bank_layer``'s banks in place, with no copy."""
     m = cfg.moe
     cdt = jnp.dtype(cfg.compute_dtype)
     d = x.shape[-1]
@@ -274,12 +280,13 @@ def moe_serve(params: Params, x: jnp.ndarray, cfg: ArchConfig,
         dispatch.segment_rows(T * k, m.num_experts))
 
     act = activation(cfg.mlp_activation)
+    layer = params.get("bank_layer")
     g = dispatch.grouped_gemm(xs, params["w_gate"].astype(cdt), layout,
-                              site="moe.expert.gate")
+                              site="moe.expert.gate", layer=layer)
     u = dispatch.grouped_gemm(xs, params["w_up"].astype(cdt), layout,
-                              site="moe.expert.up")
+                              site="moe.expert.up", layer=layer)
     o = dispatch.grouped_gemm(act(g) * u, params["w_down"].astype(cdt),
-                              layout, site="moe.expert.down")
+                              layout, site="moe.expert.down", layer=layer)
     out = o[jnp.minimum(row, R - 1)].astype(jnp.float32).reshape(T, k, d)
     keep = (row < R).reshape(T, k, 1)
     y = jnp.sum(jnp.where(keep, out * weights[..., None], 0.0), axis=1)

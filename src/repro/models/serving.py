@@ -172,10 +172,15 @@ def _scan_paged_layers(layer_fn, x, params: Params, arena: Dict[str, Any],
     updated in place through the loop (and, donated by the caller's jit,
     aliases the program's output) instead of being sliced per layer and
     stacked back.  A leading dense stack (deepseek-v3) runs first on the
-    same carry, so the layer index runs on across both stacks.  ``extra``:
-    per-layer xs of the main stack (encdec's cross K/V).  Returns (x,
-    {"k": k, "v": v}); where ``layer_fn`` returns a fourth value (each MoE
-    layer's expert load), the main stack's, stacked, come third."""
+    same carry, so the layer index runs on across both stacks.  The main
+    stack's routed expert banks (``moe.w_gate``, ``w_up``, ``w_down``)
+    stay out of the scan's xs: each layer's ``moe`` gets the whole
+    (L, E, ...) stacks, in the activations' dtype, and ``bank_layer``, its
+    index among them, and the grouped GEMM reads its bank in place
+    (``models/moe.py`` ``moe_serve``).  ``extra``: per-layer xs of the
+    main stack (encdec's cross K/V).  Returns (x, {"k": k, "v": v});
+    where ``layer_fn`` returns a fourth value (each MoE layer's expert
+    load), the main stack's, stacked, come third."""
 
     def body(carry, xs):
         h, k, v, layer = carry
@@ -183,11 +188,37 @@ def _scan_paged_layers(layer_fn, x, params: Params, arena: Dict[str, Any],
         return (*out[:3], layer + 1), out[3:]
 
     carry = (x, arena["k"], arena["v"], jnp.zeros((), jnp.int32))
+    n_dense = 0
     if "dense_layers" in params:
         carry, _ = jax.lax.scan(body, carry, (params["dense_layers"],))
-    (x, k, v, _), ys = jax.lax.scan(body, carry,
-                                    (params["layers"],) + tuple(extra))
+        n_dense = jax.tree_util.tree_leaves(
+            params["dense_layers"])[0].shape[0]
+    layers, banks = _split_banks(params["layers"], x.dtype)
+
+    def main_body(carry, xs):
+        if banks:
+            moe = dict(xs[0]["moe"], **banks, bank_layer=carry[3] - n_dense)
+            xs = (dict(xs[0], moe=moe),) + xs[1:]
+        return body(carry, xs)
+
+    (x, k, v, _), ys = jax.lax.scan(main_body, carry,
+                                    (layers,) + tuple(extra))
     return (x, {"k": k, "v": v}) + tuple(ys)
+
+
+_EXPERT_BANKS = ("w_gate", "w_up", "w_down")
+
+
+def _split_banks(layers: Params, dtype) -> Tuple[Params, Dict[str, Any]]:
+    """(the stacked layer tree less its routed expert banks, the banks'
+    (L, E, ...) stacks cast to ``dtype``); a tree without ``moe`` banks
+    comes back whole, with no banks."""
+    moe = layers.get("moe", {})
+    if not all(name in moe for name in _EXPERT_BANKS):
+        return layers, {}
+    rest = {n: w for n, w in moe.items() if n not in _EXPERT_BANKS}
+    return (dict(layers, moe=rest),
+            {n: moe[n].astype(dtype) for n in _EXPERT_BANKS})
 
 
 def paged_decode_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,

@@ -7,7 +7,8 @@
 - no routed row is dropped, even when every row picks the same experts
 - the selection bias changes which experts are chosen, never their weights
 - the grouped expert GEMM (interpret mode) equals an einsum over ragged
-  groups, with empty groups and a segment across a tile edge
+  groups, with empty groups and a segment across a tile edge, and reads
+  one layer of a stack of banks in place exactly as it reads that bank
 - rows of dead lanes and past a lane's chunk route to no expert
 """
 
@@ -250,40 +251,56 @@ def _layout(counts, bm, R):
                                 jnp.asarray(sizes, jnp.int32), bm, 4)
 
 
-@pytest.mark.parametrize("backend", ["pallas", "xla"])
-def test_grouped_gemm_matches_ragged_einsum(backend):
+@pytest.mark.parametrize("backend,layers", [
+    ("pallas", None), ("xla", None), ("pallas", 3), ("xla", 3)],
+    ids=["pallas", "xla", "pallas-stack", "xla-stack"])
+def test_grouped_gemm_matches_ragged_einsum(backend, layers):
     """Groups of 5, 0, 19 (across a tile edge), 0 and 8 rows in tiles of
     8: each group's rows times its own weights; the kernel in interpret
-    mode, and ragged_dot."""
+    mode, and ragged_dot.  With ``layers``, the bank is one layer of a
+    stack read in place: at every layer the whole output, unused tiles
+    included, is bit for bit the call on that layer's bank alone."""
     counts, bm, K, N = [5, 0, 19, 0, 8], 8, 256, 128
     sizes = -(-np.asarray(counts) // bm) * bm
     R = 64
     rng = np.random.default_rng(0)
     x = rng.standard_normal((R, K)).astype(np.float32)
-    w = rng.standard_normal((len(counts), K, N)).astype(np.float32)
+    stack = rng.standard_normal((layers or 1, len(counts), K, N)
+                                ).astype(np.float32)
     starts = np.cumsum(sizes) - sizes
     for s, c, z in zip(starts, counts, sizes):
         x[s + c:s + z] = 0.0                          # a segment's padding
     layout = _layout(counts, bm, R)
-    with dispatch.use(execute=backend, interpret=True):
-        y = np.asarray(dispatch.grouped_gemm(jnp.asarray(x), jnp.asarray(w),
-                                             layout, site="test.grouped"))
-    for g, (s, z) in enumerate(zip(starts, sizes)):
-        np.testing.assert_allclose(y[s:s + z], x[s:s + z] @ w[g],
-                                   rtol=1e-4, atol=1e-4)
+    for layer, w in enumerate(stack):
+        with dispatch.use(execute=backend, interpret=True):
+            y = np.asarray(dispatch.grouped_gemm(
+                jnp.asarray(x), jnp.asarray(w), layout, site="test.grouped"))
+            if layers:
+                np.testing.assert_array_equal(np.asarray(
+                    dispatch.grouped_gemm(
+                        jnp.asarray(x), jnp.asarray(stack), layout,
+                        site="test.grouped",
+                        layer=jnp.asarray(layer, jnp.int32))), y)
+        for g, (s, z) in enumerate(zip(starts, sizes)):
+            np.testing.assert_allclose(y[s:s + z], x[s:s + z] @ w[g],
+                                       rtol=1e-4, atol=1e-4)
 
 
 def test_grouped_kernel_skips_unused_tiles():
     """Tiles past the used ones are neither computed nor written: their
-    rows keep whatever the output held, the used rows are exact."""
+    rows keep whatever the output held, the used rows are exact, and the
+    bank is the one of the layer asked for in the stack."""
     counts, bm = [3, 9], 4
     R = 32
     layout = _layout(counts, bm, R)
     x = jnp.ones((R, 128), jnp.float32)
-    w = jnp.stack([jnp.full((128, 128), 1.0), jnp.full((128, 128), 2.0)])
+    w = jnp.stack([jnp.full((2, 128, 128), 1.0),
+                   jnp.stack([jnp.full((128, 128), 3.0),
+                              jnp.full((128, 128), 4.0)])])
     y = np.asarray(ops.moe_grouped_gemm(x, w, layout.group, layout.used,
+                                        jnp.asarray([1], jnp.int32),
                                         block_m=bm, block_n=128,
                                         block_k=128, interpret=True))
     assert int(layout.used[0]) == 4
-    np.testing.assert_array_equal(y[:4], 128.0)
-    np.testing.assert_array_equal(y[4:16], 256.0)
+    np.testing.assert_array_equal(y[:4], 384.0)
+    np.testing.assert_array_equal(y[4:16], 512.0)

@@ -15,6 +15,8 @@ only one process may load the TPU library, and each pytest-xdist worker
 imports every test file.  Keep every such compile in this one file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -172,6 +174,12 @@ def test_mla_paged_prefill_compiles(spec):
     assert "tpu_custom_call" in hlo
 
 
+ML_STACK = 6                      # MoE layers on the chip (7 less 1 dense)
+# a per-layer copy of an expert bank out of its stack
+BANK_COPY = re.compile(r"(fusion|copy|dynamic-slice)[\w.-]* = "
+                       rf"bf16\[{ML_E},({ML_D},{ML_F}|{ML_F},{ML_D})\]")
+
+
 @pytest.mark.parametrize("rows", [ML_LANES, ML_LANES * ML_CHUNK],
                          ids=["decode", "chunk"])
 @pytest.mark.parametrize("k,n", [(ML_D, ML_F), (ML_F, ML_D)],
@@ -179,7 +187,8 @@ def test_mla_paged_prefill_compiles(spec):
 def test_grouped_expert_gemm_compiles(spec, rows, k, n):
     """The serving MoE's grouped GEMM at the row tile the recommender
     gives the step's segments (384 routed rows in decode, 49,152 in a
-    chunk step) over its whole static buffer."""
+    chunk step) over its whole static buffer, reading one layer's bank
+    of the six MoE layers' stack in place."""
     from repro.models.moe import group_rows
     routed = rows * ML_K
     reg = dispatch.SiteRegistry()
@@ -188,12 +197,57 @@ def test_grouped_expert_gemm_compiles(spec, rows, k, n):
         bm = dispatch.group_block_m(routed, ML_E, ML_D, ML_F, jnp.bfloat16)
         R = group_rows(routed, ML_E, bm)
         hlo = _compile(
-            lambda x, w, g, u, z: dispatch.grouped_gemm(
+            lambda x, w, g, u, z, layer: dispatch.grouped_gemm(
                 x, w, dispatch.GroupLayout(g, u, z, bm, routed // ML_E),
-                site="moe.expert"),
-            spec((R, k)), spec((ML_E, k, n)), spec((R // bm,), jnp.int32),
-            spec((1,), jnp.int32), spec((ML_E,), jnp.int32))
+                site="moe.expert", layer=layer),
+            spec((R, k)), spec((ML_STACK, ML_E, k, n)),
+            spec((R // bm,), jnp.int32), spec((1,), jnp.int32),
+            spec((ML_E,), jnp.int32), spec((), jnp.int32))
     assert "tpu_custom_call" in hlo
+    assert not BANK_COPY.search(hlo)
     rec = reg.sites("_")["moe.expert"]
     assert (rec.backend, rec.m, rec.block_m) == ("pallas", R, bm)
+    assert (rec.k, rec.n) == (k, n)
     assert k % rec.block_k == 0 and n % rec.block_n == 0   # no bank padding
+
+
+def test_moe_layer_scan_reads_banks_in_place(spec):
+    """Moonlight's whole paged decode step (the dense layer, then the
+    layer scan over six MoE layers, 64 lanes): every grouped GEMM reads
+    its layer's banks from the stacked weights, so no fusion, copy or
+    dynamic slice produces a (64, 2048, 1408) or (64, 1408, 2048) bank."""
+    from repro.configs.base import ArchConfig, MLAConfig, MoEConfig
+    from repro.models.api import build_model
+    cfg = ArchConfig(
+        name="moonlight-16b-a3b", family="moe", num_layers=ML_STACK + 1,
+        d_model=ML_D, num_heads=ML_HEADS, num_kv_heads=ML_HEADS,
+        head_dim=192, d_ff=11264, vocab_size=163840, attention_type="mla",
+        mla=MLAConfig(q_lora_rank=None, kv_lora_rank=ML_R,
+                      qk_nope_head_dim=128, qk_rope_head_dim=ML_RD,
+                      v_head_dim=128),
+        moe=MoEConfig(num_experts=ML_E, num_shared_experts=2,
+                      experts_per_token=ML_K, d_ff_expert=ML_F,
+                      scoring_func="sigmoid", topk_method="noaux_tc",
+                      norm_topk_prob=True, routed_scaling_factor=2.446),
+        first_dense_layers=1, rope_theta=50000.0, norm_eps=1e-5,
+        tie_embeddings=False, param_dtype="bfloat16",
+        compute_dtype="bfloat16")
+    model = build_model(cfg)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), tree)
+
+    params = shaped(model.init_abstract())
+    assert params["layers"]["moe"]["w_gate"].shape == (
+        ML_STACK, ML_E, ML_D, ML_F)
+    arena = shaped(jax.eval_shape(
+        lambda: model.init_paged_arena(ML_PAGES, BS)))
+    lanes = spec((ML_LANES,), jnp.int32)
+    with dispatch.use(SaraDispatcher(), execute="pallas", interpret=False):
+        hlo = _compile(
+            lambda *a: model.paged_decode_step(*a, moe_loads=True),
+            params, spec((ML_LANES, 1), jnp.int32), {}, arena,
+            spec((ML_LANES, 256), jnp.int32), lanes, lanes)
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") >= 3
+    assert "moe_grouped_gemm" in hlo
+    assert not BANK_COPY.search(hlo)
