@@ -2,8 +2,8 @@
 
 Serves the same request set — a fraction of prompts open with one common
 token run (the system-prompt workload prefix caching is for), arrivals
-drawn from a Poisson process in virtual step time — through three
-engines and reports, per variant:
+drawn from a Poisson process in virtual step time — through two
+engines, cache off and cache on, and reports, per variant:
 
   * TTFT p50/p99 (virtual steps): a cache hit maps the shared prefix's
     KV pages at admission, so a recipient prefills only its suffix —
@@ -17,15 +17,6 @@ engines and reports, per variant:
     FLOPs avoided, COW copies, live shared pages
   * greedy parity: cache-on must emit exactly the cache-off tokens
     (page sharing is bitwise — same rows, same physical arena reads)
-
-The cascade variant (``shared_prefix_decode``) additionally batches
-decode attention over the group's common physical prefix.  The XLA
-reference rebuilds each lane's combined table and runs ONE masked
-softmax, so cascade greedy tokens are bitwise the plain tokens and the
-bench ASSERTS per-request equality wherever the resolved paged impl is
-``xla`` (everywhere off-TPU).  Only the Pallas kernel keeps the
-two-phase online-softmax merge — streaming shared pages once per group
-is its point — so on TPU the match is reported as a fraction instead.
 
 ``--smoke`` is the CI gate: hits > 0, exact greedy parity cache-on vs
 cache-off, KV-write reduction > 1.4x on the tiny trace, and a bounded
@@ -62,14 +53,12 @@ def _trace(cfg, rng, n, shared_frac, prefix_len, prompt_len, gen,
     return reqs
 
 
-def _serve(cfg, reqs, *, max_len, num_blocks, chunk,
-           prefix_cache=False, cascade=False):
+def _serve(cfg, reqs, *, max_len, num_blocks, chunk, prefix_cache=False):
     from repro.serving import EngineConfig, ServingEngine
     engine = ServingEngine(cfg, EngineConfig(
         num_slots=4, max_len=max_len, block_size=BLOCK,
         num_blocks=num_blocks, temperature=0.0, kv_layout="paged",
-        prefill_chunk=chunk, prefix_cache=prefix_cache,
-        shared_prefix_decode=cascade))
+        prefill_chunk=chunk, prefix_cache=prefix_cache))
     res = engine.run(reqs)
     if engine.prefix_cache is not None:
         engine.prefix_cache.clear()
@@ -90,7 +79,6 @@ def run(n: int = 16, shared_frac: float = 0.75, prefix_len: int = 64,
     variants = [
         ("cache_off", dict()),
         ("cache_on", dict(prefix_cache=True)),
-        ("cache_on_cascade", dict(prefix_cache=True, cascade=True)),
     ]
     rows, outputs, kv_rows = [], {}, {}
     for name, kw in variants:
@@ -129,42 +117,20 @@ def run(n: int = 16, shared_frac: float = 0.75, prefix_len: int = 64,
                 {"name": f"bench_prefix_cache.{name}.kv_cow_copies",
                  "value": s["kv_cow_copies"]},
             ]
-        if kw.get("cascade"):
-            rows.append(
-                {"name": f"bench_prefix_cache.{name}.shared_prefix_steps",
-                 "value": int(eng.obs.counters.get("shared_prefix_steps",
-                                                   0)),
-                 "derived": "decode steps batched over a common prefix"})
 
     # -- cross-variant claims -------------------------------------------------
     reduction = kv_rows["cache_off"] / max(kv_rows["cache_on"], 1)
     assert reduction >= 2.0, \
         f"prefill KV-write reduction {reduction:.2f}x < 2x " \
         f"({kv_rows['cache_off']} vs {kv_rows['cache_on']} rows)"
-    off = {k: v for k, v in outputs["cache_off"].items()}
-    for rid, toks in off.items():
+    for rid, toks in outputs["cache_off"].items():
         np.testing.assert_array_equal(outputs["cache_on"][rid], toks)
-    from repro.kernels.ops import default_paged_impl
-    if default_paged_impl() == "xla":
-        # single-softmax XLA cascade: bitwise parity is a hard claim
-        for rid, toks in off.items():
-            np.testing.assert_array_equal(outputs["cache_on_cascade"][rid],
-                                          toks)
-        match = 1.0
-        cascade_note = "single masked softmax; asserted bitwise"
-    else:
-        # Pallas keeps the two-phase online-softmax merge (reassociated)
-        match = np.mean([np.array_equal(outputs["cache_on_cascade"][r], t)
-                         for r, t in off.items()])
-        cascade_note = "pallas two-phase merge; reported, not asserted"
     rows += [
         {"name": "bench_prefix_cache.prefill_kv_write_reduction_x",
          "value": round(reduction, 3),
          "derived": "cache_off rows / cache_on rows (claim: >= 2x)"},
         {"name": "bench_prefix_cache.greedy_parity", "value": 1,
          "derived": "cache_on tokens == cache_off tokens, exactly"},
-        {"name": "bench_prefix_cache.cascade_greedy_match_frac",
-         "value": round(float(match), 3), "derived": cascade_note},
     ]
     return emit(rows, "bench_prefix_cache",
                 config={"n": n, "shared_frac": shared_frac,

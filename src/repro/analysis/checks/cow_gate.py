@@ -29,7 +29,6 @@ WRITERS = {
     "_paged_write",             # engine jit wrapper: bucketed prefill write
     "_chunk_prefill",           # engine jit wrapper: ragged chunk prefill
     "_paged_decode",            # engine jit wrapper: decode append + attend
-    "_paged_shared_decode",     # engine jit wrapper: cascade decode append
     "paged_prefill_write",      # model-level bucketed KV scatter
     "copy_page",                # raw arena page copy
     "apply_moves",              # raw arena defrag gather

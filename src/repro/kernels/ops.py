@@ -136,11 +136,10 @@ def _paged_impl(impl: Optional[str]) -> str:
 
 
 def _paged_gqa(q, k_arena, v_arena, tables, starts, lengths, scale,
-               interpret, logit_cap, lse=False):
+               interpret, logit_cap):
     """Run the GQA paged kernel on (S, C, H, hd) chunk queries: regroup the
     query heads under their KV head, one (C*G, hd) row block per head, and
-    back.  Returns o (S, C, H, hd_v), or with ``lse`` (o, m (S, C, H) f32,
-    l (S, C, H) f32)."""
+    back.  Returns o (S, C, H, hd_v)."""
     from repro.kernels.paged_attn import paged_gqa_pallas
     S, C, H, hd = q.shape
     KVH = k_arena.shape[2]
@@ -148,16 +147,9 @@ def _paged_gqa(q, k_arena, v_arena, tables, starts, lengths, scale,
     qg = q.reshape(S, C, KVH, G, hd).transpose(0, 2, 1, 3, 4)
     out = paged_gqa_pallas(qg.reshape(S, KVH, C * G, hd), k_arena, v_arena,
                            tables, starts, lengths, scale, interpret,
-                           group=G, logit_cap=logit_cap, lse=lse)
-
-    def back(x):
-        x = x.reshape(S, KVH, C, G, x.shape[-1]).transpose(0, 2, 1, 3, 4)
-        return x.reshape(S, C, H, x.shape[-1])
-
-    if not lse:
-        return back(out)
-    o, m, l = out
-    return back(o), back(m)[..., 0], back(l)[..., 0]
+                           group=G, logit_cap=logit_cap)
+    out = out.reshape(S, KVH, C, G, out.shape[-1]).transpose(0, 2, 1, 3, 4)
+    return out.reshape(S, C, H, out.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("logit_cap", "impl",
@@ -183,64 +175,6 @@ def paged_attention(q, k_arena, v_arena, tables, lengths, *,
     o = _paged_gqa(q[:, None], k_arena, v_arena, tables, lengths - 1,
                    lengths, scale, _interpret(interpret), logit_cap)
     return o[:, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("logit_cap", "impl",
-                                             "interpret"))
-def shared_paged_attention(q, k_arena, v_arena, unique_tables, unique_lens,
-                           prefix_pages, prefix_lens, *,
-                           logit_cap: float = 0.0,
-                           impl: Optional[str] = None,
-                           interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Cascade decode for shared prefixes: one softmax pass over a lane's
-    shared-prefix rows (streamed ONCE for every sharing lane via
-    ``prefix_pages``) plus one over its unique suffix rows (per-lane
-    ``unique_tables``).  Mathematically equal to :func:`paged_attention`
-    over the concatenated page lists.  The XLA reference rebuilds each
-    lane's combined table and runs ONE masked softmax, so it is BITWISE
-    equal to the plain path (greedy cascade parity is asserted, not
-    approximate); the Pallas path keeps the two-phase online-softmax
-    merge — streaming the shared pages once per group is its point — and
-    matches numerically.
-
-    q: (S, H, hd) one query token per lane; prefix_pages: (P,) int32 pages
-    every sharing lane's table starts with (tail-pad with the last id);
-    prefix_lens: (S,) int32 prefix rows lane s attends (0 = lane not in
-    the sharing group); unique_tables: (S, W) int32 each lane's pages PAST
-    the prefix (its full table shifted left; non-members keep their whole
-    table here); unique_lens: (S,) int32 valid suffix rows.  Returns
-    (S, H, hd_v); lanes empty in both phases yield zeros.
-    """
-    S, H, hd = q.shape
-    KVH = k_arena.shape[2]
-    scale = 1.0 / (hd ** 0.5)
-    if _paged_impl(impl) == "xla":
-        from repro.kernels.ref import shared_paged_attention_ref
-        return shared_paged_attention_ref(
-            q, k_arena, v_arena, unique_tables, unique_lens, prefix_pages,
-            prefix_lens, scale=scale, logit_cap=logit_cap)
-    from repro.kernels.paged_attn import paged_gqa_prefix_pallas
-    from repro.kernels.ref import merge_softmax_states
-    G = H // KVH
-    itp = _interpret(interpret)
-    # prefix phase: every lane's queries stacked per KV head
-    qp = q.reshape(S, KVH, G, hd).transpose(1, 0, 2, 3).reshape(KVH, S * G,
-                                                                 hd)
-    o_p, m_p, l_p = paged_gqa_prefix_pallas(
-        qp, k_arena, v_arena, prefix_pages, prefix_lens, scale, itp,
-        group=G, logit_cap=logit_cap)
-
-    def lanes_first(x):
-        x = x.reshape(KVH, S, G, x.shape[-1]).transpose(1, 0, 2, 3)
-        return x.reshape(S, H, x.shape[-1])
-
-    o_u, m_u, l_u = _paged_gqa(q[:, None], k_arena, v_arena, unique_tables,
-                               unique_lens - 1, unique_lens, scale, itp,
-                               logit_cap, lse=True)
-    o, _, _ = merge_softmax_states(
-        lanes_first(o_p), lanes_first(m_p)[..., 0], lanes_first(l_p)[..., 0],
-        o_u[:, 0], m_u[:, 0], l_u[:, 0])
-    return o.astype(q.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("qk_dim", "impl", "interpret"))

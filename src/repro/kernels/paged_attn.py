@@ -19,9 +19,7 @@ Two kernel families share the structure:
   case with ``starts = lengths - 1``.  Per-lane ``starts`` / ``lengths``
   make the batch ragged: lanes whose chunk is empty (``lengths[lane] ==
   0``) skip every block, which is how one batch carries heterogeneous
-  prompt lengths.  The kernel also emits the online-softmax state, which
-  cascade decode merges with the shared-prefix phase
-  (``paged_gqa_prefix_pallas``).
+  prompt lengths.
 * **absorbed MLA** (``paged_mla_decode_pallas`` /
   ``paged_mla_prefill_pallas``) — see below.
 
@@ -65,12 +63,6 @@ ROW_BLOCK = 512
 # GQA
 # ---------------------------------------------------------------------------
 
-def _reset_state(m_scr, l_scr, acc_scr):
-    m_scr[...] = jnp.full_like(m_scr, NEG)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-
-
 def _fold_page(q, k, v, live, h, m_scr, l_scr, acc_scr, *, scale,
                logit_cap):
     """Fold one page of keys into KV head ``h``'s running softmax state.
@@ -94,22 +86,8 @@ def _fold_page(q, k, v, live, h, m_scr, l_scr, acc_scr, *, scale,
     l_scr[h] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
 
 
-def _emit_state(outs, m_scr, l_scr, acc_scr):
-    """Write the normalized output and, when two more output refs follow
-    it, the raw (m, l) softmax state.  Rows that never accumulated keep
-    (0, NEG, 0): zeros out, and a softmax-state merge degenerates to the
-    other phase."""
-    o_ref = outs[0]
-    o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-                  ).reshape(o_ref.shape).astype(o_ref.dtype)
-    if len(outs) == 3:
-        outs[1][...] = m_scr[...].reshape(outs[1].shape)
-        outs[2][...] = l_scr[...].reshape(outs[2].shape)
-
-
-def _gqa_kernel(tables, starts, lengths, q_ref, k_ref, v_ref, *refs, bs,
-                n_bt, group, scale, logit_cap):
-    *outs, m_scr, l_scr, acc_scr = refs
+def _gqa_kernel(tables, starts, lengths, q_ref, k_ref, v_ref, o_ref, m_scr,
+                l_scr, acc_scr, *, bs, n_bt, group, scale, logit_cap):
     lane = pl.program_id(0)
     row0 = pl.program_id(1) * q_ref.shape[2]   # first flat row of the block
     j = pl.program_id(2)
@@ -118,7 +96,9 @@ def _gqa_kernel(tables, starts, lengths, q_ref, k_ref, v_ref, *refs, bs,
 
     @pl.when(j == 0)
     def _reset():
-        _reset_state(m_scr, l_scr, acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     @pl.when(j * bs < kv_len)
     def _accumulate():
@@ -138,12 +118,14 @@ def _gqa_kernel(tables, starts, lengths, q_ref, k_ref, v_ref, *refs, bs,
 
     @pl.when(j == n_bt - 1)
     def _emit():
-        _emit_state(outs, m_scr, l_scr, acc_scr)
+        # rows that never accumulated keep l == 0 and come out as zeros
+        o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                      ).reshape(o_ref.shape).astype(o_ref.dtype)
 
 
 def paged_gqa_pallas(q, k_arena, v_arena, tables, starts, lengths,
                      scale: float, interpret: bool, *, group: int,
-                     logit_cap: float = 0.0, lse: bool = False):
+                     logit_cap: float = 0.0):
     """q: (S, KVH, C*G, hd) one chunk of queries per lane and KV head, flat
     row ``c * G + g`` for chunk row c and query g of the head's group
     (``group`` = G); k_arena: (NB, bs, KVH, hd); v_arena: (NB, bs, KVH,
@@ -153,9 +135,7 @@ def paged_gqa_pallas(q, k_arena, v_arena, tables, starts, lengths,
     *including* the chunk (``starts + chunk_len``).  The chunk's own K/V
     rows must already be in the arena.  Returns o (S, KVH, C*G, hd_v)
     normalized; rows past a lane's chunk are garbage the caller discards,
-    lanes with length 0 yield zeros.  With ``lse`` it returns (o, m, l),
-    adding the softmax state cascade decode merges: m (S, KVH, C*G, 1) f32
-    running max and l (S, KVH, C*G, 1) f32 exp-sum."""
+    lanes with length 0 yield zeros."""
     S, KVH, R, hd = q.shape
     bs = k_arena.shape[1]
     hd_v = v_arena.shape[-1]
@@ -174,12 +154,6 @@ def paged_gqa_pallas(q, k_arena, v_arena, tables, starts, lengths,
                             lambda s, r, j, t, st, ln: (t[s, j], 0, 0, 0))
 
     rows = R + pad
-    out_specs = [row_block(hd_v)]
-    out_shape = [jax.ShapeDtypeStruct((S, KVH, rows, hd_v), q.dtype)]
-    if lse:
-        out_specs += [row_block(1)] * 2
-        out_shape += [jax.ShapeDtypeStruct((S, KVH, rows, 1),
-                                           jnp.float32)] * 2
     out = pl.pallas_call(
         functools.partial(_gqa_kernel, bs=bs, n_bt=W, group=group,
                           scale=scale, logit_cap=logit_cap),
@@ -187,108 +161,17 @@ def paged_gqa_pallas(q, k_arena, v_arena, tables, starts, lengths,
             num_scalar_prefetch=3,
             grid=(S, rows // rb, W),
             in_specs=[row_block(hd), page_block(hd), page_block(hd_v)],
-            out_specs=out_specs,
+            out_specs=row_block(hd_v),
             scratch_shapes=[pltpu.VMEM((KVH, rb, 1), jnp.float32),
                             pltpu.VMEM((KVH, rb, 1), jnp.float32),
                             pltpu.VMEM((KVH, rb, hd_v), jnp.float32)],
         ),
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct((S, KVH, rows, hd_v), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tables, starts, lengths, q, k_arena, v_arena)
-    out = [x[:, :, :R] for x in out]
-    return tuple(out) if lse else out[0]
-
-
-# ---------------------------------------------------------------------------
-# shared-prefix (cascade) decode: one walk over the hot pages for all lanes
-# ---------------------------------------------------------------------------
-
-def _gqa_prefix_kernel(pages, nlive, plen_ref, q_ref, k_ref, v_ref, *refs,
-                       bs, n_bt, scale, logit_cap):
-    *outs, m_scr, l_scr, acc_scr = refs
-    j = pl.program_id(0)
-
-    @pl.when(j == 0)
-    def _reset():
-        _reset_state(m_scr, l_scr, acc_scr)
-
-    @pl.when(j * bs < nlive[0])
-    def _accumulate():
-        # every lane's queries stacked into one MXU call against the SAME
-        # page: the page DMA happens once per page grid step, not once per
-        # lane — that is the cascade win.  Flat row i belongs to lane
-        # i // G; its prefix_len gates how much of the shared run it
-        # attends (0 = lane outside the group)
-        for h in range(q_ref.shape[0]):
-            q = q_ref[h]                                   # (S*G, hd)
-            col = j * bs + jax.lax.broadcasted_iota(
-                jnp.int32, (q.shape[0], bs), 1)
-            _fold_page(q, k_ref[0, :, h, :], v_ref[0, :, h, :],
-                       col < plen_ref[...], h, m_scr, l_scr, acc_scr,
-                       scale=scale, logit_cap=logit_cap)
-
-    @pl.when(j == n_bt - 1)
-    def _emit():
-        _emit_state(outs, m_scr, l_scr, acc_scr)
-
-
-def paged_gqa_prefix_pallas(q, k_arena, v_arena, prefix_pages, prefix_lens,
-                            scale: float, interpret: bool, *, group: int,
-                            logit_cap: float = 0.0):
-    """Shared-prefix phase of cascade decode: ONE grid walk over the hot
-    prefix pages serves every lane at once.
-
-    q: (KVH, S*G, hd) every lane's decode queries per KV head, flat row
-    ``s * G + g`` (``group`` = G); prefix_pages: (P,) int32 physical pages
-    of the shared prefix in logical order (tail-pad with the last id);
-    prefix_lens: (S,) int32 prefix rows lane s attends (0 = lane not in the
-    sharing group).  The grid is (P,) — lanes are NOT a grid dimension; all
-    lanes' queries hit each page block together, so a prefix shared by k
-    lanes is streamed once instead of k times.  Returns (o (KVH, S*G, hd_v)
-    normalized, m (KVH, S*G, 1) f32, l (KVH, S*G, 1) f32); lanes with
-    prefix_lens == 0 come back as (0, NEG, 0) so the merge degenerates to
-    the unique phase."""
-    KVH, R, hd = q.shape
-    bs = k_arena.shape[1]
-    hd_v = v_arena.shape[-1]
-    P = prefix_pages.shape[0]
-    # scalar skip bound for padded tail columns (every sharing lane spans
-    # the same page run, so max == the run's row count)
-    nlive = jnp.max(prefix_lens).astype(jnp.int32).reshape(1)
-    # per-row lengths ride as a VMEM operand (not scalar prefetch): the
-    # kernel needs them as a vector to mask the stacked (S*G, bs) scores
-    plens = jnp.repeat(prefix_lens.astype(jnp.int32), group).reshape(R, 1)
-
-    def whole(shape):
-        return pl.BlockSpec(shape, lambda j, t, nl: (0,) * len(shape))
-
-    def page_block(width):
-        return pl.BlockSpec((1, bs, KVH, width),
-                            lambda j, t, nl: (t[j], 0, 0, 0))
-
-    return pl.pallas_call(
-        functools.partial(_gqa_prefix_kernel, bs=bs, n_bt=P, scale=scale,
-                          logit_cap=logit_cap),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(P,),
-            in_specs=[whole((R, 1)), whole((KVH, R, hd)), page_block(hd),
-                      page_block(hd_v)],
-            out_specs=[whole((KVH, R, hd_v)), whole((KVH, R, 1)),
-                       whole((KVH, R, 1))],
-            scratch_shapes=[pltpu.VMEM((KVH, R, 1), jnp.float32),
-                            pltpu.VMEM((KVH, R, 1), jnp.float32),
-                            pltpu.VMEM((KVH, R, hd_v), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((KVH, R, hd_v), q.dtype),
-                   jax.ShapeDtypeStruct((KVH, R, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((KVH, R, 1), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(prefix_pages, nlive, plens, q, k_arena, v_arena)
+    return out[:, :, :R]
 
 
 # ---------------------------------------------------------------------------

@@ -168,131 +168,6 @@ def paged_mla_prefill_attention_ref(q_abs, q_rope, ckv_arena, krope_arena,
     return o.astype(q_abs.dtype)
 
 
-def merge_softmax_states(o_a, m_a, l_a, o_b, m_b, l_b):
-    """Combine two *normalized* partial-attention outputs over disjoint key
-    sets into the exact softmax over their union.
-
-    ``o_*``: (..., hd_v) normalized partial outputs; ``m_*``: (...) running
-    max of the raw scores; ``l_*``: (...) sum of ``exp(score - m)``.  An
-    empty state (``l == 0``, ``m == -1e30``) degenerates to the other side;
-    two empty states yield zeros.  Returns (o, m, l) of the union.
-    """
-    o_a, o_b = o_a.astype(jnp.float32), o_b.astype(jnp.float32)
-    m = jnp.maximum(m_a, m_b)
-    a = l_a * jnp.exp(m_a - m)
-    b = l_b * jnp.exp(m_b - m)
-    l = a + b
-    denom = jnp.maximum(l, 1e-30)
-    o = (o_a * a[..., None] + o_b * b[..., None]) / denom[..., None]
-    return o, m, l
-
-
-def paged_attention_lse_ref(q, k_arena, v_arena, tables, lengths,
-                            *, scale: float | None = None,
-                            logit_cap: float = 0.0):
-    """:func:`paged_attention_ref` that also returns the online-softmax
-    state, for merging with another phase (shared-prefix cascade decode).
-
-    Returns (o (S, H, hd_v) normalized, m (S, H) f32 running max, l (S, H)
-    f32 exp-sum); empty lanes come back as (0, -1e30, 0).
-    """
-    S, H, hd = q.shape
-    KVH = k_arena.shape[2]
-    G = H // KVH
-    if scale is None:
-        scale = 1.0 / (hd ** 0.5)
-    k = paged_gather(k_arena, tables).astype(jnp.float32)   # (S, L, KVH, hd)
-    v = paged_gather(v_arena, tables).astype(jnp.float32)
-    qf = q.astype(jnp.float32).reshape(S, KVH, G, hd)
-    s = jnp.einsum("shgd,slhd->shgl", qf, k) * scale
-    if logit_cap > 0.0:
-        s = jnp.tanh(s / logit_cap) * logit_cap
-    mask = jnp.arange(k.shape[1])[None, :] < lengths[:, None]   # (S, L)
-    s = jnp.where(mask[:, None, None, :], s, -1e30)
-    m = jnp.max(s, axis=-1)                                 # (S, KVH, G)
-    # the explicit mask on p (not just on s) keeps fully-masked lanes at
-    # l == 0: with m == -1e30 every masked exp(s - m) would be exp(0) == 1
-    p = jnp.where(mask[:, None, None, :], jnp.exp(s - m[..., None]), 0.0)
-    l = jnp.sum(p, axis=-1)
-    o = jnp.einsum("shgl,slhd->shgd", p, v) / \
-        jnp.maximum(l, 1e-30)[..., None]
-    return (o.reshape(S, H, v.shape[-1]).astype(q.dtype),
-            m.reshape(S, H), l.reshape(S, H))
-
-
-def shared_prefix_attention_ref(q, k_arena, v_arena, prefix_pages,
-                                prefix_lens, *, scale: float | None = None,
-                                logit_cap: float = 0.0):
-    """Partial decode attention over ONE shared page list for every lane.
-
-    q: (S, H, hd); prefix_pages: (P,) int32 physical pages every sharing
-    lane's table starts with; prefix_lens: (S,) int32 prefix rows lane s
-    attends (0 = lane not in the sharing group -> empty state).  Returns
-    (o, m, l) as in :func:`paged_attention_lse_ref`.
-    """
-    S, H, hd = q.shape
-    KVH = k_arena.shape[2]
-    G = H // KVH
-    if scale is None:
-        scale = 1.0 / (hd ** 0.5)
-    k = k_arena[prefix_pages].astype(jnp.float32)           # (P, bs, KVH, hd)
-    v = v_arena[prefix_pages].astype(jnp.float32)
-    k = k.reshape((-1,) + k.shape[2:])                      # (P*bs, KVH, hd)
-    v = v.reshape((-1,) + v.shape[2:])
-    qf = q.astype(jnp.float32).reshape(S, KVH, G, hd)
-    s = jnp.einsum("shgd,lhd->shgl", qf, k) * scale
-    if logit_cap > 0.0:
-        s = jnp.tanh(s / logit_cap) * logit_cap
-    mask = jnp.arange(k.shape[0])[None, :] < prefix_lens[:, None]   # (S, L)
-    s = jnp.where(mask[:, None, None, :], s, -1e30)
-    m = jnp.max(s, axis=-1)
-    p = jnp.where(mask[:, None, None, :], jnp.exp(s - m[..., None]), 0.0)
-    l = jnp.sum(p, axis=-1)
-    o = jnp.einsum("shgl,lhd->shgd", p, v) / \
-        jnp.maximum(l, 1e-30)[..., None]
-    return (o.reshape(S, H, v.shape[-1]).astype(q.dtype),
-            m.reshape(S, H), l.reshape(S, H))
-
-
-def shared_paged_attention_ref(q, k_arena, v_arena, unique_tables,
-                               unique_lens, prefix_pages, prefix_lens,
-                               *, scale: float | None = None,
-                               logit_cap: float = 0.0) -> jnp.ndarray:
-    """Cascade decode oracle — BITWISE equal to :func:`paged_attention_ref`
-    over the concatenated page lists.
-
-    Instead of running the prefix and unique phases separately and merging
-    online-softmax states (which reassociates the reduction, so greedy
-    parity with the plain path held only numerically), each lane's combined
-    table is rebuilt gap-free — its prefix pages followed immediately by
-    its unique pages, exactly the order the lane's full block table has
-    them in — and ONE masked softmax runs over it via
-    :func:`paged_attention_ref`.  The only difference from the plain path
-    is trailing table padding, and padded columns are exact no-ops: their
-    ``-1e30`` scores underflow to 0.0 after ``exp``, leaving every partial
-    sum bit-identical.  The two-phase + merge structure survives in the
-    Pallas kernel path (``ops.shared_paged_attention``), where streaming
-    the shared pages once per group is the point.  Returns (S, H, hd_v).
-    """
-    S = q.shape[0]
-    bs = k_arena.shape[1]
-    pw = prefix_pages.shape[0]
-    uw = unique_tables.shape[1]
-    # pages each lane takes from the shared run (prefix_lens is a whole
-    # number of fully-written pages by construction; 0 = not in the group)
-    npref = prefix_lens // bs                               # (S,)
-    j = jnp.arange(pw + uw)                                 # (W,)
-    in_prefix = j[None, :] < npref[:, None]                 # (S, W)
-    pref_cols = jnp.broadcast_to(prefix_pages[jnp.clip(j, 0, pw - 1)][None],
-                                 (S, pw + uw))
-    uniq_idx = jnp.clip(j[None, :] - npref[:, None], 0, uw - 1)
-    uniq_cols = jnp.take_along_axis(unique_tables, uniq_idx, axis=1)
-    combined = jnp.where(in_prefix, pref_cols, uniq_cols)   # (S, W) int32
-    return paged_attention_ref(q, k_arena, v_arena, combined,
-                               prefix_lens + unique_lens, scale=scale,
-                               logit_cap=logit_cap)
-
-
 def linear_attn_ref(r, k, v, logw, u) -> jnp.ndarray:
     """Exact sequential recurrence (the definition, O(S) steps).
 

@@ -106,7 +106,6 @@ def serve_continuous(arch: str = "llama3.2-1b", preset: str = "reduced",
                      dispatcher: str = "oracle",
                      adaptnet_ckpt: str = None, kv_layout: str = "auto",
                      prefill_chunk: int = None, prefix_cache: bool = False,
-                     shared_prefix_decode: bool = False,
                      defrag_threshold: float = None,
                      shared_prefix_len: int = 0, trace_out: str = None,
                      sanitize: bool = False, chaos=None,
@@ -139,8 +138,7 @@ def serve_continuous(arch: str = "llama3.2-1b", preset: str = "reduced",
     output.  ``prefix_cache`` (requires ``prefill_chunk``) turns on the
     cross-request prefix cache: prompts that open with an
     already-served token run map those KV pages refcounted/copy-on-write
-    instead of recomputing them; ``shared_prefix_decode`` additionally
-    batches decode attention over the common physical prefix (cascade).
+    instead of recomputing them.
     ``chaos`` (a :class:`repro.serving.faults.ChaosConfig`) turns on the
     seed-driven fault-injection harness — injected pool OOMs / poisoned
     pages / stalls / forced preemptions are contained by the engine's
@@ -172,7 +170,6 @@ def serve_continuous(arch: str = "llama3.2-1b", preset: str = "reduced",
         execute=execute, dispatcher_mode=dispatcher,
         adaptnet_dir=adaptnet_ckpt, kv_layout=kv_layout,
         prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
-        shared_prefix_decode=shared_prefix_decode,
         defrag_threshold=defrag_threshold, trace=trace_out is not None,
         sanitize=sanitize, chaos=chaos, snapshot_dir=snapshot_dir,
         snapshot_every=snapshot_every, spec_draft=spec_draft,
@@ -244,11 +241,6 @@ def main():
                          "copy-on-write KV pages shared across prompts "
                          "with a common token prefix (requires "
                          "--prefill-chunk and the paged layout)")
-    ap.add_argument("--shared-prefix-decode", action="store_true",
-                    help="with --prefix-cache: cascade decode attention — "
-                         "one pass over the common physical prefix pages "
-                         "+ per-lane unique suffixes, merged by softmax "
-                         "state (reassociates the softmax; opt-in)")
     ap.add_argument("--defrag-threshold", type=float, default=None,
                     help="auto-defragment the KV pool from the engine "
                          "step loop when fragmentation exceeds this "
@@ -332,7 +324,7 @@ def main():
         return
     if a.smoke and a.prefix_cache:
         # Prefix-cache smoke: a shared-prefix workload served twice —
-        # cache off, then cache on (+ optional cascade) — must agree
+        # cache off, then cache on — must agree
         # token-for-token under greedy sampling while the cached run
         # actually reuses pages.
         common = dict(
@@ -344,9 +336,7 @@ def main():
             sanitize=a.sanitize, log=False)
         base, _ = serve_continuous(**common)
         outputs, engine = serve_continuous(
-            **common, prefix_cache=True,
-            shared_prefix_decode=a.shared_prefix_decode,
-            trace_out=a.trace_out)
+            **common, prefix_cache=True, trace_out=a.trace_out)
         assert all(len(v) == 6 for v in outputs.values()), outputs
         assert set(outputs) == set(base)
         for rid in base:
@@ -447,7 +437,6 @@ def main():
                      adaptnet_ckpt=a.adaptnet_ckpt, kv_layout=a.kv_layout,
                      prefill_chunk=a.prefill_chunk or None,
                      prefix_cache=a.prefix_cache,
-                     shared_prefix_decode=a.shared_prefix_decode,
                      defrag_threshold=a.defrag_threshold,
                      shared_prefix_len=a.shared_prefix_len,
                      trace_out=a.trace_out, sanitize=a.sanitize,

@@ -12,10 +12,11 @@ from repro.models import serving, transformer
 from repro.models.modules import param_count
 
 
-def _loads(moe_loads: bool) -> Dict[str, bool]:
-    """The step programs' ``moe_loads`` option, passed only where it is on
-    (a step function without it keeps the plain signature)."""
-    return {"moe_loads": True} if moe_loads else {}
+def _on(**flags: bool) -> Dict[str, bool]:
+    """The step programs' boolean options (``moe_loads``, ``every_row``),
+    passed only where they are on (a step function without them keeps the
+    plain signature)."""
+    return {name: True for name, on in flags.items() if on}
 
 
 class Model:
@@ -70,30 +71,19 @@ class Model:
         return serving.paged_prefill_write(arena, layers_cache, block_ids)
 
     def paged_prefill_step(self, params, tokens, arena, block_tables,
-                           kv_lens, chunk_lens, moe_loads=False):
+                           kv_lens, chunk_lens, moe_loads=False,
+                           every_row=False):
         return serving.paged_prefill_step(params, tokens, self.cfg, arena,
                                           block_tables, kv_lens, chunk_lens,
-                                          **_loads(moe_loads))
-
-    def paged_verify_step(self, params, tokens, arena, block_tables,
-                          kv_lens, chunk_lens):
-        return serving.paged_verify_step(params, tokens, self.cfg, arena,
-                                         block_tables, kv_lens, chunk_lens)
+                                          **_on(moe_loads=moe_loads,
+                                                every_row=every_row))
 
     def paged_decode_step(self, params, tokens, state, arena, block_tables,
                           kv_lens, write_mask, moe_loads=False):
         return serving.paged_decode_step(params, tokens, self.cfg, state,
                                          arena, block_tables, kv_lens,
-                                         write_mask, **_loads(moe_loads))
-
-    def paged_shared_decode_step(self, params, tokens, state, arena,
-                                 block_tables, kv_lens, write_mask,
-                                 prefix_pages, prefix_lens, unique_tables,
-                                 unique_lens):
-        return serving.paged_shared_decode_step(
-            params, tokens, self.cfg, state, arena, block_tables, kv_lens,
-            write_mask, prefix_pages, prefix_lens, unique_tables,
-            unique_lens)
+                                         write_mask,
+                                         **_on(moe_loads=moe_loads))
 
 
 def build_model(cfg: ArchConfig) -> Model:

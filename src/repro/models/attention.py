@@ -404,44 +404,6 @@ def gqa_paged_decode(params: Params, x: jnp.ndarray, positions: jnp.ndarray,
     return out, k_arena, v_arena
 
 
-def gqa_paged_shared_decode(params: Params, x: jnp.ndarray,
-                            positions: jnp.ndarray, cfg: ArchConfig, *,
-                            k_arena, v_arena, layer, block_tables, kv_lens,
-                            write_mask, prefix_pages, prefix_lens,
-                            unique_tables, unique_lens):
-    """Cascade-decode twin of :func:`gqa_paged_decode`: the KV *write* goes
-    through the full per-lane ``block_tables`` exactly as before (the
-    pending token's row lands in the lane's own — never shared — tail
-    page), while attention splits into a shared-prefix phase over
-    ``prefix_pages`` (streamed once for every sharing lane) and a per-lane
-    unique phase over ``unique_tables``, merged by online-softmax state
-    (kernels/ops.py::shared_paged_attention).  Rope is applied before the
-    arena write, so attention over the cached rows is position-free and
-    the split changes no lane's math — only how often the hot pages move.
-    """
-    cdt = jnp.dtype(cfg.compute_dtype)
-    q, k, v = _proj_qkv(params, x, x, cfg, cdt)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-
-    from repro.kernels import ops as kops
-    NB, bs = k_arena.shape[1], k_arena.shape[2]
-    wm = (write_mask > 0).astype(kv_lens.dtype)
-    rows = _paged_chunk_rows(block_tables, kv_lens, wm, 1, bs, NB)
-    # saralint: ok[cow-gate] decode appends at row kv_len of the lane's exclusively-owned tail page (or the trash block when masked); shared prefix pages cover only rows < kv_len
-    k_arena = _arena_write_chunk(k_arena, layer, rows, k[:, :1])
-    v_arena = _arena_write_chunk(v_arena, layer, rows, v[:, :1])
-    o = kops.shared_paged_attention(
-        q[:, 0], _arena_layer(k_arena, layer), _arena_layer(v_arena, layer),
-        unique_tables, unique_lens, prefix_pages, prefix_lens,
-        logit_cap=cfg.attn_logit_softcap)
-    S = x.shape[0]
-    out = hint(o.reshape(S, 1, cfg.q_dim), "B", None, "M")
-    out = hint(dense(out, params["wo"], None, cdt, site="layer.attn.out"),
-               "B", None, None)
-    return out, k_arena, v_arena
-
-
 def _paged_chunk_rows(tables, kv_lens, chunk_lens, num_rows: int,
                       block_size: int, num_blocks: int):
     """Flat arena row for each of a lane's ``num_rows`` chunk positions

@@ -28,9 +28,7 @@ from repro.models.transformer import (_embed, _frontend_embed, _maybe_remat,
                                       _scan_mamba_span, _unembed_weight,
                                       decoder_layer_apply, hybrid_layout,
                                       paged_decoder_layer_apply,
-                                      paged_prefill_layer_apply,
-                                      paged_shared_decoder_layer_apply,
-                                      Params)
+                                      paged_prefill_layer_apply, Params)
 from repro.models.modules import dense, rmsnorm
 
 Cache = Dict[str, Any]
@@ -263,51 +261,10 @@ def paged_decode_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
     return ((logits, *loads) if moe_loads else logits), new_arena
 
 
-def paged_shared_decode_step(params: Params, tokens: jnp.ndarray,
-                             cfg: ArchConfig, state: Dict[str, Any],
-                             arena: Dict[str, Any],
-                             block_tables: jnp.ndarray, kv_lens: jnp.ndarray,
-                             write_mask: jnp.ndarray,
-                             prefix_pages: jnp.ndarray,
-                             prefix_lens: jnp.ndarray,
-                             unique_tables: jnp.ndarray,
-                             unique_lens: jnp.ndarray
-                             ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
-    """Cascade decode: like :func:`paged_decode_step`, but each lane's
-    attention splits into a shared-prefix phase (the hot ``prefix_pages``
-    are streamed ONCE per step for every lane in the sharing group) and a
-    per-lane unique phase over ``unique_tables``/``unique_lens``, merged by
-    online-softmax state.  The KV write still goes through the full
-    ``block_tables``.  GQA text families only (absorbed MLA and the
-    frontend families keep the plain paged path).  Returns ((S, V) logits,
-    new arena)."""
-    fam = cfg.family
-    if fam not in CHUNKED_PREFILL_FAMILIES or cfg.attention_type == "mla":
-        raise ValueError(f"family {fam!r}/{cfg.attention_type} cannot run "
-                         "shared-prefix cascade decode (GQA text families "
-                         "only)")
-    x = _embed(params, tokens, cfg)
-    positions = kv_lens[:, None]
-    wm = write_mask.astype(jnp.int32)
-
-    def layer_fn(h, layer_p, ak, av, layer):
-        return paged_shared_decoder_layer_apply(
-            layer_p, h, positions, cfg, k_arena=ak, v_arena=av, layer=layer,
-            block_tables=block_tables, kv_lens=kv_lens, write_mask=wm,
-            prefix_pages=prefix_pages, prefix_lens=prefix_lens,
-            unique_tables=unique_tables, unique_lens=unique_lens)[:3]
-
-    x, new_arena = _scan_paged_layers(_maybe_remat(layer_fn, cfg), x,
-                                      params, arena)
-
-    x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    return _lm_head(params, x[:, -1, :], cfg), new_arena
-
-
 def paged_prefill_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
                        arena: Dict[str, Any], block_tables: jnp.ndarray,
                        kv_lens: jnp.ndarray, chunk_lens: jnp.ndarray,
-                       moe_loads: bool = False
+                       moe_loads: bool = False, every_row: bool = False
                        ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """One chunked-prefill step over every lane through the paged KV arena.
 
@@ -322,6 +279,12 @@ def paged_prefill_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
     traffic is exactly the chunk's real tokens, and only those rows route
     to experts.  Returns ((S, V) logits at each lane's last valid chunk
     row, new arena); ``moe_loads`` as in :func:`paged_decode_step`.
+
+    ``every_row`` makes this the speculative-decode verifier: tokens
+    carry each lane's pending token followed by its draft proposals, and
+    the LM head runs over all C rows, because row i's logits accept or
+    correct draft token i+1.  The logits are then (S, C, V); rows past
+    ``chunk_lens`` are garbage the caller ignores.
     """
     fam = cfg.family
     if fam not in CHUNKED_PREFILL_FAMILIES:
@@ -343,47 +306,11 @@ def paged_prefill_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
                                               x, params, arena)
 
     x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    last = jnp.clip(chunk_lens - 1, 0, C - 1)
-    h_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0, :]
-    logits = _lm_head(params, h_last, cfg)
+    if not every_row:
+        last = jnp.clip(chunk_lens - 1, 0, C - 1)
+        x = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0, :]
+    logits = _lm_head(params, x, cfg)
     return ((logits, *loads) if moe_loads else logits), new_arena
-
-
-def paged_verify_step(params: Params, tokens: jnp.ndarray, cfg: ArchConfig,
-                      arena: Dict[str, Any], block_tables: jnp.ndarray,
-                      kv_lens: jnp.ndarray, chunk_lens: jnp.ndarray
-                      ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
-    """Speculative-decode verifier: the ragged chunked-prefill pass with
-    logits at EVERY chunk row.
-
-    Identical to :func:`paged_prefill_step` — tokens (S, C) carry each
-    lane's pending token followed by its draft proposals, KV rows land in
-    the lane's pages before attention, row r attends causally through the
-    block table — except the LM head runs over all C rows, because row i's
-    logits are what accepts or corrects draft token i+1.  Returns
-    ((S, C, V) logits, new arena); rows past ``chunk_lens`` are garbage
-    the caller ignores (their KV went to the trash page).
-    """
-    fam = cfg.family
-    if fam not in CHUNKED_PREFILL_FAMILIES:
-        raise ValueError(f"family {fam!r} cannot verify through the paged "
-                         f"arena (chunked prefill supports "
-                         f"{CHUNKED_PREFILL_FAMILIES})")
-    S, C = tokens.shape
-    x = _embed(params, tokens, cfg)
-    positions = kv_lens[:, None] + jnp.arange(C)[None, :]
-
-    def layer_fn(h, layer_p, ak, av, layer):
-        return paged_prefill_layer_apply(
-            layer_p, h, positions, cfg, k_arena=ak, v_arena=av, layer=layer,
-            block_tables=block_tables, kv_lens=kv_lens,
-            chunk_lens=chunk_lens)[:3]
-
-    x, new_arena = _scan_paged_layers(_maybe_remat(layer_fn, cfg), x,
-                                      params, arena)
-
-    x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    return _lm_head(params, x, cfg), new_arena
 
 
 # ---------------------------------------------------------------------------

@@ -144,31 +144,6 @@ def paged_decoder_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
     return x, nk, nv, load
 
 
-def paged_shared_decoder_layer_apply(p: Params, x, positions,
-                                     cfg: ArchConfig, *, k_arena, v_arena,
-                                     layer, block_tables, kv_lens, write_mask,
-                                     prefix_pages, prefix_lens,
-                                     unique_tables, unique_lens):
-    """Cascade-decode twin of :func:`paged_decoder_layer_apply`: attention
-    over a shared page prefix is computed once per step for every lane in
-    the sharing group (models/attention.py::gqa_paged_shared_decode).  GQA
-    families only — absorbed MLA keeps the plain paged path.  Returns
-    (x, new_k_arena, new_v_arena, expert load or None)."""
-    from repro.models.attention import gqa_paged_shared_decode
-
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    a, nk, nv = gqa_paged_shared_decode(
-        p["attn"], h, positions, cfg, k_arena=k_arena, v_arena=v_arena,
-        layer=layer, block_tables=block_tables, kv_lens=kv_lens,
-        write_mask=write_mask, prefix_pages=prefix_pages,
-        prefix_lens=prefix_lens, unique_tables=unique_tables, unique_lens=unique_lens)
-    x = x + a.astype(x.dtype)
-    h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    f, load = _paged_ffn(p, h2, cfg, (write_mask > 0)[:, None])
-    x = x + f.astype(x.dtype)
-    return x, nk, nv, load
-
-
 def paged_prefill_layer_apply(p: Params, x, positions, cfg: ArchConfig, *,
                               k_arena, v_arena, layer, block_tables, kv_lens,
                               chunk_lens):

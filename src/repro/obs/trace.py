@@ -58,7 +58,7 @@ STEP_PHASES = ("schedule", "inputs", "prefill", "prefill_chunk", "decode",
                "readback", "sample", "commit")
 
 COUNTERS = ("jit_compiles", "dispatch_records", "kv_defrag_auto",
-            "shared_prefix_steps", "prefix_cache_inserted_pages",
+            "prefix_cache_inserted_pages",
             "prefix_cache_evicted_pages", "kv_sanitize_checks",
             "kv_poison_hits", "kv_generation_faults",
             # fault tolerance (serving/faults.py + the engine's step
@@ -76,8 +76,7 @@ COUNTERS = ("jit_compiles", "dispatch_records", "kv_defrag_auto",
             "spec_bonus_tokens", "spec_draft_preempts")
 
 GAUGES = ("kv_pages_in_use", "kv_fragmentation", "slot_occupancy",
-          "decode_table_width", "shared_prefix_lanes",
-          "spec_accepted_per_step")
+          "decode_table_width", "spec_accepted_per_step")
 
 # Perfetto phase codes used by the export ("X" complete slice with a
 # duration, "i" instant, "C" counter sample)

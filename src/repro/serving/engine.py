@@ -210,15 +210,6 @@ class EngineConfig:
     # hits admit mid-prompt, and only the chunked path can resume a
     # prefill from a per-lane offset.
     prefix_cache: bool = False
-    # Cascade decode: when >= 2 decode lanes' block tables start with the
-    # same physical pages, stream that shared prefix ONCE per step for the
-    # whole group instead of once per lane.  Opt-in on top of
-    # prefix_cache.  The XLA reference rebuilds each lane's combined
-    # table and runs one masked softmax, so greedy parity with cache-off
-    # is bitwise; the Pallas kernel keeps the two-phase online-softmax
-    # merge and matches numerically.  GQA text families only (absorbed
-    # MLA keeps the plain paged decode).
-    shared_prefix_decode: bool = False
     # Speculative decoding (serving/spec_decode.py): draft ``spec_k``
     # tokens per lane per step with a draft model, verify all of them
     # (plus the pending token) with ONE target pass through the ragged
@@ -229,9 +220,8 @@ class EngineConfig:
     # "self" for self-speculation (shares the target's params — the
     # acceptance-rate upper bound, what the benchmark uses to isolate
     # engine overheads).  Requires prefill_chunk (the verifier IS the
-    # chunk kernel), greedy decoding (temperature <= 0), and is
-    # incompatible with shared_prefix_decode (the verify chunk replaces
-    # the decode step the cascade would group).
+    # chunk program, with logits at every row) and greedy decoding
+    # (temperature <= 0).
     spec_draft: Optional[str] = None
     spec_k: int = 4
     # Draft-arena page budget (None = same as the target pool).  Draft
@@ -284,18 +274,15 @@ class EngineConfig:
 class _PagedFeed(NamedTuple):
     """One paged decode's inputs, built on the host before the call:
     ``args`` after ``params`` (tokens, state, arena, tables, lengths,
-    write mask), the shared-prefix cascade's extra arrays (None without a
-    shared group), and what the step's accounting and the sanitizer
-    read back."""
+    write mask), and what the step's accounting and the sanitizer read
+    back."""
 
     args: tuple
-    shared: Optional[tuple]
     width: int
     kv_read: int
     rids: list
     tables: np.ndarray
     gens: Optional[np.ndarray]
-    shared_pages: int = 0
 
 
 class ServingEngine:
@@ -447,20 +434,6 @@ class ServingEngine:
                     jax.jit(functools.partial(self.model.paged_prefill_step,
                                               **loads),
                             donate_argnums=(2,)), "chunk_prefill", self.obs)
-            self._paged_shared_decode = None
-            if e.shared_prefix_decode:
-                if self.prefix_cache is None:
-                    raise ValueError(
-                        "shared_prefix_decode needs prefix_cache: shared "
-                        "page runs only arise from cache-hit admissions")
-                if cfg.attention_type == "mla":
-                    raise ValueError(
-                        "shared_prefix_decode is GQA-only (absorbed MLA "
-                        "keeps the plain paged decode)")
-                self._paged_shared_decode = JitWatch(
-                    jax.jit(self.model.paged_shared_decode_step,
-                            donate_argnums=(3,)),
-                    "paged_shared_decode", self.obs)
             self._cache = None
         else:
             # stacked per-slot caches: leading axis = slot, lane batch=1
@@ -486,11 +459,6 @@ class ServingEngine:
                     "0): the accept rule compares drafts against the "
                     "verify argmax, which is only the sampling rule when "
                     "greedy")
-            if e.shared_prefix_decode:
-                raise ValueError(
-                    "spec_draft is incompatible with shared_prefix_decode: "
-                    "the verify chunk replaces the decode step the "
-                    "cascade would group")
             if e.spec_k < 1:
                 raise ValueError("spec_k must be >= 1")
             draft_cfg, draft_params = resolve_draft(
@@ -505,8 +473,9 @@ class ServingEngine:
                 chunk=self.prefill_chunk, spec_k=e.spec_k,
                 recorder=self.obs)
             self._spec_verify = JitWatch(
-                jax.jit(self.model.paged_verify_step, donate_argnums=(2,)),
-                "spec_verify", self.obs)
+                jax.jit(functools.partial(self.model.paged_prefill_step,
+                                          every_row=True),
+                        donate_argnums=(2,)), "spec_verify", self.obs)
         # what one masked-dense decode step would stream: every slot's full
         # capacity (recurrent-state families have no KV rows to speak of)
         self._dense_kv_rows = (e.num_slots * self._cache_len
@@ -1201,44 +1170,21 @@ class ServingEngine:
         self.obs.gauge("decode_table_width", width)
         args = (jnp.asarray(self._last_tok), self._state, self.arena.leaves,
                 jnp.asarray(tables), jnp.asarray(kv), jnp.asarray(wm))
-        group = None
-        if self._paged_shared_decode is not None:
-            group = self._shared_prefix_group(active, kv, wm)
-        if group is None:
-            return _PagedFeed(args, None, width, e.block_size * sum(need),
-                              rids, tables, gens)
-        prefix_pages, prefix_lens, utables, ulens, kv_read, npages = group
-        self.obs.count("shared_prefix_steps", 1)
-        self.obs.gauge("shared_prefix_lanes", int((prefix_lens > 0).sum()))
-        shared = (jnp.asarray(prefix_pages), jnp.asarray(prefix_lens),
-                  jnp.asarray(utables), jnp.asarray(ulens))
-        return _PagedFeed(args, shared, width, kv_read, rids, tables, gens,
-                          npages)
+        return _PagedFeed(args, width, e.block_size * sum(need), rids,
+                          tables, gens)
 
     def _decode_paged(self, active: Dict[int, Request], feed: _PagedFeed):
         """One batched decode over every lane through the page arena.
         Returns (logits (S, V) on the host, seconds)."""
         t0 = time.time()
-        if feed.shared is not None:
-            with self._dispatch_scope("decode"), \
-                    self.timeline.phase("paged_decode", lanes=len(active),
-                                        width=feed.width,
-                                        kv_rows=feed.kv_read,
-                                        shared_pages=feed.shared_pages):
-                # saralint: ok[cow-gate] decode appends one row into the lane's exclusively-owned tail page; shared prefix pages cover only rows < kv_len
-                logits, leaves = self._paged_shared_decode(
-                    self.params, *feed.args, *feed.shared)
-        else:
-            with self._dispatch_scope("decode"), \
-                    self.timeline.phase("paged_decode", lanes=len(active),
-                                        width=feed.width,
-                                        kv_rows=feed.kv_read):
-                # saralint: ok[cow-gate] decode appends one row into the lane's exclusively-owned tail page; shared prefix pages cover only rows < kv_len
-                logits, leaves = self._paged_decode(self.params, *feed.args)
+        with self._dispatch_scope("decode"), \
+                self.timeline.phase("paged_decode", lanes=len(active),
+                                    width=feed.width, kv_rows=feed.kv_read):
+            # saralint: ok[cow-gate] decode appends one row into the lane's exclusively-owned tail page; shared prefix pages cover only rows < kv_len
+            logits, leaves = self._paged_decode(self.params, *feed.args)
         with self.timeline.phase("sync") as sync:
             logits, leaves = jax.block_until_ready((logits, leaves))
-            if feed.shared is None:
-                logits = self._moe_stats(sync, logits)
+            logits = self._moe_stats(sync, logits)
         dt = time.time() - t0
         self.arena.leaves = leaves
         with self.timeline.phase("readback"):
@@ -1454,71 +1400,6 @@ class ServingEngine:
                 "page is still reachable through a live block table")
             err.rids = [active[s].rid for s in bad]
             raise err
-
-    def _shared_prefix_group(self, active: Dict[int, Request],
-                             kv: np.ndarray, wm: np.ndarray):
-        """Detect the hottest shared page run among the decode lanes: the
-        largest group (>= 2 lanes) whose block tables begin with the same
-        physical pages, with >= 1 fully-written common page.  Returns the
-        cascade-kernel operands ``(prefix_pages, prefix_lens,
-        unique_tables, unique_lens, kv_read_rows, n_prefix_pages)`` —
-        padded to power-of-two widths like the plain decode tables — or
-        ``None`` when no group exists this step."""
-        e = self.ecfg
-        S, bs = e.num_slots, e.block_size
-        blocks = {s: self.pool.table(r.rid).blocks
-                  for s, r in active.items()}
-        groups: Dict[int, List[int]] = {}
-        for s, b in blocks.items():
-            # only fully-written pages can sit in the shared phase (it
-            # reads whole pages), so a lane needs >= bs committed rows
-            if b and int(kv[s]) >= bs:
-                groups.setdefault(b[0], []).append(s)
-        if not groups:
-            return None
-        best = max(groups.values(), key=len)
-        if len(best) < 2:
-            return None
-        # longest common physical prefix, capped at each member's fully
-        # written pages — the pending token's row must stay in the unique
-        # phase (it is written this very step)
-        P = min(min(int(kv[s]) // bs for s in best),
-                min(len(blocks[s]) for s in best))
-        first = blocks[best[0]]
-        i = 0
-        while i < P and all(blocks[s][i] == first[i] for s in best[1:]):
-            i += 1
-        P = i
-        if P < 1:
-            return None
-        members = set(best)
-        prefix_lens = np.zeros((S,), np.int32)
-        ulens = np.zeros((S,), np.int32)
-        for s in active:
-            attn = int(kv[s]) + int(wm[s])
-            if s in members:
-                prefix_lens[s] = P * bs
-                ulens[s] = attn - P * bs
-            else:
-                ulens[s] = attn
-        uneed = max(self.pool.blocks_for(int(n)) for n in ulens)
-        uw = KVBlockPool.table_width(max(uneed, 1),
-                                     self._max_blocks_per_slot)
-        utables = np.zeros((S, uw), np.int32)
-        for s in active:
-            off = P if s in members else 0
-            b = blocks[s][off:off + uw]
-            if b:
-                utables[s, :len(b)] = b
-                utables[s, len(b):] = b[-1]
-        pw = KVBlockPool.table_width(P, self._max_blocks_per_slot)
-        prefix_pages = np.full((pw,), first[P - 1], np.int32)
-        prefix_pages[:P] = first[:P]
-        # the measured win: the P shared pages stream once for the whole
-        # group instead of once per member lane
-        kv_read = bs * (P + sum(self.pool.blocks_for(int(n))
-                                for n in ulens))
-        return prefix_pages, prefix_lens, utables, ulens, kv_read, P
 
     def run(self, requests: Sequence[Request]) -> Dict[str, np.ndarray]:
         """Serve a request set to completion; returns {rid: generated}.

@@ -9,9 +9,9 @@ decode step into three moves:
    per-request block tables, alloc/extend/free, preemption when dry).
 2. **Verify** — ONE target-model pass checks every lane's pending token
    plus all its drafts through the ragged chunked-prefill kernel
-   (``models/serving.paged_verify_step``: C = spec_k + 1 rows per lane,
-   logits at every row).  Row ``i`` answers "what would greedy decode
-   emit after draft ``i`` tokens?".
+   (``models/serving.paged_prefill_step`` with ``every_row``: C =
+   spec_k + 1 rows per lane, logits at every row).  Row ``i`` answers
+   "what would greedy decode emit after draft ``i`` tokens?".
 3. **Accept** — :func:`accept_tokens` commits the longest draft prefix
    the verify argmax agrees with, plus one corrected token from the
    first disagreeing row (or a bonus extension when all drafts match).

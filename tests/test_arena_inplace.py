@@ -1,16 +1,16 @@
 """The paged step programs update the stacked KV arena in place.
 
 - aliasing: every engine step program that takes the arena (decode,
-  chunked prefill, shared-prefix decode, speculative verify, the draft
-  programs and the bucketed prefill write) donates it, so its compiled
+  chunked prefill, speculative verify, the draft programs and the
+  bucketed prefill write) donates it, so its compiled
   executable aliases at least the arena's bytes, and no
   dynamic-update-slice in it produces a whole stacked arena: the layer
   scan scatters each layer's rows into the carried stack instead of
   slicing a layer out and stacking the result back
-- parity: chunk, decode, verify and shared-prefix decode steps give logits
-  and arena leaves bitwise equal to the scan they replace (each layer's
-  pages in as ``xs``, out as ``ys``, restacked), for GQA, absorbed MLA
-  and MLA behind a leading dense stack
+- parity: chunk, decode and verify (the chunk step with logits at every
+  row) steps give logits and arena leaves bitwise equal to the scan they
+  replace (each layer's pages in as ``xs``, out as ``ys``, restacked),
+  for GQA, absorbed MLA and MLA behind a leading dense stack
 """
 
 import dataclasses
@@ -30,8 +30,7 @@ GQA_ARCH = "llama3.2-1b"
 MLA_ARCH = "deepseek-v3-671b"        # MLA + moe + one leading dense layer
 
 # engine step program -> position of the arena among its arguments
-ARENA_ARG = {"paged_decode": 3, "chunk_prefill": 2,
-             "paged_shared_decode": 3, "spec_verify": 2,
+ARENA_ARG = {"paged_decode": 3, "chunk_prefill": 2, "spec_verify": 2,
              "spec_draft_prefill": 2, "spec_draft_loop": 3,
              "paged_write": 0}
 HLO_DTYPE = {"float32": "f32", "bfloat16": "bf16", "float16": "f16"}
@@ -66,7 +65,7 @@ def step_programs():
                 kv_layout="paged")
     engines = [
         EngineConfig(max_len=30, prefill_chunk=8, prefix_cache=True,
-                     shared_prefix_decode=True, **base),
+                     **base),
         EngineConfig(max_len=40, prefill_chunk=8, spec_draft="self",
                      spec_k=2, **base),
         EngineConfig(max_len=40, **base),            # bucketed prefill
@@ -170,9 +169,8 @@ def _drive(model, params, arena, program):
     kv = LENS.copy()
     wm = np.array([1, 1, 0], np.int32)               # lane 2 masked
     if program == "verify":
-        step = jax.jit(lambda *a: model.paged_verify_step(*a))
-    elif program == "shared_decode":
-        step = jax.jit(lambda *a: model.paged_shared_decode_step(*a))
+        step = jax.jit(lambda *a: model.paged_prefill_step(*a,
+                                                           every_row=True))
     else:
         step = jax.jit(lambda *a: model.paged_decode_step(*a))
     for _ in range(3):
@@ -185,16 +183,8 @@ def _drive(model, params, arena, program):
         else:
             toks = jnp.asarray(rng.integers(0, cfg.vocab_size,
                                             (len(LENS), 1)), jnp.int32)
-            args = (params, toks, {}, arena, tables, jnp.asarray(kv),
-                    jnp.asarray(wm))
-            if program == "shared_decode":
-                plen = np.array([BS, BS, 0], np.int32)
-                utab = np.where(plen[:, None] > 0,
-                                np.roll(TABLES, -1, axis=1), TABLES)
-                args += (jnp.asarray([0], jnp.int32), jnp.asarray(plen),
-                         jnp.asarray(utab),
-                         jnp.asarray(kv + wm - plen))
-            logits, arena = step(*args)
+            logits, arena = step(params, toks, {}, arena, tables,
+                                 jnp.asarray(kv), jnp.asarray(wm))
             kv = kv + wm
         outs.append((logits, arena))
     return outs
@@ -202,7 +192,7 @@ def _drive(model, params, arena, program):
 
 @pytest.mark.parametrize("arch,program", [
     ("gqa", "decode"), ("mla", "decode"), ("mla-split", "decode"),
-    ("gqa", "verify"), ("gqa", "shared_decode")])
+    ("gqa", "verify"), ("mla", "verify"), ("mla-split", "verify")])
 def test_inplace_scan_bitwise_equals_xs_ys_scan(arch, program):
     cfg = _cfg(arch)
     model = build_model(cfg)

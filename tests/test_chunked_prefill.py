@@ -7,7 +7,9 @@
 - model parity: streaming a ragged prompt batch through paged_prefill_step
   chunk by chunk reproduces the dense bucketed prefill's last-token logits
   exactly (GQA and MLA-with-leading-dense-stack archs), and a subsequent
-  paged decode step matches the dense decode step
+  paged decode step matches the dense decode step; with ``every_row``
+  (the speculative verifier) the step's logits at each lane's last
+  valid row are bitwise its plain last-row logits
 - engine parity: a prefill_chunk engine generates exactly the greedy
   tokens of the dense bucketed-prefill engine on prompts straddling chunk
   and page boundaries (including length-1 prompts), and its prefill
@@ -17,6 +19,8 @@
   exactly the pages written, and a pressure run (stalls + preemptions)
   still matches the full-reserve greedy output
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -222,6 +226,36 @@ def test_paged_prefill_step_streams_to_dense_parity(arch):
     np.testing.assert_allclose(np.asarray(p_logits),
                                np.asarray(d_logits)[:, 0],
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("arch", [GQA_ARCH, MLA_ARCH])
+def test_every_row_logits_hold_the_last_row_logits(arch):
+    """The speculative verifier is the chunk step with ``every_row``: its
+    logits at each lane's last valid row are bitwise the plain chunk
+    step's, and both write the same arena."""
+    cfg = get_arch(arch).reduced()
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    tables, used = _tables(STARTS + CHUNKS, BS, width=3)
+    # random pages: the lanes that start mid-stream attend to rows the
+    # step does not write
+    arena = jax.tree_util.tree_map(
+        lambda a: jax.random.normal(jax.random.PRNGKey(1), a.shape, a.dtype),
+        model.init_paged_arena(used + 1, BS))
+    toks = jnp.asarray(np.random.default_rng(2).integers(
+        0, cfg.vocab_size, (len(STARTS), C)), jnp.int32)
+    args = (params, toks, arena, jnp.asarray(tables), jnp.asarray(STARTS),
+            jnp.asarray(CHUNKS))
+    last, last_arena = jax.jit(model.paged_prefill_step)(*args)
+    rows, rows_arena = jax.jit(functools.partial(model.paged_prefill_step,
+                                                 every_row=True))(*args)
+    assert rows.shape == (len(STARTS), C, cfg.vocab_size)
+    at_last = np.clip(CHUNKS - 1, 0, C - 1)
+    np.testing.assert_array_equal(
+        np.asarray(rows)[np.arange(len(STARTS)), at_last], np.asarray(last))
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(rows_arena[name]),
+                                      np.asarray(last_arena[name]))
 
 
 def test_paged_prefill_step_empty_batch_leaves_live_pages_untouched():

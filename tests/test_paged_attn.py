@@ -3,7 +3,8 @@
 - kernel parity: the Pallas paged kernel (interpret mode) == the XLA
   gather reference == masked-dense attention over the linearized rows, for
   GQA and absorbed MLA, across ragged per-lane lengths including block
-  boundaries (kv_len % block_size == 0 and +-1) and empty lanes
+  boundaries (kv_len % block_size == 0 and +-1) and empty lanes; a batch
+  whose every lane is empty comes out as zeros
 - model parity: paged_decode_step logits == vmapped dense decode_step
   logits to fp32 tolerance (GQA and MLA-with-leading-dense-stack archs)
 - engine parity: a kv_layout="paged" engine generates exactly the greedy
@@ -120,6 +121,35 @@ def test_gqa_kernel_logit_softcap():
     np.testing.assert_allclose(np.asarray(capped_p), np.asarray(capped_r),
                                rtol=1e-5, atol=1e-5)
     assert not np.allclose(np.asarray(capped_r), np.asarray(plain))
+
+
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+def test_all_empty_batch_yields_zeros(kind):
+    """Every lane at length 0 (a step whose lanes are all masked): no
+    table column is live, so both paged kernels skip every page and
+    return zeros, XLA and Pallas interpret alike."""
+    rng = np.random.default_rng(4)
+    S, H, NB = 3, 4, 6
+    tables = jnp.asarray(rng.integers(0, NB, (S, 2)), jnp.int32)
+    zeros = jnp.zeros((S,), jnp.int32)
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    if kind == "gqa":
+        q, k, v = normal(S, H, 8), normal(NB, BS, 2, 8), normal(NB, BS, 2, 8)
+        outs = [ops.paged_attention(q, k, v, tables, zeros, impl="xla"),
+                ops.paged_attention(q, k, v, tables, zeros, impl="pallas",
+                                    interpret=True)]
+    else:
+        qa, qr = normal(S, H, 16), normal(S, H, 8)
+        ckv, kro = normal(NB, BS, 16), normal(NB, BS, 8)
+        outs = [ops.mla_paged_attention(qa, qr, ckv, kro, tables, zeros,
+                                        qk_dim=24, impl=impl,
+                                        interpret=True)
+                for impl in ("xla", "pallas")]
+    for o in outs:
+        np.testing.assert_array_equal(np.asarray(o), 0.0)
 
 
 # ---------------------------------------------------------------------------

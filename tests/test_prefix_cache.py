@@ -8,14 +8,12 @@
 - trie: longest-prefix match at page granularity, insert pinning only
   new spans, LRU leaf-first eviction that never touches a page a live
   table still references, clear() returning the pool to fully free
-- kernels: shared-prefix (cascade) attention — XLA reference and Pallas
-  interpret — equals plain paged attention over the concatenated
-  prefix+suffix tables; softmax-state merge degenerates on empty sides
 - scheduler: suffix-only reservation on a cache hit; over-capacity
   prompts rejected at submit with PoolError
 - engine: exact greedy parity cache-on vs cache-off with COW exercised
-  (whole-prompt hit resumes inside a shared page), cascade decode
-  end-to-end, auto-defrag from the step loop
+  (whole-prompt hit resumes inside a shared page), cache-hit lanes
+  decoding side by side through the donor's physical pages, auto-defrag
+  from the step loop
 """
 
 import jax.numpy as jnp
@@ -23,9 +21,6 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_arch
-from repro.kernels import ops
-from repro.kernels.ref import (merge_softmax_states, paged_attention_lse_ref,
-                               shared_paged_attention_ref)
 from repro.serving import EngineConfig, KVArena, KVBlockPool, Request, \
     ServingEngine
 from repro.serving.kv_pool import PoolError
@@ -235,101 +230,6 @@ def test_prefix_cache_clear_returns_pool_to_free():
 
 
 # ---------------------------------------------------------------------------
-# kernels: cascade attention == plain paged attention over concat tables
-# ---------------------------------------------------------------------------
-
-def _cascade_case(seed=0):
-    """3 lanes over one arena: lanes 0/1 share prefix pages [0, 1]
-    (8 rows), lane 2 is a non-member; ragged unique suffixes."""
-    rng = np.random.default_rng(seed)
-    S, KVH, G, hd, bs, NB = 3, 2, 2, 8, 4, 8
-    q = jnp.asarray(rng.standard_normal((S, KVH * G, hd)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((NB, bs, KVH, hd)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((NB, bs, KVH, hd)), jnp.float32)
-    prefix_pages = jnp.asarray([0, 1], jnp.int32)
-    prefix_lens = jnp.asarray([8, 8, 0], jnp.int32)
-    utables = jnp.asarray([[2, 3], [4, 4], [5, 6]], jnp.int32)
-    ulens = jnp.asarray([5, 3, 6], jnp.int32)
-    full_tables = jnp.asarray([[0, 1, 2, 3], [0, 1, 4, 4], [5, 6, 6, 6]],
-                              jnp.int32)
-    full_lens = jnp.asarray([13, 11, 6], jnp.int32)
-    return (q, k, v, utables, ulens, prefix_pages, prefix_lens,
-            full_tables, full_lens)
-
-
-def test_shared_prefix_ref_matches_concatenated_paged():
-    """BITWISE, not allclose: the ref rebuilds one gap-free combined
-    table per lane and runs a single masked softmax, so greedy decode
-    over the cascade path must produce the exact floats the plain paged
-    path does (the engine's shared-prefix greedy-parity proof leans on
-    this)."""
-    (q, k, v, ut, ul, pp, pl, ft, fl) = _cascade_case()
-    o_full = ops.paged_attention(q, k, v, ft, fl, impl="xla")
-    o_casc = shared_paged_attention_ref(q, k, v, ut, ul, pp, pl)
-    np.testing.assert_array_equal(np.asarray(o_casc), np.asarray(o_full))
-
-
-def test_shared_prefix_ref_bitwise_with_padded_tables():
-    """Pad-width mismatch must not perturb the floats: widening the
-    unique tables (and the prefix page list) with garbage page ids past
-    the real lengths changes only masked lanes, so the output stays
-    bit-identical to the unpadded call."""
-    (q, k, v, ut, ul, pp, pl, ft, fl) = _cascade_case(seed=6)
-    o_ref = shared_paged_attention_ref(q, k, v, ut, ul, pp, pl)
-    ut_wide = jnp.concatenate(
-        [ut, jnp.full((ut.shape[0], 3), 7, jnp.int32)], axis=1)
-    pp_wide = jnp.concatenate([pp, jnp.asarray([7, 7], jnp.int32)])
-    o_wide = shared_paged_attention_ref(q, k, v, ut_wide, ul, pp_wide, pl)
-    np.testing.assert_array_equal(np.asarray(o_wide), np.asarray(o_ref))
-    o_full = ops.paged_attention(q, k, v, ft, fl, impl="xla")
-    np.testing.assert_array_equal(np.asarray(o_wide), np.asarray(o_full))
-
-
-def test_shared_paged_attention_pallas_matches_xla():
-    (q, k, v, ut, ul, pp, pl, ft, fl) = _cascade_case(seed=3)
-    o_xla = ops.shared_paged_attention(q, k, v, ut, ul, pp, pl, impl="xla")
-    o_pal = ops.shared_paged_attention(q, k, v, ut, ul, pp, pl,
-                                       impl="pallas", interpret=True)
-    np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_xla),
-                               rtol=1e-5, atol=1e-5)
-    o_full = ops.paged_attention(q, k, v, ft, fl, impl="xla")
-    np.testing.assert_allclose(np.asarray(o_xla), np.asarray(o_full),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_shared_paged_attention_all_empty_lane():
-    """prefix 0 + unique 0 -> zero output (the merge's empty identity)."""
-    (q, k, v, ut, _, pp, _, _, _) = _cascade_case(seed=4)
-    zeros = jnp.zeros((3,), jnp.int32)
-    o = ops.shared_paged_attention(q, k, v, ut, zeros, pp, zeros,
-                                   impl="xla")
-    assert np.allclose(np.asarray(o), 0.0)
-    o_p = ops.shared_paged_attention(q, k, v, ut, zeros, pp, zeros,
-                                     impl="pallas", interpret=True)
-    assert np.allclose(np.asarray(o_p), 0.0)
-
-
-def test_merge_softmax_states_empty_side_is_identity():
-    rng = np.random.default_rng(2)
-    S, H, hd = 2, 3, 4
-    q = jnp.asarray(rng.standard_normal((S, H, hd)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((3, 4, H, hd)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((3, 4, H, hd)), jnp.float32)
-    t = jnp.asarray([[0, 1], [2, 2]], jnp.int32)
-    lens = jnp.asarray([6, 4], jnp.int32)
-    o, m, l = paged_attention_lse_ref(q, k, v, t, lens)
-    empty_o = jnp.zeros_like(o, jnp.float32)
-    empty_m = jnp.full_like(m, -1e30)
-    empty_l = jnp.zeros_like(l)
-    merged, _, _ = merge_softmax_states(o, m, l, empty_o, empty_m, empty_l)
-    np.testing.assert_allclose(np.asarray(merged), np.asarray(o),
-                               rtol=1e-6, atol=1e-6)
-    merged2, _, _ = merge_softmax_states(empty_o, empty_m, empty_l, o, m, l)
-    np.testing.assert_allclose(np.asarray(merged2), np.asarray(o),
-                               rtol=1e-6, atol=1e-6)
-
-
-# ---------------------------------------------------------------------------
 # scheduler: suffix reservation + submit rejection
 # ---------------------------------------------------------------------------
 
@@ -450,7 +350,7 @@ def test_scheduler_submit_full_reserve_rejects_impossible_reservation():
 
 
 # ---------------------------------------------------------------------------
-# engine: end-to-end parity, COW, cascade, auto-defrag
+# engine: end-to-end parity, COW, shared-page decode, auto-defrag
 # ---------------------------------------------------------------------------
 
 def _engine(cfg, **kw):
@@ -501,31 +401,42 @@ def test_engine_prefix_cache_parity_and_cow():
     assert eng.metrics.prefill_kv_write_rows < off_rows
 
 
-def test_engine_shared_prefix_decode_cascade():
-    """Cascade decode takes over when >= 2 lanes' tables open with the
-    same physical pages; generations complete and match the plain
-    prefix-cache engine."""
+def test_engine_cache_hit_lanes_decode_through_shared_pages():
+    """Two cache-hit lanes decode side by side, their block tables opening
+    with the donor's physical pages, through the one paged decode program:
+    their greedy tokens equal the cache-off engine's."""
     cfg = get_arch(GQA_ARCH).reduced()
     rng = np.random.default_rng(12)
     shared = rng.integers(0, cfg.vocab_size, 16).astype(np.int32)
     # 4 requests on 2 slots: r0/r1 prefill concurrently (r1 misses — r0
     # inserts only at its final chunk), then r2/r3 both hit and decode
-    # side by side through the donor's physical pages — the group the
-    # cascade detector needs
+    # side by side through the donor's physical pages
     prompts = []
     for i in range(4):
         p = rng.integers(0, cfg.vocab_size, 20).astype(np.int32)
         p[:16] = shared
         prompts.append(p)
 
-    eng_p = _engine(cfg, max_len=30, prefix_cache=True)
-    res_p = _run(eng_p, prompts)
-    eng_c = _engine(cfg, max_len=30, prefix_cache=True,
-                    shared_prefix_decode=True)
-    res_c = _run(eng_c, prompts)
-    assert int(eng_c.obs.counters.get("shared_prefix_steps", 0)) > 0
-    for rid in res_p:
-        np.testing.assert_array_equal(res_c[rid], res_p[rid])
+    res_off = _run(_engine(cfg, max_len=30), prompts)
+    eng = _engine(cfg, max_len=30, prefix_cache=True)
+    reqs = [Request(f"r{i}", p, 6) for i, p in enumerate(prompts)]
+    for r in reqs:
+        eng.submit(r)
+    side_by_side = 0
+    while eng.step():
+        lanes = [r for r in eng.sched.active.values()
+                 if r.rid in ("r2", "r3") and r.generated]
+        if len(lanes) == 2:
+            first = {eng.pool.table(r.rid).blocks[0] for r in lanes}
+            side_by_side += len(first) == 1
+    assert side_by_side > 0
+    assert eng.summary()["kv_shared_pages"] > 0
+    for r in reqs:
+        np.testing.assert_array_equal(np.asarray(r.generated, np.int32),
+                                      res_off[r.rid])
+    eng.prefix_cache.clear()
+    eng.pool.check()
+    assert eng.pool.num_free == eng.pool.num_blocks
 
 
 def test_engine_auto_defrag_from_step_loop():
@@ -548,10 +459,6 @@ def test_engine_prefix_cache_requires_chunked_prefill():
     with pytest.raises(ValueError, match="prefill_chunk"):
         ServingEngine(cfg, EngineConfig(
             num_slots=2, max_len=23, kv_layout="paged", prefix_cache=True))
-    with pytest.raises(ValueError, match="prefix_cache"):
-        ServingEngine(cfg, EngineConfig(
-            num_slots=2, max_len=23, kv_layout="paged", prefill_chunk=8,
-            shared_prefix_decode=True))
 
 
 def test_metrics_cache_hit_accounting():

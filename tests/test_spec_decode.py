@@ -5,7 +5,8 @@ The load-bearing property is *greedy parity*: every token the spec path
 commits is a target-model verify argmax, so generated sequences must be
 bitwise-identical to plain greedy decode — speculation may only change
 how many tokens commit per step.  The tests prove that on a Poisson
-arrival trace, then poke each edge of the accept/rollback state machine:
+arrival trace (for a GQA model, and for an absorbed-MLA, routed-expert
+one), then poke each edge of the accept/rollback state machine:
 the pure accept rule (accept-all / reject-all / partial-accept), rewind
 into COW-shared prefix-cache pages, draft-lane preemption under a
 starved draft pool, and EOS/budget truncation mid-commit.
@@ -126,6 +127,20 @@ def test_spec_parity_poisson_trace():
     assert s["decode_steps"] < sum(len(t) for t in plain.values())
 
 
+def test_spec_parity_latent_attention_experts():
+    """The verifier is the chunk program with logits at every row for an
+    absorbed-MLA, routed-expert model too: greedy tokens equal plain
+    decode's, with drafts accepted."""
+    cfg = get_arch("deepseek-v3-671b").reduced()
+    trace = _poisson_trace()
+    plain = _engine(cfg).run(_clone(trace))
+    eng = _engine(cfg, spec_draft="self", spec_k=3)
+    spec = eng.run(_clone(trace))
+    for rid in plain:
+        np.testing.assert_array_equal(plain[rid], spec[rid])
+    assert eng.summary()["spec_accepted_per_step"] > 1.0
+
+
 def test_spec_parity_disagreeing_draft():
     """Parity must hold no matter how bad the draft is: a draft with
     different weights rejects most tokens, and every correction is the
@@ -222,6 +237,3 @@ def test_spec_config_validation():
         _engine(cfg, spec_draft="self", spec_k=0)
     with pytest.raises(ValueError, match="family"):
         _engine(cfg, spec_draft="rwkv6-1.6b")
-    with pytest.raises(ValueError, match="shared_prefix_decode"):
-        _engine(cfg, spec_draft="self", prefix_cache=True,
-                shared_prefix_decode=True)

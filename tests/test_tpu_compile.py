@@ -101,16 +101,6 @@ def test_paged_prefill_compiles(spec, chunk):
     assert "tpu_custom_call" in hlo
 
 
-def test_cascade_decode_compiles(spec):
-    k, v, tables, lengths = _arena_args(spec)
-    q = spec((SLOTS, CFG.num_heads, CFG.head_dim))
-    pages = spec((8,), jnp.int32)
-    hlo = _compile(lambda *a: ops.shared_paged_attention(
-        *a, impl="pallas", interpret=False), q, k, v, tables, lengths, pages,
-        lengths)
-    assert "tpu_custom_call" in hlo
-
-
 @pytest.mark.parametrize("mode", [OS, WS, IS], ids=["OS", "WS", "IS"])
 def test_rsa_gemm_largest_tile_compiles(spec, mode):
     """The tile space's largest block fits VMEM in every residency mode
